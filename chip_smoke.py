@@ -1,0 +1,596 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives the main path once, through the entry points a user
+calls, at the full width of GPT-2 124M (vocab 50304, h768, L12, 12 heads,
+bf16 + fp32 LayerNorm, AdamW with fp32 masters), weights random from a seed:
+
+* train: ``CompiledStep(train_step, stateful=[model, opt], donate_state=True,
+  donate_inputs=True)`` fed by ``io.DeviceLoader``, b24 s1024, a few steps
+  on one repeated batch — loss finite, first loss near ln(vocab), falling;
+* kernels: every Pallas kernel the run selects against the repo's own XLA
+  formulation of that op at the shapes used (values, stated tolerance);
+* serve: the same architecture through ``serving.Scheduler`` twice — the
+  engine as its constructor defaults give it, and the speed-v2 engine
+  (``spec_k=4, prefill_chunk=128``) — a dozen seeded requests in all, each
+  engine's request set replayed through the same executables (tokens must
+  repeat exactly), every fault / fallback / recompile counter at 0;
+* dp4: when four devices are visible, the same trainer under
+  ``build_mesh({"dp": 4})`` + ``ShardedOptimizer`` (fp32 wire, then int8),
+  with per-device shard evidence and loss parity against the one-chip leg.
+  With fewer devices it says "not run", never "ok".
+
+Run with no arguments it needs a TPU and exits non-zero without one.
+``--rehearse`` is the explicit tiny CPU rehearsal (``JAX_PLATFORMS=cpu``,
+Pallas interpreted) for debugging the script itself; it proves nothing
+about the chip. It prints versions, device, compile seconds per step and
+``peak_bytes_in_use``; no rate and no utilization. The last line of stdout
+is one JSON object: ``{"ok": true, "device": {...}}``.
+"""
+from __future__ import annotations
+
+import argparse
+import collections
+import contextlib
+import gc
+import json
+import math
+import re
+import sys
+import time
+
+import numpy as np
+
+Size = collections.namedtuple(
+    "Size", "vocab hidden layers heads max_len batch seq")
+#: bench.py's GPT-2 124M cell
+FULL = Size(vocab=50304, hidden=768, layers=12, heads=12, max_len=1024,
+            batch=24, seq=1024)
+#: --rehearse: same sequence lengths (so the attention router takes the same
+#: routes) at a width and depth the CPU interpreter finishes in a minute
+TINY = Size(vocab=512, hidden=128, layers=2, heads=2, max_len=1024,
+            batch=4, seq=1024)
+
+TRAIN_STEPS = 6
+#: (label, engine arguments, prompt lengths, new tokens per request). The
+#: default engine gets a prompt over 512 tokens so the 1024 bucket takes the
+#: cached flash kernel at sq1024; on the speed-v2 engine prompts <= 128 take
+#: the short buckets (einsum) and longer ones stream through
+#: serve_prefill_chunk (cached kernel at sq128 x sk1024); decode and verify
+#: (sq 1 and 5) go through the blockwise scan on both.
+SERVE_LEGS = (
+    ("default", {}, [12, 100, 600, 7, 90, 13], [16, 12, 24, 16, 8, 20]),
+    ("speed-v2", {"spec_k": 4, "prefill_chunk": 128},
+     [9, 100, 300, 700, 120, 14], [16, 12, 24, 16, 8, 20]),
+)
+#: kernel vs XLA formulation in fp32/highest: max|a-b| / max|b|. Forward
+#: outputs are one bf16 rounding apart; gradients accumulate bf16 products
+#: over 1024 keys (attention) or 24k rows (LayerNorm dgamma/dbeta).
+FWD_TOL = 1e-2
+GRAD_TOL = 3e-2
+#: dp4 loss against the one-chip leg on the same data, per step: same math,
+#: a different reduction order over bf16 (fp32 wire); int8 also rounds the
+#: gathered weights (bench.py's INT8_PARITY_RTOL)
+DP_FP32_RTOL = 1e-2
+DP_INT8_RTOL = 2e-2
+
+
+def say(msg):
+    print(msg, flush=True)
+
+
+class SmokeFailure(AssertionError):
+    pass
+
+
+def check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+@contextlib.contextmanager
+def ops_traced():
+    """Count framework ops by registry name while they are traced: which
+    attention / LayerNorm route each compiled step actually took."""
+    from paddle_tpu.ops import dispatch
+
+    seen = collections.Counter()
+    orig = dispatch.apply_op
+
+    def counting(name, *a, **k):
+        seen[name] += 1
+        return orig(name, *a, **k)
+
+    dispatch.apply_op = counting
+    try:
+        yield seen
+    finally:
+        dispatch.apply_op = orig
+
+
+class CacheStats:
+    """Persistent-compilation-cache traffic, from jax's own monitoring
+    events: a warm second run must show requests == hits."""
+
+    def __init__(self):
+        import jax
+
+        self.n = collections.Counter()
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event, **_):
+        if event.startswith("/jax/compilation_cache/"):
+            self.n[event.rsplit("/", 1)[1]] += 1
+
+    def line(self, cache_dir):
+        return (f"compile cache: dir={cache_dir} "
+                f"requests={self.n['compile_requests_use_cache']} "
+                f"hits={self.n['cache_hits']} "
+                f"compiled_and_written={self.n['cache_misses']}")
+
+
+# ---------------------------------------------------------------------------
+# builders (tools/tpu_aot_preflight.py compiles the same objects ahead of time)
+# ---------------------------------------------------------------------------
+def build_model(size):
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    cfg = GPTConfig(
+        vocab_size=size.vocab, hidden_size=size.hidden,
+        num_layers=size.layers, num_heads=size.heads,
+        max_position_embeddings=size.max_len, hidden_dropout=0.0,
+        attention_dropout=0.0)
+    paddle.seed(0)
+    model = GPTForCausalLM(cfg)
+    model.to(dtype="bfloat16")
+    for _, sub in model.named_sublayers():
+        if type(sub).__name__ == "LayerNorm":
+            sub.to(dtype="float32")  # fp32 LayerNorm for stability
+    return model
+
+
+def build_trainer(size, mesh=None, quantize=None):
+    """bench.py's train step. With ``mesh``: parameters replicated over it
+    and the update sharded over ``dp`` by ``ShardedOptimizer`` (ZeRO)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.functionalize import CompiledStep
+
+    model = build_model(size)
+    if mesh is not None:
+        rep = NamedSharding(mesh, P())
+        for p in model.parameters():
+            p._value = jax.device_put(p._value, rep)
+    opt = paddle.optimizer.AdamW(
+        learning_rate=1e-4, parameters=model.parameters(),
+        multi_precision=True)
+    stepper = opt
+    if mesh is not None:
+        from paddle_tpu.distributed.sharding import ShardedOptimizer
+
+        stepper = ShardedOptimizer(opt, axis="dp", mesh=mesh,
+                                   quantize=quantize)
+
+    def train_step(ids, labels):
+        loss = model.loss(ids, labels)
+        loss.backward()
+        stepper.step()
+        stepper.clear_grad()
+        return loss
+
+    # stateful threads the INNER optimizer: the ZeRO wrapper owns no arrays
+    step = CompiledStep(train_step, stateful=[model, opt], donate_state=True,
+                        donate_inputs=True)
+    return model, opt, step
+
+
+def build_engine(size, **kw):
+    from paddle_tpu.serving import GenerationEngine
+
+    return GenerationEngine(build_model(size), max_batch=8,
+                            max_len=size.max_len, **kw)
+
+
+def train_batches(size):
+    """One seeded batch of random tokens with next-token labels, repeated:
+    every copy is a fresh host array because the step donates its inputs.
+    (Labels equal to the inputs would start far below ln(vocab): the tied
+    head scores a position's own token highest at initialization.)"""
+    a = np.random.RandomState(0).randint(
+        0, size.vocab, (size.batch, size.seq + 1)).astype(np.int32)
+    return [(a[:, :-1].copy(), a[:, 1:].copy()) for _ in range(TRAIN_STEPS)]
+
+
+def run_steps(step, loader):
+    """Drive the step over the loader; returns (losses, first-call seconds,
+    mean seconds of the later calls). Each loss is read back, so a call's
+    time includes its execution."""
+    losses, times = [], []
+    for ids, labels in loader:
+        t0 = time.perf_counter()
+        loss = step(ids, labels)
+        losses.append(float(np.asarray(loss._value)))
+        times.append(time.perf_counter() - t0)
+    return losses, times[0], sum(times[1:]) / max(1, len(times) - 1)
+
+
+def mosaic_operand_shapes(hlo_text):
+    """Operand shapes of every Mosaic custom call in a compiled program's
+    per-device HLO, one tuple of ``dtype[dims]`` strings per call."""
+    return [tuple(re.findall(r"(?:bf16|f32|s32|u32)\[[\d,]*\]",
+                             line.split("custom-call(")[1]))
+            for line in hlo_text.splitlines()
+            if "tpu_custom_call" in line and "custom-call(" in line]
+
+
+def peak_bytes(device):
+    """``peak_bytes_in_use`` + ``peak_bytes_reserved``: on this libtpu the
+    first counts buffers only, the second the programs' temporaries."""
+    stats = device.memory_stats()
+    if not stats:
+        return "not reported"
+    return (f"{stats.get('peak_bytes_in_use')} in use + "
+            f"{stats.get('peak_bytes_reserved')} reserved")
+
+
+# ---------------------------------------------------------------------------
+# legs
+# ---------------------------------------------------------------------------
+def leg_train(size):
+    import jax
+
+    from paddle_tpu.io import DeviceLoader
+    from paddle_tpu.profiler import telemetry
+
+    telemetry.reset()
+    model, opt, step = build_trainer(size)
+    with ops_traced() as seen:
+        losses, first_s, later_s = run_steps(
+            step, DeviceLoader(train_batches(size)))
+    say(f"train: b{size.batch} s{size.seq} L{size.layers} h{size.hidden} "
+        f"losses {[round(v, 4) for v in losses]}")
+    say(f"train: first call (trace+compile+run) {first_s:.1f}s, later calls "
+        f"{later_s:.3f}s each; peak bytes {peak_bytes(jax.devices()[0])}")
+    check(all(math.isfinite(v) for v in losses), f"loss not finite: {losses}")
+    check(abs(losses[0] - math.log(size.vocab)) < 0.5,
+          f"first loss {losses[0]:.3f} not near ln(vocab)="
+          f"{math.log(size.vocab):.3f}")
+    check(all(b < a for a, b in zip(losses, losses[1:])),
+          f"loss not falling on a repeated batch: {losses}")
+    counts = telemetry.get_telemetry().compile_counts()
+    check(counts.get("train_step") == 1,
+          f"train_step compiled {counts.get('train_step')} times")
+    check(seen["flash_sdpa"] == size.layers
+          and seen["fused_layer_norm"] == 2 * size.layers + 1
+          and not seen["sdpa"] and not seen["layer_norm_op"]
+          and not seen["blockwise_sdpa"],
+          f"train step did not take the Pallas routes: {dict(seen)}")
+    say(f"train: routes flash_sdpa x{seen['flash_sdpa']}, fused_layer_norm "
+        f"x{seen['fused_layer_norm']}; ok")
+    return losses
+
+
+def _rel_err(a, b):
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+
+
+def leg_kernels(size):
+    """Each Pallas kernel the train and serve legs select, against the XLA
+    formulation of the same op on the same inputs upcast to fp32 at HIGHEST
+    matmul precision."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.attention import (LengthMask, _sdpa_flash,
+                                                    _sdpa_flash_cached,
+                                                    _sdpa_raw)
+    from paddle_tpu.nn.functional.norm import (_layer_norm_pallas,
+                                               _layer_norm_raw)
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    b, s, h = size.batch, size.seq, size.heads
+    d = size.hidden // h
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
+
+    def rnd(shape, dtype):
+        return jax.random.normal(next(keys), shape, f32).astype(dtype)
+
+    def up(x):
+        return x.astype(f32) if jnp.issubdtype(x.dtype, jnp.floating) else x
+
+    def compare(name, kernel, reference, args, n_grad):
+        """Forward always; gradients w.r.t. the first ``n_grad`` args under a
+        fixed random cotangent."""
+        out = jax.jit(kernel)(*args)
+        ct = rnd(out.shape, f32)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(reference)(*map(up, args))
+        errs = {"fwd": _rel_err(out, ref)}
+        if n_grad:
+            def scalar(fn):
+                return lambda *a: jnp.sum(fn(*a).astype(f32) * ct)
+
+            argnums = tuple(range(n_grad))
+            g = jax.jit(jax.grad(scalar(kernel), argnums))(*args)
+            with jax.default_matmul_precision("highest"):
+                gr = jax.jit(jax.grad(scalar(reference), argnums))(
+                    *map(up, args))
+            for i, (x, y) in enumerate(zip(g, gr)):
+                errs[f"d{i}"] = _rel_err(x, y)
+        say(f"kernels: {name}: " + " ".join(
+            f"{k}={v:.2e}" for k, v in errs.items()))
+        check(np.all(np.isfinite(np.asarray(out, np.float32))),
+              f"{name}: kernel output not finite")
+        for k, v in errs.items():
+            tol = FWD_TOL if k == "fwd" else GRAD_TOL
+            check(v <= tol, f"{name}: {k} error {v:.3e} over tolerance {tol}")
+
+    compare(f"fused_layer_norm [{b},{s},{size.hidden}] bf16",
+            lambda x, g, be: _layer_norm_pallas.raw(x, g, be),
+            lambda x, g, be: _layer_norm_raw.raw(
+                x, g, be, begin_axis=2, has_w=True, has_b=True),
+            (rnd((b, s, size.hidden), bf), 1.0 + 0.1 * rnd((size.hidden,), f32),
+             0.1 * rnd((size.hidden,), f32)), 3)
+    compare(f"flash_sdpa causal [{b},{s},{h},{d}] bf16",
+            lambda q, k, v: _sdpa_flash.raw(q, k, v, causal=True),
+            lambda q, k, v: _sdpa_raw.raw(q, k, v, causal=True),
+            tuple(rnd((b, s, h, d), bf) for _ in range(3)), 3)
+    # the cached kernel as serving reaches it: one request's queries over
+    # its full cache row — the 1024 prefill bucket and a 128-token chunk
+    sk = size.max_len
+    for sq, off, klen in ((sk, 0, 600), (128, 256, None)):
+        q_pos = (off + jnp.arange(sq, dtype=jnp.int32))[None, :]
+        kv_len = None if klen is None else jnp.asarray([klen], jnp.int32)
+        mask = LengthMask(q_pos, kv_len)
+
+        def cached(q, k, v, mask=mask):
+            return _sdpa_flash_cached.raw(q, k, v, mask.q_pos, mask.kv_len)
+
+        def dense(q, k, v, mask=mask, sk=sk):
+            return _sdpa_raw.raw(q, k, v, mask.additive(sk, q.dtype))
+
+        compare(f"flash_sdpa_cached sq{sq} sk{sk} b1 bf16", cached, dense,
+                (rnd((1, sq, h, d), bf), rnd((1, sk, h, d), bf),
+                 rnd((1, sk, h, d), bf)), 0)
+
+
+def _periodic_prompt(rng, vocab, n):
+    """A motif tiled to ``n`` tokens: the n-gram draft proposer always finds
+    an earlier occurrence, so speculative verify runs from the first tick."""
+    motif = rng.randint(0, vocab, (int(rng.randint(4, 8)),))
+    return [int(t) for t in np.tile(motif, n // len(motif) + 1)[:n]]
+
+
+def bucketed_prompts(eng, prompt_lens):
+    """The prefill buckets these prompts compile: prompts longer than the
+    chunk stream through serve_prefill_chunk instead
+    (scheduler._admit_one); the rest take one bucketed prefill each."""
+    from paddle_tpu.serving.kv_cache import pick_bucket
+
+    return {pick_bucket(n, eng.prefill_buckets) for n in prompt_lens
+            if not (eng.prefill_chunk and n > eng.prefill_chunk
+                    and eng.chunked_prefill_fits(n))}
+
+
+def leg_serve(size, label, engine_kw, prompt_lens, new_tokens):
+    import jax
+
+    from paddle_tpu.profiler import telemetry
+    from paddle_tpu.serving import Request, Scheduler
+
+    telemetry.reset()
+    tm = telemetry.get_telemetry()
+    eng = build_engine(size, **engine_kw)
+    if jax.default_backend() == "tpu":
+        check(not eng.freeze_weights,
+              "on the chip the weights must ride as donated state")
+    rng = np.random.RandomState(2)
+    prompts = [_periodic_prompt(rng, size.vocab, n) for n in prompt_lens]
+
+    def serve_all():
+        sched = Scheduler(eng)
+        reqs = [sched.submit(Request(prompt=p, max_new_tokens=n))
+                for p, n in zip(prompts, new_tokens)]
+        sched.run()
+        return reqs
+
+    t0 = time.perf_counter()
+    with ops_traced() as seen:
+        first = serve_all()
+    cold_s = time.perf_counter() - t0
+    compiles_after_first = dict(tm.compile_counts())
+    t0 = time.perf_counter()
+    again = serve_all()  # same executables: tokens must repeat exactly
+    warm_s = time.perf_counter() - t0
+
+    for r, n in zip(first + again, new_tokens * 2):
+        check(r.finish_reason in ("eos", "length"),
+              f"{label}: request {r.rid} ended {r.finish_reason!r}")
+        check(len(r.tokens) == n and all(0 <= t < size.vocab
+                                         for t in r.tokens),
+              f"{label}: request {r.rid} tokens malformed: {r.tokens}")
+    for a, b in zip(first, again):
+        check(a.tokens == b.tokens,
+              f"{label}: replay through the same executables diverged: "
+              f"{a.tokens} vs {b.tokens}")
+    counters = tm.counters()
+    for name in ("serve.errors", "serve.oom_evictions",
+                 "serve.degraded_steps", "serve.spec_fallback_ticks",
+                 "serve.timeouts", "serve.shed"):
+        check(not counters.get(name),
+              f"{label}: {name} = {counters.get(name)}")
+    check(tm.recompile_count == 0,
+          f"{label}: recompile_count {tm.recompile_count}")
+    counts = tm.compile_counts()
+    check(counts == compiles_after_first,
+          f"{label}: the replay compiled again: {compiles_after_first} -> "
+          f"{counts}")
+    expect = {"serve_prefill": len(bucketed_prompts(eng, prompt_lens)),
+              "serve_decode": 1}
+    if eng.spec_k:
+        expect["serve_verify"] = 1
+        check(counters.get("serve.spec_ticks", 0) > 0,
+              f"{label}: no speculative tick ran")
+    if eng.prefill_chunk:
+        expect["serve_prefill_chunk"] = 1
+        check(counters.get("serve.prefill_chunks", 0) > 0,
+              f"{label}: no prefill chunk ran")
+    for name, n in expect.items():
+        got = counts.get(name, 0)
+        # a speculative engine may never need its plain decode step
+        ok = got <= n if (name == "serve_decode" and eng.spec_k) else got == n
+        check(ok, f"{label}: {name} compiled {got} times, expected {n}")
+    check(seen["flash_sdpa_cached"] and seen["blockwise_sdpa"]
+          and seen["sdpa"] and seen["fused_layer_norm"]
+          and not seen["layer_norm_op"],
+          f"{label}: a serving route did not run: {dict(seen)}")
+    say(f"serve[{label}]: {len(first)} requests x2 (prompts {prompt_lens}), "
+        f"all eos|length, replay identical; compiles {counts}; ops traced "
+        f"flash_sdpa_cached x{seen['flash_sdpa_cached']} blockwise_sdpa "
+        f"x{seen['blockwise_sdpa']} sdpa x{seen['sdpa']}")
+    say(f"serve[{label}]: seconds in calls that compiled, per step "
+        f"{ {k: round(v, 1) for k, v in tm.compile_seconds().items()} }")
+    say(f"serve[{label}]: first pass (with compiles) {cold_s:.1f}s, replay "
+        f"{warm_s:.1f}s; spec_ticks {counters.get('serve.spec_ticks', 0)} "
+        f"prefill_chunks {counters.get('serve.prefill_chunks', 0)}; "
+        f"peak bytes {peak_bytes(jax.devices()[0])}; ok")
+
+
+def leg_dp4(size, one_chip_losses):
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.distributed.mesh import build_mesh
+    from paddle_tpu.io import DeviceLoader
+    from paddle_tpu.profiler import telemetry
+
+    n = len(jax.devices())
+    if n < 4:
+        say(f"dp4: not run: {n} device(s)")
+        return
+    mesh = build_mesh({"dp": 4})
+    rows = NamedSharding(mesh, P("dp"))
+    for quantize, rtol in ((None, DP_FP32_RTOL), ("int8", DP_INT8_RTOL)):
+        wire = quantize or "fp32"
+        telemetry.reset()
+        model, opt, step = build_trainer(size, mesh=mesh, quantize=quantize)
+        batches = train_batches(size)
+        staged = jax.device_put(batches[0][0], rows)
+        batch_shards = sorted((s.device.id, s.data.shape)
+                              for s in staged.addressable_shards)
+        check(len(batch_shards) == 4 and all(
+            shape == (size.batch // 4, size.seq) for _, shape in batch_shards),
+            f"dp4[{wire}]: batch shards {batch_shards}")
+        losses, first_s, later_s = run_steps(step, DeviceLoader(
+            batches, place_fn=lambda a: jax.device_put(a, rows)))
+        # the per-device program (lowered after the run, so the step's own
+        # trace-and-compile count above stays the real one)
+        mosaic = mosaic_operand_shapes(step.lower(
+            *[jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows)
+              for a in batches[0]]).compile().as_text())
+        local = {shape[5:].split(",")[0] for shapes in mosaic
+                 for shape in shapes if shape.startswith("bf16[")}
+        total = per_dev = 0
+        for store in opt._accumulators.values():
+            for v in store.values():
+                if hasattr(v, "addressable_shards"):
+                    total += v.nbytes
+                    per_dev += v.addressable_shards[0].data.nbytes
+        rel = max(abs(a - b) / abs(b)
+                  for a, b in zip(losses, one_chip_losses))
+        say(f"dp4[{wire}]: batch shards {batch_shards}; optimizer state "
+            f"{per_dev} of {total} bytes on device 0 "
+            f"({per_dev / total:.3f}); {len(mosaic)} Mosaic calls, leading "
+            f"dims of their bf16 operands {sorted(local)}")
+        say(f"dp4[{wire}]: losses {[round(v, 4) for v in losses]} vs one "
+            f"chip, max rel diff {rel:.2e} (tolerance {rtol}); first call "
+            f"{first_s:.1f}s, later {later_s:.3f}s; peak bytes per device "
+            f"{[peak_bytes(d) for d in jax.devices()[:4]]}")
+        check(per_dev / total < 0.30,
+              f"dp4[{wire}]: optimizer state not sharded: {per_dev}/{total}")
+        if jax.default_backend() == "tpu":
+            rows_local = size.batch // 4
+            check(mosaic and local <= {str(rows_local),
+                                       str(rows_local * size.seq)},
+                  f"dp4[{wire}]: Mosaic calls do not see the local batch: "
+                  f"{sorted(local)}")
+        check(all(math.isfinite(v) for v in losses) and rel <= rtol,
+              f"dp4[{wire}]: loss parity {rel:.3e} over {rtol}: {losses} vs "
+              f"{one_chip_losses}")
+        check(telemetry.get_telemetry().compile_counts().get(
+            "train_step") == 1, f"dp4[{wire}]: train_step recompiled")
+        del model, opt, step
+        gc.collect()
+    say("dp4: ok")
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny CPU rehearsal of the script (Pallas "
+                         "interpreted); proves nothing about the chip")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if args.rehearse:
+        if dev.platform != "cpu":
+            sys.exit("chip_smoke: --rehearse is the CPU rehearsal; run it "
+                     "with JAX_PLATFORMS=cpu")
+    elif dev.platform != "tpu":
+        sys.exit(f"chip_smoke: no TPU: jax found {device}. Run it on the "
+                 f"chip; `--rehearse` is the tiny CPU rehearsal.")
+
+    from paddle_tpu.framework.compile_cache import enable_compile_cache
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.profiler import devprof, telemetry
+
+    import importlib.metadata
+
+    import jaxlib
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    stats = CacheStats()
+    cache_dir = enable_compile_cache()
+    telemetry.enable()
+    devprof.enable_auto_harvest(False)  # no second lowering per step
+    say(f"chip_smoke: jax {jax.__version__} jaxlib {jaxlib.__version__} "
+        f"libtpu {libtpu} platform {dev.platform} device_kind {dev.device_kind!r} "
+        f"count {device['count']}"
+        + (" [CPU REHEARSAL]" if args.rehearse else ""))
+    size = TINY if args.rehearse else FULL
+    t_start = time.perf_counter()
+    with (pallas.interpret_mode() if args.rehearse
+          else contextlib.nullcontext()):
+        check(args.rehearse or not pallas.interpret_requested(),
+              "Pallas interpret mode on the chip path")
+        losses = leg_train(size)
+        gc.collect()
+        leg_kernels(size)
+        gc.collect()
+        for leg in SERVE_LEGS:
+            leg_serve(size, *leg)
+            gc.collect()
+        leg_dp4(size, losses)
+    say(f"memory_stats of device 0: {dev.memory_stats()}")
+    say(stats.line(cache_dir))
+    say(f"chip_smoke: all legs passed in {time.perf_counter() - t_start:.0f}s")
+    result = {"ok": True, "device": device}
+    if args.rehearse:
+        result["rehearsal"] = True
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

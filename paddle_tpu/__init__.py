@@ -14,12 +14,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-# normalize the jax surface BEFORE any submodule does `from jax import
-# shard_map` (older runtimes keep it under jax.experimental)
-from .framework.jax_compat import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 # framework core
 from .framework.dtype import (  # noqa: F401
     bfloat16,

@@ -19,6 +19,8 @@ import warnings
 
 import jax
 import numpy as np
+from jax._src import source_info_util
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .findings import LintReport
 from .mem_lint import MEM_LINT_DEFAULTS
@@ -37,16 +39,7 @@ LINT_DEFAULTS = {
 }
 
 
-def _jaxpr_types():
-    try:
-        from jax.extend.core import ClosedJaxpr, Jaxpr  # jax >= 0.4.33
-    except Exception:  # pragma: no cover - older jax layouts
-        from jax.core import ClosedJaxpr, Jaxpr
-    return Jaxpr, ClosedJaxpr
-
-
 def _subjaxprs(v):
-    Jaxpr, ClosedJaxpr = _jaxpr_types()
     if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
     elif isinstance(v, Jaxpr):
@@ -57,16 +50,15 @@ def _subjaxprs(v):
 
 
 def _eqn_where(eqn):
-    """User-code ``file:line`` provenance for a jaxpr equation."""
-    try:
-        from jax._src import source_info_util
-
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return f"{os.path.basename(frame.file_name)}:{frame.start_line}"
-    except Exception:
-        pass
-    return ""
+    """User-code ``file:line`` provenance for a jaxpr equation ('' when the
+    equation carries no traceback). ``user_frame`` is jax-private and takes
+    the traceback itself on jax 0.9; nothing is caught here, so the next
+    signature change fails the lint tests instead of emptying every
+    finding's ``where``."""
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return ""
+    return f"{os.path.basename(frame.file_name)}:{frame.start_line}"
 
 
 def _walk_eqns(jaxpr):

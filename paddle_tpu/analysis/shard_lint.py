@@ -156,7 +156,7 @@ def _env_get(env, v):
 
 def _path_of(var_paths, v):
     """Input-path provenance for a jaxpr atom ('' for Literals — they are
-    unhashable on the 0.4.x line and never step inputs anyway)."""
+    unhashable and never step inputs anyway)."""
     if hasattr(v, "val") or not var_paths:
         return ""
     try:

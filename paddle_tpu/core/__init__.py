@@ -12,20 +12,24 @@ job rather than the compiler's:
 
 Sources live in ``native/`` and are compiled on demand with g++ into a
 shared library loaded via ctypes (no pybind11 in this environment — the
-C-ABI + ctypes route is the binding layer, reference L5).
+C-ABI + ctypes route is the binding layer, reference L5). The library is
+named after a hash of its sources, so what gets loaded was built from the
+sources git carries: a ``_build/`` copied over from another checkout or
+machine (it is git-ignored) is never trusted on its modification time.
 """
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC_DIR = os.path.join(_HERE, "native")
 _BUILD_DIR = os.path.join(_HERE, "_build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libpaddle_tpu_core.so")
-
 _SOURCES = ("tcp_store.cc", "host_tracer.cc")
 
 _lock = threading.Lock()
@@ -33,25 +37,28 @@ _lib = None
 _load_error = None
 
 
-def _stale():
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_SRC_DIR, s)) > lib_mtime for s in _SOURCES
-    )
+def _lib_path():
+    digest = hashlib.sha1()
+    for s in _SOURCES:
+        with open(os.path.join(_SRC_DIR, s), "rb") as f:
+            digest.update(f.read())
+    return os.path.join(
+        _BUILD_DIR, f"libpaddle_tpu_core.{digest.hexdigest()[:12]}.so")
 
 
-def _build():
+def _build(lib_path):
     os.makedirs(_BUILD_DIR, exist_ok=True)
     srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
-    tmp = _LIB_PATH + f".tmp{os.getpid()}"
+    tmp = lib_path + f".tmp{os.getpid()}"
     cmd = [
         "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
         "-o", tmp, *srcs,
     ]
     subprocess.run(cmd, check=True, capture_output=True, text=True)
-    os.replace(tmp, _LIB_PATH)  # atomic wrt concurrent builders
+    os.replace(tmp, lib_path)  # atomic wrt concurrent builders
+    for old in glob.glob(os.path.join(_BUILD_DIR, "libpaddle_tpu_core*.so")):
+        if old != lib_path:
+            os.remove(old)  # built from other sources
 
 
 def _declare(lib):
@@ -86,20 +93,25 @@ def _declare(lib):
 
 
 def load_native():
-    """Build (if needed) and load the native library. Returns None and
-    remembers the error when the toolchain is unavailable — callers fall
-    back to pure-python paths."""
+    """Build (if needed) and load the native library. When it cannot be
+    built or loaded (no g++, a failing compile) this returns None, remembers
+    the error (:func:`native_load_error`) and says so once: callers that
+    have a pure-python path take it, but not silently."""
     global _lib, _load_error
     with _lock:
         if _lib is not None or _load_error is not None:
             return _lib
         try:
-            if _stale():
-                _build()
-            _lib = _declare(ctypes.CDLL(_LIB_PATH))
-        except Exception as e:  # noqa: BLE001 - record & degrade
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path):
+                _build(lib_path)
+            _lib = _declare(ctypes.CDLL(lib_path))
+        except (OSError, subprocess.CalledProcessError) as e:
             _load_error = e
-            _lib = None
+            detail = getattr(e, "stderr", None) or e
+            warnings.warn(
+                f"paddle_tpu native core library unavailable, using the "
+                f"pure-python paths: {detail}", RuntimeWarning, stacklevel=2)
         return _lib
 
 

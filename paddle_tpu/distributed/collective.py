@@ -194,11 +194,12 @@ def _apply(tensor, group, per_shard_fn, out_specs=None, in_specs=None):
 
 
 def per_shard_fn_single(fn, x, g):
-    """world_size==1: run the collective body with the axis bound to size 1."""
-    one = Mesh(np.array(jax.devices()[:1]), axis_names=(g.axis_name,))
-    return shard_map(
-        fn, mesh=one, in_specs=(P(),), out_specs=P(), check_vma=False
-    )(x)
+    """world_size==1: run the collective body with the axis bound to size 1.
+    The axis is bound by a size-1 ``vmap``, not by a one-device mesh: the
+    data stays on whatever device (or sharding) it lives on, where a mesh
+    over ``jax.devices()[:1]`` would pull everything onto chip 0."""
+    return jax.tree_util.tree_map(
+        lambda o: o[0], jax.vmap(fn, axis_name=g.axis_name)(x[None]))
 
 
 def _mp_eager(g, x):
@@ -288,10 +289,7 @@ def _record_static(opname, g, per_shard_fn, tensor, in_specs=None,
 
     def fwd(x):
         if g.nranks == 1:
-            one = Mesh(np.array(jax.devices()[:1]),
-                       axis_names=(g.axis_name,))
-            return shard_map(per_shard_fn, mesh=one, in_specs=(P(),),
-                             out_specs=P(), check_vma=False)(x)
+            return per_shard_fn_single(per_shard_fn, x, g)
         return shard_map(per_shard_fn, mesh=g.mesh, in_specs=(ins,),
                          out_specs=outs, check_vma=False)(x)
 

@@ -11,6 +11,12 @@ mainly wires the coordinator address for ``jax.distributed.initialize``
 parity with the reference's one-proc-per-device model) it spawns N local
 processes with the PADDLE_* env surface and a shared coordinator —
 ``init_parallel_env`` in each worker completes the rendezvous.
+
+A chip belongs to one process at a time, so with ``--nproc_per_node > 1``
+every child is pinned to its own chip (``TPU_VISIBLE_CHIPS`` = its local
+rank; see :func:`_chip_env`) — left to the default each child would claim
+every chip of the host and all but the first would fail or hang. The
+parent stays stdlib-only: it never touches jax, so it never holds a chip.
 """
 from __future__ import annotations
 
@@ -56,13 +62,47 @@ def _parse(argv=None):
     return p.parse_args(argv)
 
 
+#: TPU_PROCESS_BOUNDS of a single host split into one-chip processes, by
+#: chip count (the table jax's own multi-process TPU tests use)
+_ONE_CHIP_PROCESS_BOUNDS = {4: "2,2,1", 8: "4,2,1"}
+
+
+def _chip_env(local_rank, nproc, ports):
+    """libtpu variables that give local process ``local_rank`` of ``nproc``
+    exactly one chip. On a single host of 4 or 8 chips the processes also
+    form one connected slice (collectives between them run over ICI); for
+    any other count each process gets an isolated chip, and a connected
+    slice needs the ``TPU_PROCESS_*`` variables from the caller. Variables
+    the caller already set are left alone. Harmless without a TPU."""
+    bounds = _ONE_CHIP_PROCESS_BOUNDS.get(nproc)
+    env = {
+        "TPU_VISIBLE_CHIPS": str(local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds or "1,1,1",
+        "TPU_PROCESS_PORT": str(ports[local_rank]),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+    if bounds:
+        env["CLOUD_TPU_TASK_ID"] = str(local_rank)
+        env["TPU_PROCESS_ADDRESSES"] = ",".join(
+            f"localhost:{p}" for p in ports)
+    return {k: v for k, v in env.items() if k not in os.environ}
+
+
 def _spawn(args, master, attempt):
     os.makedirs(args.log_dir, exist_ok=True)
     world = args.nnodes * args.nproc_per_node
     procs = []
+    ports = []
+    while len(ports) < args.nproc_per_node:
+        port = _free_port()
+        if port not in ports:
+            ports.append(port)
     for local_rank in range(args.nproc_per_node):
         rank = args.rank * args.nproc_per_node + local_rank
         env = dict(os.environ)
+        if args.nproc_per_node > 1:
+            env.update(_chip_env(local_rank, args.nproc_per_node, ports))
         env.update({
             "PADDLE_TRAINER_ID": str(rank),
             "PADDLE_LOCAL_RANK": str(local_rank),

@@ -54,15 +54,9 @@ def _replicate_activation(val, mesh):
     point). Under an ambient mesh (e.g. inside the pipeline schedule's
     partially-manual region, where pp is a Manual axis) a bare PartitionSpec
     must be used; otherwise constrain against the group's concrete mesh."""
-    am = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
-    if am is not None and not getattr(am, "empty", True):
-        try:
-            return jax.lax.with_sharding_constraint(val, P())
-        except (RuntimeError, ValueError, TypeError):
-            # the 0.4.x line resolves bare specs against the concrete
-            # `with Mesh(...)` context, not the ambient abstract mesh set by
-            # the pipeline trace — fall through to the explicit-sharding form
-            pass
+    if (isinstance(val, jax.core.Tracer)
+            and not jax.sharding.get_abstract_mesh().empty):
+        return jax.lax.with_sharding_constraint(val, P())
     if mesh is None or getattr(mesh, "size", 0) <= 1:
         # no mesh active (single-process dryrun/tests): the constraint
         # would be a no-op anyway, and an empty mesh makes it a hard error

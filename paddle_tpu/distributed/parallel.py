@@ -105,10 +105,7 @@ def init_parallel_env():
     )
     # NOTE: no jax API may run before jax.distributed.initialize — even
     # jax.devices()/process_count() would initialize the XLA backend.
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:  # older jax
-        already = False
+    already = jax.distributed.is_initialized()
     if coord and not already and os.environ.get("PADDLE_TRAINERS_NUM"):
         if os.environ.get("PADDLE_DISTRIBUTED_BACKEND", "") == "gloo":
             jax.config.update("jax_platforms", "cpu")

@@ -338,6 +338,11 @@ class ShardedOptimizer:
             return jax.device_put(arr, sh)
 
         optimizer._accumulator_transform = _transform
+        # born sharded, and complete before any step is traced: steps thread
+        # the INNER optimizer (this wrapper owns no arrays), so nothing else
+        # would create the int8 residuals ahead of the first trace — and a
+        # state pytree that grows inside step 1 compiles step 2 again
+        self._ensure_accumulators()
 
     # -- placement helpers ---------------------------------------------------
     def _constrain(self, v, sharding):

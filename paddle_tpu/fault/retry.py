@@ -20,7 +20,7 @@ __all__ = ["retry", "retriable", "TransientError"]
 
 class TransientError(OSError):
     """An error the caller believes is transient (injected faults, flaky
-    filesystems/tunnels). Subclasses OSError so default retry_on catches
+    filesystems). Subclasses OSError so default retry_on catches
     it."""
 
 

@@ -30,17 +30,26 @@ class Place:
         return f"Place({self.device_type}:{self.device_id})"
 
     def jax_device(self):
-        devs = [d for d in jax.devices() if d.platform == self.device_type]
-        if not devs:
-            devs = jax.devices()
-        return devs[min(self.device_id, len(devs) - 1)]
+        """The jax device this place names. A place that names no device of
+        this process raises: ``TPUPlace(3)`` on one chip is an error, not
+        chip 0, and an accelerator place on a CPU-only host is not the CPU."""
+        devs = self._devices()
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self!r} names no device: this process has {len(devs)} "
+                f"{self.device_type} device(s) "
+                f"(default backend {jax.default_backend()!r})")
+        return devs[self.device_id]
+
+    def _devices(self):
+        return [d for d in jax.devices() if d.platform == self.device_type]
 
 
 class CPUPlace(Place):
     device_type = "cpu"
 
-    def jax_device(self):
-        return jax.local_devices(backend="cpu")[self.device_id] if _has_cpu() else jax.devices()[0]
+    def _devices(self):
+        return jax.local_devices(backend="cpu")
 
 
 class TPUPlace(Place):
@@ -74,13 +83,6 @@ class CustomPlace(Place):
         self.device_type = device_type
 
 
-def _has_cpu():
-    try:
-        return bool(jax.local_devices(backend="cpu"))
-    except RuntimeError:
-        return False
-
-
 _current_device = None
 
 
@@ -93,16 +95,20 @@ def _default_place() -> Place:
 
 
 def set_device(device: str) -> Place:
-    """paddle.set_device — accepts 'cpu', 'tpu', 'tpu:0', 'gpu' (alias)."""
+    """paddle.set_device — accepts 'cpu', 'tpu', 'tpu:0', 'gpu' (alias for
+    the accelerator). Asking for an accelerator this process does not have
+    raises; it is never answered with the CPU."""
     global _current_device
     name, _, idx = device.partition(":")
     idx = int(idx) if idx else 0
     if name == "cpu":
-        _current_device = CPUPlace(idx)
+        place = CPUPlace(idx)
     elif name in ("tpu", "gpu", "xpu", "npu", "mlu"):
-        _current_device = TPUPlace(idx) if jax.default_backend() != "cpu" else CPUPlace(idx)
+        place = TPUPlace(idx)
     else:
         raise ValueError(f"unknown device {device!r}")
+    place.jax_device()  # raises when the place names no device
+    _current_device = place
     return _current_device
 
 

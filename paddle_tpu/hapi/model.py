@@ -68,6 +68,7 @@ class Model:
         self._remat = None
         self._remat_applied = False
         self._remat_report = None
+        self._batch_mesh = None  # (mesh, axis) once prepare(zero=) ran
 
     # ------------------------------------------------------------------
     # setup
@@ -110,6 +111,7 @@ class Model:
                 zero if isinstance(zero, str) else mesh.axis_names[0])
             optimizer = ShardedOptimizer(optimizer, axis=axis, mesh=mesh,
                                          **cfg)
+            self._batch_mesh = (mesh, axis)
         self._optimizer = optimizer
         if loss is not None and not (isinstance(loss, Layer) or callable(loss)):
             raise TypeError("loss must be a Layer or callable")
@@ -285,6 +287,29 @@ class Model:
     # ------------------------------------------------------------------
     # epoch loops (reference model.py:1574 fit / :1743 evaluate / :1852 predict)
     # ------------------------------------------------------------------
+    def _device_loader(self, batches):
+        """Stage batches onto the device(s). Under ``prepare(zero=...)``
+        each batch is split over the data axis of the mesh — left to the
+        default, the whole batch would land on chip 0 and every chip would
+        compute all of it. A batch the axis does not divide (a short last
+        one) is replicated over the mesh instead."""
+        from ..io.device_loader import DeviceLoader
+
+        if self._batch_mesh is None:
+            return DeviceLoader(batches)
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        mesh, axis = self._batch_mesh
+        n = mesh.shape[axis]
+
+        def place(a):
+            split = getattr(a, "ndim", 0) and a.shape[0] % n == 0
+            return jax.device_put(
+                a, NamedSharding(mesh, P(axis) if split else P()))
+
+        return DeviceLoader(batches, place_fn=place)
+
     def _loader(self, data, batch_size, shuffle, num_workers, drop_last=False):
         from ..io import DataLoader, Dataset
 
@@ -379,10 +404,8 @@ class Model:
         loader = self._loader(test_data, batch_size, False, num_workers)
         cbks = config_callbacks(callbacks, model=self, verbose=verbose)
         cbks.on_begin("predict")
-        from ..io.device_loader import DeviceLoader
-
         outputs = []
-        for step, batch in enumerate(DeviceLoader(loader)):
+        for step, batch in enumerate(self._device_loader(loader)):
             batch = _to_list(batch)
             # labeled datasets: drop the trailing label column(s)
             if self._loss is not None and len(batch) >= 2:
@@ -411,7 +434,6 @@ class Model:
                        fault_sess=None, epoch=0):
         import itertools
 
-        from ..io.device_loader import DeviceLoader
         from ..metric import AsyncMetricBuffer
         from ..profiler import telemetry, tracing
 
@@ -449,7 +471,8 @@ class Model:
         if tr_on:
             epoch_span = tracing.start_span(
                 f"{mode}_epoch", attrs={"epoch": epoch, "mode": mode})
-        for step, batch in enumerate(DeviceLoader(src), start=skip_steps):
+        for step, batch in enumerate(self._device_loader(src),
+                                     start=skip_steps):
             batch = _to_list(batch)
             # convention: trailing element(s) are labels when a loss is set
             if self._loss is not None and len(batch) >= 2:

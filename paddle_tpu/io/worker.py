@@ -34,6 +34,8 @@ from multiprocessing import shared_memory
 
 import numpy as np
 
+from .collate import default_collate_fn, numpy_collate_fn, tensors_from_numpy
+
 __all__ = ["ProcessPool", "WorkerFailure"]
 
 _STOP = "__stop__"
@@ -143,6 +145,13 @@ def _drop_shm(meta):
 
 def _worker_loop(wid, num_workers, dataset, collate_fn, task_q, result_q,
                  worker_init_fn, use_shared_memory, iterable_cfg, base_seed):
+    # One process per chip: the parent holds the accelerator, so nothing a
+    # worker runs may reach for it. The default collate stays in numpy (see
+    # ProcessPool), and user code that does touch jax here gets the CPU.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     from .dataloader import WorkerInfo, _worker_info
 
     _worker_info.info = WorkerInfo(wid, num_workers, dataset)
@@ -239,8 +248,13 @@ class ProcessPool:
         self._epoch = 0
         self._busy = False   # one live iterator at a time (epoch tags)
         base_seed = int.from_bytes(os.urandom(4), "little")
+        # the default collate makes Tensors, i.e. jax arrays: workers run
+        # its numpy twin and the parent wraps the leaves on receipt
+        self._wrap_tensors = loader.collate_fn is default_collate_fn
+        collate_fn = (numpy_collate_fn if self._wrap_tensors
+                      else loader.collate_fn)
         # capture spawn args (not the loader: its __del__ owns this pool)
-        spawn_args = (self._nw, loader.dataset, loader.collate_fn,
+        spawn_args = (self._nw, loader.dataset, collate_fn,
                       self._task_q, self._result_q, loader.worker_init_fn,
                       loader.use_shared_memory, iterable_cfg, base_seed)
         self._spawn = lambda w: ctx.Process(
@@ -315,6 +329,8 @@ class ProcessPool:
             return (_EPOCH_END, idx)
         batch = (_decode_shm(payload) if not payload.get("pickled")
                  else payload["data"])
+        if self._wrap_tensors:
+            batch = tensors_from_numpy(batch)
         return ("ok", idx, batch)
 
     # -- map-style epochs ---------------------------------------------------

@@ -22,12 +22,14 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from ..framework import random as rnd
 from ..profiler import telemetry as _telemetry
 from ..profiler import tracing as _tracing
 from ..framework.tensor import Tensor
 from ..nn.layer.layers import Layer
+from ..ops import partition as _partition
 from ..optimizer.optimizer import Optimizer
 
 __all__ = ["functionalize", "CompiledStep", "to_static", "not_to_static"]
@@ -206,6 +208,26 @@ def _arg_path_str(path):
     return base + jax.tree_util.keystr(rest)
 
 
+def _onto_mesh(state, mesh):
+    """State leaves still on one device (the optimizer's step counter, the
+    RNG key — born on the default device) move onto the batch's mesh,
+    replicated. Left there they stay on chip 0 while every other leaf is
+    on the mesh, and they come back from the first step with a mesh
+    sharding: that change of signature alone recompiles the second step.
+    (A mesh that spans processes is left alone: a process-local array cannot
+    be device_put onto devices this process does not address.)"""
+    if mesh.is_multi_process:
+        return state
+    rep = NamedSharding(mesh, PartitionSpec())
+
+    def put(v):
+        if isinstance(getattr(v, "sharding", None), SingleDeviceSharding):
+            return jax.device_put(v, rep)
+        return v
+
+    return jax.tree_util.tree_map(put, state)
+
+
 class CompiledStep:
     """A cached compiled XLA step (≙ the reference's compiled-program cache in
     ``fluid/executor.py`` + InterpreterCore instruction list)."""
@@ -251,7 +273,7 @@ class CompiledStep:
 
         def pure(state, dyn_donated, dyn_kept, static_spec):
             marker["traced"] = True
-            treedef, static_leaves, don_mask = static_spec
+            treedef, static_leaves, don_mask, partition = static_spec
             it_d, it_k, it_m = iter(dyn_donated), iter(dyn_kept), iter(don_mask)
             if static_leaves is None:
                 leaves = [next(it_d) if next(it_m) else next(it_k)
@@ -266,7 +288,10 @@ class CompiledStep:
             try:
                 t_args = jax.tree_util.tree_map(_wrap, args)
                 t_kwargs = jax.tree_util.tree_map(_wrap, kwargs)
-                out = fn(*t_args, **t_kwargs)
+                # the mesh the batch arrived on: Pallas call sites partition
+                # themselves over it (GSPMD cannot split a Mosaic kernel)
+                with _partition.partition_scope(partition):
+                    out = fn(*t_args, **t_kwargs)
                 out_arrays = jax.tree_util.tree_map(_unwrap, out)
                 new_state = spec.snapshot()
             finally:
@@ -306,13 +331,16 @@ class CompiledStep:
                                    len(dyn))
         dyn_donated = [l for l, m in zip(dyn, mask) if m]
         dyn_kept = [l for l, m in zip(dyn, mask) if not m]
-        return dyn_donated, dyn_kept, (treedef, spec_t, mask)
+        return dyn_donated, dyn_kept, (treedef, spec_t, mask,
+                                       _partition.observed_partition(dyn))
 
     def _invoke(self, args, kwargs):
         from ..fault import inject
 
         state = self.spec.snapshot()
         dyn_donated, dyn_kept, static = self._prepare(args, kwargs)
+        if static[3] is not None:
+            state = _onto_mesh(state, static[3][0])
         try:
             inject.check("dispatch")  # oom/error injection (devprof tests)
             out_arrays, new_state = self._jitted(state, dyn_donated, dyn_kept,

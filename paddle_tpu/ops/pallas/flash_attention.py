@@ -544,11 +544,14 @@ def supports(seq_q, seq_k, head_dim=None,
 # length-masked (cached) forward — serving prefill / chunked prefill / verify
 # ---------------------------------------------------------------------------
 
-def _cached_fwd_kernel(q_ref, k_ref, v_ref, qpos_ref, klen_ref, o_ref,
+def _cached_fwd_kernel(klen_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
                        acc_ref, m_ref, l_ref, *, scale, block_k):
     """Online-softmax sweep with per-row validity from streamed positions:
     key slot j attends iff ``j <= q_pos[row]`` and ``j < kv_len[batch]`` —
-    the LengthMask contract — so no dense bias ever reaches HBM."""
+    the LengthMask contract — so no dense bias ever reaches HBM.
+    ``klen_ref`` is the whole ``(batch,)`` kv_len vector, scalar-prefetched
+    into SMEM (a per-batch ``(1, 1)`` SMEM block does not lower at
+    batch > 1: Mosaic wants the last two block dims tile-aligned)."""
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -564,7 +567,7 @@ def _cached_fwd_kernel(q_ref, k_ref, v_ref, qpos_ref, klen_ref, o_ref,
     ) * scale
     cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     qpos = qpos_ref[0, 0][:, 0:1]
-    valid = (cols <= qpos) & (cols < klen_ref[0, 0])
+    valid = (cols <= qpos) & (cols < klen_ref[pl.program_id(0)])
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_ref[:, 0:1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -591,40 +594,42 @@ def _flash_cached_impl(q, k, v, qpos, klen, scale, block_q, block_k,
     sk = k.shape[2]
     nq, nk = sq // block_q, sk // block_k
 
-    def qmap(bb, hh, qi, ki):
+    # index maps take the scalar-prefetch ref as a trailing argument
+    def qmap(bb, hh, qi, ki, klen_ref):
         return (bb, hh, qi, 0)
 
-    def kmap(bb, hh, qi, ki):
+    def kmap(bb, hh, qi, ki, klen_ref):
         return (bb, hh, ki, 0)
 
     kernel = functools.partial(_cached_fwd_kernel, scale=scale,
                                block_k=block_k)
     return pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), qmap),
-            pl.BlockSpec((1, 1, block_k, d), kmap),
-            pl.BlockSpec((1, 1, block_k, d), kmap),
-            pl.BlockSpec((1, 1, block_q, STAT_LANES),
-                         lambda bb, hh, qi, ki: (bb, 0, qi, 0)),
-            pl.BlockSpec((1, 1), lambda bb, hh, qi, ki: (bb, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d), qmap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, d), qmap),
+                pl.BlockSpec((1, 1, block_k, d), kmap),
+                pl.BlockSpec((1, 1, block_k, d), kmap),
+                pl.BlockSpec((1, 1, block_q, STAT_LANES),
+                             lambda bb, hh, qi, ki, klen_ref: (bb, 0, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, d), qmap),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-        ],
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=int(4 * b * h * sq * sk * d),
             bytes_accessed=int(2 * (q.size + k.size + v.size + q.size)),
             transcendentals=int(b * h * sq * sk),
         ),
-    )(q, k, v, qpos, klen)
+    )(klen, q, k, v, qpos)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -675,6 +680,7 @@ def flash_attention_cached(q, k, v, q_pos, kv_len=None, *, scale=None,
     steps. Returns ``(batch, seq_q, heads, head_dim)``.
     """
     from ...framework.flags import flag_value
+    from ..partition import batch_sharded
     from . import interpret_requested
 
     if interpret is None:
@@ -697,10 +703,14 @@ def flash_attention_cached(q, k, v, q_pos, kv_len=None, *, scale=None,
     qpos = jnp.broadcast_to(
         jnp.asarray(q_pos, jnp.int32)[:, None, :, None],
         (b, 1, sq, STAT_LANES))
-    klen = (jnp.full((b, 1), sk, jnp.int32) if kv_len is None
-            else jnp.asarray(kv_len, jnp.int32).reshape(b, 1))
-    out = _flash_cached(qt, kt, vt, qpos, klen, float(scale), int(block_q),
-                        int(block_k), bool(interpret))
+    klen = (jnp.full((b,), sk, jnp.int32) if kv_len is None
+            else jnp.asarray(kv_len, jnp.int32).reshape(b))
+
+    def call(qt, kt, vt, qpos, klen):
+        return _flash_cached(qt, kt, vt, qpos, klen, float(scale),
+                             int(block_q), int(block_k), bool(interpret))
+
+    out = batch_sharded(call, (qt, kt, vt, qpos, klen), (True,) * 5)
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -732,6 +742,7 @@ def flash_attention(q, k, v, bias=None, *, causal=False, scale=None,
     Returns ``(batch, seq_q, heads, head_dim)``.
     """
     from ...framework.flags import flag_value
+    from ..partition import batch_sharded
     from . import interpret_requested
 
     if interpret is None:
@@ -791,7 +802,14 @@ def flash_attention(q, k, v, bias=None, *, causal=False, scale=None,
         else:
             bias = bias.astype(jnp.float32)
         bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
-    out = _flash(qt, kt, vt, bias, seed, float(scale), bool(causal),
-                 int(block_q), int(block_k), bool(interpret),
-                 bool(bias_grad) and bias is not None, dropout_p)
+
+    def call(qt, kt, vt, bias, seed):
+        return _flash(qt, kt, vt, bias, seed, float(scale), bool(causal),
+                      int(block_q), int(block_k), bool(interpret),
+                      bool(bias_grad) and bias is not None, dropout_p)
+
+    per_batch_bias = bias is not None and bias.shape[0] > 1
+    out = batch_sharded(call, (qt, kt, vt, bias, seed),
+                        (True, True, True, per_batch_bias, False),
+                        seed_index=4)
     return jnp.swapaxes(out, 1, 2)

@@ -555,6 +555,7 @@ def flash_attention_packed(q, k, v, num_heads, bias=None, *, causal=False,
     per-batch/per-head biases.
     """
     from ...framework.flags import flag_value
+    from ..partition import batch_sharded
     from . import interpret_requested
 
     if interpret is None:
@@ -646,6 +647,11 @@ def flash_attention_packed(q, k, v, num_heads, bias=None, *, causal=False,
             bias = jnp.where(bias, 0.0, NEG_INF).astype(jnp.float32)
         else:
             bias = bias.astype(jnp.float32)
-    return _flash(q, k, v, bias, seed, h, float(scale), bool(causal),
-                  int(block_q), int(block_k), bool(interpret), dropout_p,
-                  int(bwd_block))
+
+    def call(q, k, v, bias, seed):
+        return _flash(q, k, v, bias, seed, h, float(scale), bool(causal),
+                      int(block_q), int(block_k), bool(interpret), dropout_p,
+                      int(bwd_block))
+
+    return batch_sharded(call, (q, k, v, bias, seed),
+                         (True, True, True, False, False), seed_index=4)

@@ -117,6 +117,7 @@ def supports(features):
 def fused_layer_norm(x, gamma, beta, eps=1e-5, interpret=None):
     """LayerNorm over the last axis. ``x``: (..., features); ``gamma``/``beta``:
     (features,). Returns the same shape/dtype as ``x``."""
+    from ..partition import batch_sharded
     from . import interpret_requested
 
     if interpret is None:
@@ -124,18 +125,24 @@ def fused_layer_norm(x, gamma, beta, eps=1e-5, interpret=None):
     feat = x.shape[-1]
     if not supports(feat):
         raise ValueError(f"fused_layer_norm needs features % {LANES} == 0, got {feat}")
-    lead = x.shape[:-1]
-    rows = 1
-    for s in lead:
-        rows *= s
-    x2 = x.reshape(rows, feat)
-    # sublane-aligned row block; pad rows to a block multiple (padded rows
-    # carry zero cotangents through the slice below, so grads are exact)
-    block_rows = min(BLOCK_ROWS, -(-rows // 8) * 8)
-    pad = -rows % block_rows
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    out = _ln(x2, gamma.reshape(1, feat), beta.reshape(1, feat),
-              float(eps), int(block_rows), bool(interpret))
-    out = out[:rows]
-    return out.reshape(*lead, feat)
+
+    def call(x, gamma, beta):
+        lead = x.shape[:-1]
+        rows = 1
+        for s in lead:
+            rows *= s
+        x2 = x.reshape(rows, feat)
+        # sublane-aligned row block; pad rows to a block multiple (padded
+        # rows carry zero cotangents through the slice below, so grads are
+        # exact)
+        block_rows = min(BLOCK_ROWS, -(-rows // 8) * 8)
+        pad = -rows % block_rows
+        if pad:
+            x2 = jnp.pad(x2, ((0, pad), (0, 0)))
+        out = _ln(x2, gamma.reshape(1, feat), beta.reshape(1, feat),
+                  float(eps), int(block_rows), bool(interpret))
+        return out[:rows].reshape(*lead, feat)
+
+    # rows are independent: dim 0 shards over the batch axes (a bare
+    # (features,) vector has no batch dim to shard)
+    return batch_sharded(call, (x, gamma, beta), (x.ndim > 1, False, False))

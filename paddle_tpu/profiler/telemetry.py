@@ -152,6 +152,7 @@ class Telemetry(Monitor):
         self._current = None
         self._next_step = 0
         self._compiles = {}
+        self._compile_s = {}
         self._warned = set()
         # step-name -> declared executable-variant count: bucketed programs
         # (one prefill executable per length bucket) compile N times BY
@@ -254,6 +255,8 @@ class Telemetry(Monitor):
             self._counters["compile.count"] = \
                 self._counters.get("compile.count", 0) + 1
             n = self._compiles[key] = self._compiles.get(key, 0) + 1
+            self._compile_s[key] = (self._compile_s.get(key, 0.0)
+                                    + (end_ns - start_ns) / 1e9)
             threshold = max(self.recompile_warn_threshold,
                             self._declared.get(key, 1))
             warn = n > threshold and key not in self._warned
@@ -270,6 +273,12 @@ class Telemetry(Monitor):
     def compile_counts(self):
         with self._lock:
             return dict(self._compiles)
+
+    def compile_seconds(self):
+        """Seconds spent in calls that traced, per step-name: trace + XLA
+        compile (or persistent-cache load) + that call's own execution."""
+        with self._lock:
+            return dict(self._compile_s)
 
     def declare_variants(self, key, n):
         """Declare that step ``key`` legitimately compiles up to ``n``
@@ -533,6 +542,7 @@ class Telemetry(Monitor):
             self._current = None
             self._next_step = 0
             self._compiles.clear()
+            self._compile_s.clear()
             self._warned.clear()
 
 

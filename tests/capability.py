@@ -37,8 +37,6 @@ if os.environ.get("PADDLE_TPU_HW_TESTS") != "1":
 import numpy as np
 import jax
 import jax.numpy as jnp
-from paddle_tpu.framework.jax_compat import ensure_jax_compat
-ensure_jax_compat()
 from jax.sharding import NamedSharding, PartitionSpec as P
 from paddle_tpu.distributed import fleet
 from paddle_tpu.framework.tensor import Tensor

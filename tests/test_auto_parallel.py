@@ -72,6 +72,9 @@ def _mlp():
 
 
 def test_engine_fit_eval_predict():
+    # the epoch shuffle draws from the global host RNG; unseeded, about one
+    # order in twelve leaves the loss ratio just above the 0.5 bar
+    np.random.seed(0)
     net = _mlp()
     opt = paddle.optimizer.Adam(learning_rate=0.01, parameters=net.parameters())
     engine = Engine(model=net, loss=paddle.nn.MSELoss(), optimizer=opt)

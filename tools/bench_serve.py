@@ -311,6 +311,9 @@ def main(argv=None):
                          "bench_sentinel 'equal'-direction gate)")
     args = ap.parse_args(argv)
 
+    from paddle_tpu.framework.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.concurrency = min(args.concurrency, 4)
     n_req = args.requests or 2 * args.concurrency

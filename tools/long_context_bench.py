@@ -60,11 +60,13 @@ def main():
 
     import paddle_tpu as paddle
     from paddle_tpu import analysis
+    from paddle_tpu.framework.compile_cache import enable_compile_cache
     from paddle_tpu.framework.flags import set_flags
     from paddle_tpu.framework.tensor import Tensor
     from paddle_tpu.jit.functionalize import CompiledStep
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
+    enable_compile_cache()
     on_tpu = jax.default_backend() != "cpu"
     predict_only = (not on_tpu if args.predict_only is None
                     else args.predict_only)

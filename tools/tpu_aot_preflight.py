@@ -1,0 +1,267 @@
+"""AOT pre-flight: compile the TPU programs here, where there is no TPU.
+
+libtpu ships in this environment, so
+``jax.experimental.topologies.get_topology_desc(platform="tpu",
+topology_name="v5e:2x2")`` hands back four compile-only ``TPU v5 lite``
+devices and ``jax.jit(f).lower(<ShapeDtypeStruct placed on them>).compile()``
+runs the real XLA:TPU + Mosaic compilers under ``JAX_PLATFORMS=cpu``. What
+it proves: the program lowers, every Pallas kernel passes Mosaic, and the
+buffers fit. What it cannot: donation, readback, run-time HBM, numerics —
+those are ``chip_smoke.py``'s, on the chip. It costs no chip budget.
+
+    JAX_PLATFORMS=cpu python tools/tpu_aot_preflight.py           # kernels
+    JAX_PLATFORMS=cpu python tools/tpu_aot_preflight.py --steps   # + the
+        full-width GPT-2 124M train step (one chip; four chips dp/ZeRO)
+        and the serving steps chip_smoke.py runs
+
+One line per program: ``ok``/``FAIL``, compile seconds, and for the sharded
+programs the operand shapes the per-device Mosaic calls see. Exit code 1 if
+anything failed, 2 if the topology is unavailable.
+"""
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+import time
+
+# the repo root: paddle_tpu and chip_smoke.py import from there
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+from chip_smoke import mosaic_operand_shapes
+
+TOPOLOGY = "v5e:2x2"
+
+
+def topology_devices():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name=TOPOLOGY).devices
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+class Report:
+    def __init__(self):
+        self.failed = []
+
+    def run(self, name, build):
+        """``build()`` returns a ``jax.stages.Lowered``; compile and report."""
+        t0 = time.perf_counter()
+        try:
+            compiled = build().compile()
+        except Exception as e:  # noqa: BLE001 - the tool's job is to list them
+            self.failed.append(name)
+            msg = str(e).strip().splitlines()
+            print(f"FAIL {name}: {type(e).__name__}: {msg[0] if msg else ''}",
+                  flush=True)
+            return None
+        dt = time.perf_counter() - t0
+        n = len(mosaic_operand_shapes(compiled.as_text()))
+        print(f"ok   {name}: {dt:.1f}s compile, {n} Mosaic call(s)",
+              flush=True)
+        return compiled
+
+
+# ---------------------------------------------------------------------------
+# kernels, one GPT-2 shape each (h12 d64 s1024, bf16)
+# ---------------------------------------------------------------------------
+def kernel_programs(devs):
+    from paddle_tpu.ops.pallas import (flash_attention, flash_attention_cached,
+                                       fused_layer_norm)
+    from paddle_tpu.ops.pallas.flash_attention_packed import (
+        flash_attention_packed)
+    from paddle_tpu.ops.partition import partition_scope
+
+    one = SingleDeviceSharding(devs[0])
+    b, s, h, d = 2, 1024, 12, 64
+    bf = jnp.bfloat16
+
+    def grad_of(fn, n):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+            argnums=tuple(range(n))))
+
+    x3 = _sds((b, s, h * d), bf, one)
+    x4 = _sds((b, s, h, d), bf, one)
+    gam = _sds((h * d,), jnp.float32, one)
+    yield "layer_norm fwd+bwd", lambda: grad_of(
+        lambda x, g, be: fused_layer_norm(x, g, be), 3).lower(x3, gam, gam)
+    yield "flash packed causal fwd+bwd", lambda: grad_of(
+        lambda q, k, v: flash_attention_packed(q, k, v, h, causal=True),
+        3).lower(x3, x3, x3)
+    yield "flash packed dropout fwd+bwd", lambda: grad_of(
+        lambda q, k, v, seed: flash_attention_packed(
+            q, k, v, h, causal=True, dropout_p=0.1, dropout_seed=seed),
+        3).lower(x3, x3, x3, _sds((2,), jnp.int32, one))
+    yield "flash layout-swapping bias fwd+bwd", lambda: grad_of(
+        lambda q, k, v, bias: flash_attention(q, k, v, bias, causal=True,
+                                              bias_grad=False),
+        3).lower(x4, x4, x4, _sds((b, 1, s, s), jnp.float32, one))
+    # serving reaches the cached kernel at batch 1 (a 1024 bucket, a 128
+    # chunk); batch 8 is what F.sdpa with a LengthMask gives any other caller
+    for cb, sq in ((1, 1024), (8, 128)):
+        q = _sds((cb, sq, h, d), bf, one)
+        kv = _sds((cb, s, h, d), bf, one)
+        yield (f"flash cached b{cb} sq{sq} sk{s}",
+               lambda q=q, kv=kv, cb=cb, sq=sq: jax.jit(
+                   flash_attention_cached).lower(
+                       q, kv, kv, _sds((cb, sq), jnp.int32, one),
+                       _sds((cb,), jnp.int32, one)))
+
+    # LN + flash inside one jit sharded over the 4-device mesh
+    mesh = Mesh(np.array(devs), ("dp",))
+    row = NamedSharding(mesh, P("dp"))
+    rep = NamedSharding(mesh, P())
+    gb = 8
+
+    def block(x, g, be):
+        with partition_scope((mesh, ("dp",))):
+            y = fused_layer_norm(x, g, be)
+            return flash_attention_packed(y, y, y, h, causal=True)
+
+    yield "dp4: layer_norm + flash fwd+bwd", lambda: grad_of(block, 3).lower(
+        _sds((gb, s, h * d), bf, row), _sds((h * d,), jnp.float32, rep),
+        _sds((h * d,), jnp.float32, rep))
+    qs = _sds((gb, 128, h, d), bf, row)
+    kvs = _sds((gb, s, h, d), bf, row)
+
+    def cached(q, k, v, qp, kl):
+        with partition_scope((mesh, ("dp",))):
+            return flash_attention_cached(q, k, v, qp, kl)
+
+    yield "dp4: flash cached b8", lambda: jax.jit(cached).lower(
+        qs, kvs, kvs, _sds((gb, 128), jnp.int32, row),
+        _sds((gb,), jnp.int32, row))
+
+
+# ---------------------------------------------------------------------------
+# the full-width steps chip_smoke.py runs
+# ---------------------------------------------------------------------------
+def lower_step(step, args, place_state, place_arg):
+    """Lower a CompiledStep for the topology: state and arguments become
+    ShapeDtypeStructs placed by ``place_state(array)``/``place_arg(array)``."""
+    def abstract(place):
+        return lambda a: _sds(a.shape, a.dtype, place(a))
+
+    state = jax.tree_util.tree_map(abstract(place_state),
+                                   step.spec.snapshot())
+    args = jax.tree_util.tree_map(
+        abstract(place_arg), jax.tree_util.tree_map(jnp.asarray, args))
+    dyn_donated, dyn_kept, static = step._prepare(args, {})
+    return step._jitted.lower(state, dyn_donated, dyn_kept, static)
+
+
+def step_programs(devs):
+    one = SingleDeviceSharding(devs[0])
+    model, opt, step = chip_smoke.build_trainer(chip_smoke.FULL)
+    ids = np.zeros((chip_smoke.FULL.batch, chip_smoke.FULL.seq), np.int32)
+    yield "train_step b24 s1024 (1 chip)", lambda: lower_step(
+        step, (ids, ids), lambda a: one, lambda a: one)
+
+    # The topology's devices compile but hold no data, so the ZeRO trainer
+    # cannot really place its state on them: while it is built, a
+    # device_put onto the topology mesh leaves the array where it is and
+    # only records the sharding it asked for, which is what lowering needs.
+    mesh = Mesh(np.array(devs), ("dp",))
+    wanted = {}
+    real_put = jax.device_put
+
+    def recording_put(x, device=None, **kw):
+        if isinstance(device, NamedSharding) and device.mesh is mesh:
+            wanted[id(x)] = device
+            return x
+        return real_put(x, device, **kw)
+
+    for quantize in (None, "int8"):
+        wanted.clear()  # ids of the previous trainer's arrays are stale
+        jax.device_put = recording_put
+        try:
+            model, opt, step = chip_smoke.build_trainer(
+                chip_smoke.FULL, mesh=mesh, quantize=quantize)
+        finally:
+            jax.device_put = real_put
+        rep = NamedSharding(mesh, P())
+        yield (f"train_step b24 s1024 (dp4 ZeRO, wire "
+               f"{quantize or 'fp32'})"), lambda step=step: lower_step(
+                   step, (ids, ids),
+                   lambda a, wanted=dict(wanted): wanted.get(id(a), rep),
+                   lambda a: NamedSharding(mesh, P("dp")))
+
+    # freeze_weights=False is what "auto" resolves to on the chip: the
+    # weights ride as donated state (on this CPU host "auto" would fold
+    # them into the executables as constants — another program)
+    place = (lambda a: one)
+    for label, kw, prompt_lens, _ in chip_smoke.SERVE_LEGS:
+        eng = chip_smoke.build_engine(chip_smoke.FULL, freeze_weights=False,
+                                      **kw)
+        yield f"serve_decode ({label})", lambda eng=eng: lower_step(
+            eng.decode_step, eng.example_decode_args([1]), place, place)
+        for bucket in sorted(chip_smoke.bucketed_prompts(eng, prompt_lens)):
+            yield (f"serve_prefill bucket {bucket} ({label})",
+                   lambda eng=eng, bucket=bucket: lower_step(
+                       eng.prefill_step,
+                       (np.zeros((1, bucket), np.int32), np.int32(1),
+                        np.int32(0), eng._example_cache([0])), place, place))
+        if eng.verify_step is not None:
+            yield f"serve_verify ({label})", lambda eng=eng: lower_step(
+                eng.verify_step, eng.example_verify_args([1]), place, place)
+        if eng.chunk_step is not None:
+            yield f"serve_prefill_chunk ({label})", lambda eng=eng: lower_step(
+                eng.chunk_step, eng.example_chunk_args([0]), place, place)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--steps", action="store_true",
+                    help="also compile the full-width chip_smoke.py steps")
+    args = ap.parse_args(argv)
+    try:
+        devs = topology_devices()
+    except Exception as e:  # noqa: BLE001 - no libtpu / unknown topology
+        print(f"topology {TOPOLOGY} unavailable: {type(e).__name__}: {e}")
+        return 2
+    print(f"jax {jax.__version__}; compiling for {len(devs)} x "
+          f"{devs[0].device_kind} ({TOPOLOGY})", flush=True)
+    # The process's own backend is the CPU, so the routers would pick the
+    # XLA formulations; the programs below are lowered for the topology's
+    # TPU devices, where the kernels are what runs.
+    from paddle_tpu.ops import pallas
+
+    pallas.is_available = lambda: True
+
+    rep = Report()
+    for name, build in kernel_programs(devs):
+        compiled = rep.run(name, build)
+        if compiled is not None and name.startswith("dp4"):
+            for shapes in sorted(set(
+                    mosaic_operand_shapes(compiled.as_text()))):
+                print(f"       per-device Mosaic operands: "
+                      f"{' '.join(shapes)}", flush=True)
+    if args.steps:
+        for name, build in step_programs(devs):
+            compiled = rep.run(name, build)
+            if compiled is not None:
+                ma = compiled.memory_analysis()
+                print(f"       temp {ma.temp_size_in_bytes / 2**30:.2f} GiB, "
+                      f"arguments {ma.argument_size_in_bytes / 2**30:.2f} GiB",
+                      flush=True)
+    if rep.failed:
+        print(f"{len(rep.failed)} program(s) failed: {rep.failed}")
+        return 1
+    print("all programs compiled")
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
