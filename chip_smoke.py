@@ -453,7 +453,8 @@ def leg_serve(size, label, engine_kw, prompt_lens, new_tokens):
         f"flash_sdpa_cached x{seen['flash_sdpa_cached']} blockwise_sdpa "
         f"x{seen['blockwise_sdpa']} sdpa x{seen['sdpa']}")
     say(f"serve[{label}]: seconds in calls that compiled, per step "
-        f"{ {k: round(v, 1) for k, v in tm.compile_seconds().items()} }")
+        f"(trace, lower, backend, first run) "
+        f"{ {k: [round(x, 1) for x in v.values()] for k, v in tm.compile_seconds().items()} }")
     say(f"serve[{label}]: first pass (with compiles) {cold_s:.1f}s, replay "
         f"{warm_s:.1f}s; spec_ticks {counters.get('serve.spec_ticks', 0)} "
         f"prefill_chunks {counters.get('serve.prefill_chunks', 0)}; "

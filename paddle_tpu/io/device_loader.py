@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 
 import numpy as np
 import jax
@@ -97,15 +96,13 @@ class DeviceLoader:
 
         inject.check("stage")  # transient-stage-error injection point
         # Tensors are opaque to tree_flatten, so they arrive here as leaves
+        with _telemetry.phase_span("h2d_copy"):
+            staged = jax.tree_util.tree_map(self._stage_leaf, batch)
         if not _telemetry.enabled():
-            return jax.tree_util.tree_map(self._stage_leaf, batch)
-        t0 = time.perf_counter_ns()
-        staged = jax.tree_util.tree_map(self._stage_leaf, batch)
-        t1 = time.perf_counter_ns()
+            return staged
         nbytes = sum(_leaf_bytes(l)
                      for l in jax.tree_util.tree_leaves(batch))
         tm = _telemetry.get_telemetry()
-        tm.add_phase("h2d_copy", t0, t1)
         tm.inc("device_loader.batches_staged")
         tm.inc("device_loader.bytes_staged", nbytes)
         return staged
@@ -115,19 +112,17 @@ class DeviceLoader:
         staged (get_nowait succeeds); a *miss* blocks the consumer — that
         block IS the pipeline's data-wait, accumulated as stall time."""
         tm = _telemetry.get_telemetry()
-        t0 = time.perf_counter_ns()
-        try:
-            item = out_q.get_nowait()
-            hit = True
-        except queue.Empty:
-            hit = False
-            item = out_q.get()
-        t1 = time.perf_counter_ns()
-        tm.add_phase("data_wait", t0, t1)
+        with _telemetry.phase_span("data_wait") as wait:
+            try:
+                item = out_q.get_nowait()
+                hit = True
+            except queue.Empty:
+                hit = False
+                item = out_q.get()
         tm.inc("device_loader.prefetch_hit" if hit
                else "device_loader.prefetch_miss")
         if not hit:
-            tm.inc("device_loader.stall_s", (t1 - t0) / 1e9)
+            tm.inc("device_loader.stall_s", wait.duration_s)
         tm.set_gauge("device_loader.queue_depth", out_q.qsize())
         return item
 

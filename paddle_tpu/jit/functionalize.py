@@ -18,7 +18,6 @@ buckets), and fused-optimizer ops — XLA does the scheduling and fusion.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -242,6 +241,9 @@ class CompiledStep:
         self._trace_marker = {"traced": False}
         self.spec = _StateSpec(stateful)
         self._pure = self._build_pure()
+        # the program's name in a device trace and in the compile cache's
+        # key: `jit_<step name>` (jit_train_step, jit_serve_decode, ...)
+        self._pure.__name__ = self._pure.__qualname__ = self.name
         # donate_inputs: staged single-use batches (io.DeviceLoader) hand
         # their HBM back to XLA for the step's own temporaries. Contract:
         # donated inputs are CONSUMED — the caller must not touch a batch
@@ -367,12 +369,12 @@ class CompiledStep:
             # visible in the PRE-step state pytree; after one real step the
             # state has stabilized and the defect is invisible statically
             _analysis().autolint(self, args, kwargs, enabled=True)
-        tm_on = _telemetry.enabled()
-        # trace-context compile attribution: only worth timing when a span
-        # is actually current (a request's prefill, a train step, ...)
-        tr_on = _tracing.enabled() and _tracing.current_span() is not None
-        if not tm_on and not tr_on:
+        # the one boundary call: `dispatch` (a cached call: host enqueue
+        # time), renamed `compile` below if this call turns out to trace
+        span = _telemetry.phase_span("dispatch", key=self.name)
+        if span is _tracing.NULL_SPAN:
             return self._invoke(args, kwargs)
+        tm_on = _telemetry.enabled()
         marker = self._trace_marker
         marker["traced"] = False
         # capture the batch signature (shapes only) BEFORE the call: if it
@@ -385,29 +387,34 @@ class CompiledStep:
                 sig = _devprof()._shape_only((args, kwargs))
             except Exception:
                 sig = None
-        t0 = time.perf_counter_ns()
-        out = self._invoke(args, kwargs)
-        t1 = time.perf_counter_ns()
-        if marker["traced"]:
-            if tm_on:
-                # traced this call: wall time is dominated by trace+XLA
-                # compile; repeated hits here for one step name = shape/
-                # dtype churn
-                tm = _telemetry.get_telemetry()
-                tm.note_compile(self.name, t0, t1)
-                if sig is not None:
-                    # first compile: harvest the DeviceCostReport (memory/
-                    # cost/comm ground truth) into the telemetry registry
-                    _devprof().maybe_harvest_on_compile(self, sig[0], sig[1])
-            if tr_on:
-                # a `compile` child span under the current request/train
-                # span: the trace export shows who paid this compile
-                idx = (_telemetry.get_telemetry().compile_counts()
-                       .get(self.name) if tm_on else None)
-                _tracing.note_compile(self.name, t0, t1, compile_index=idx)
-        elif tm_on:
-            # cache hit: host-side enqueue of the async device execution
-            _telemetry.get_telemetry().add_phase("dispatch", t0, t1)
+        # JAX's own trace / lowering / backend-compile events of this
+        # thread belong to this step while the call is in flight
+        watch = _telemetry.compile_watch_begin() if tm_on else None
+        try:
+            with span:
+                out = self._invoke(args, kwargs)
+                if marker["traced"]:
+                    # traced this call: wall time is dominated by trace +
+                    # XLA compile; repeated hits here for one step name =
+                    # shape/dtype churn. In a request's (or train step's)
+                    # trace the span says who paid this compile
+                    span.name = "compile"
+                    span.set_attr("step", self.name)
+                    if tm_on:
+                        span.set_attr(
+                            "compile_index",
+                            _telemetry.get_telemetry().compile_counts()
+                            .get(self.name, 0) + 1)
+        finally:
+            if watch is not None:
+                _telemetry.compile_watch_end(watch)
+        if marker["traced"] and tm_on:
+            _telemetry.get_telemetry().note_compile(
+                self.name, span.start_ns, span.end_ns, watch)
+            if sig is not None:
+                # first compile: harvest the DeviceCostReport (memory/
+                # cost/comm ground truth) into the telemetry registry
+                _devprof().maybe_harvest_on_compile(self, sig[0], sig[1])
         return out
 
     def analyze(self, *args, **kwargs):

@@ -10,7 +10,6 @@ full between fences.
 """
 from __future__ import annotations
 
-import time
 
 import numpy as np
 
@@ -53,16 +52,12 @@ class AsyncMetricBuffer:
         pending, self._pending = self._pending, []
         if not pending:
             return []
-        if _telemetry.enabled():
-            t0 = time.perf_counter_ns()
+        with _telemetry.phase_span("readback"):
             new = [float(np.asarray(v)) for v in pending]
-            t1 = time.perf_counter_ns()
+        if _telemetry.enabled():
             tm = _telemetry.get_telemetry()
-            tm.add_phase("readback", t0, t1)
             tm.inc("metric.fences")
             tm.inc("metric.scalars_read", len(new))
-        else:
-            new = [float(np.asarray(v)) for v in pending]
         self.values.extend(new)
         return new
 

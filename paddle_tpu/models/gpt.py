@@ -27,6 +27,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 from ..framework.tensor import Tensor
@@ -153,6 +154,17 @@ class GPTDecoderLayer(Layer):
         self._use_sep = cfg.use_sep and _sep_degree() > 1
 
     def forward(self, x, attn_mask=None, cache=None):
+        # named scopes: the profiler's op metadata groups by layer part
+        with jax.named_scope("attention"):
+            x, cache = self._attention(x, attn_mask, cache)
+        with jax.named_scope("mlp"):
+            residual = x
+            y = self.ln_2(x)
+            y = self.down_proj(F.gelu(self.up_proj(y), approximate=True))
+            out = residual + self.resid_dropout(y)
+        return out if cache is None else (out, cache)
+
+    def _attention(self, x, attn_mask, cache):
         b, s, h = x.shape
         residual = x
         y = self.ln_1(x)
@@ -198,13 +210,7 @@ class GPTDecoderLayer(Layer):
                 is_causal=cache is None,
             )
         attn = attn.reshape([b, s, local_width])
-        x = residual + self.resid_dropout(self.out_proj(attn))
-
-        residual = x
-        y = self.ln_2(x)
-        y = self.down_proj(F.gelu(self.up_proj(y), approximate=True))
-        out = residual + self.resid_dropout(y)
-        return out if cache is None else (out, cache)
+        return residual + self.resid_dropout(self.out_proj(attn)), cache
 
 
 class GPTModel(Layer):
@@ -253,9 +259,11 @@ class GPTForCausalLM(Layer):
         if cache is not None:
             h, new_cache = self.gpt(input_ids, position_ids, attn_mask,
                                     cache=cache)
-            return ops.matmul(h, w, transpose_y=True), new_cache
+            with jax.named_scope("lm_head"):
+                return ops.matmul(h, w, transpose_y=True), new_cache
         h = self.gpt(input_ids, position_ids, attn_mask)
-        return ops.matmul(h, w, transpose_y=True)
+        with jax.named_scope("lm_head"):
+            return ops.matmul(h, w, transpose_y=True)
 
     def generate(self, prompt_ids, max_new_tokens=32, eos_id=None,
                  max_len=None, prefill_buckets=None):

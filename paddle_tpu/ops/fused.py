@@ -213,9 +213,13 @@ def fused_linear_cross_entropy(hidden, weight, labels, bias=None,
         reduction: ``"mean" | "sum" | "none"``.
         chunk: token-chunk size (0 = auto).
     """
-    if bias is None:
-        return _flce_op(hidden, weight, labels, ignore_index=ignore_index,
+    # one scope for the head matmul and the loss: a profile's op metadata
+    # groups them as the step's "lm_head_loss" part
+    with jax.named_scope("lm_head_loss"):
+        if bias is None:
+            return _flce_op(hidden, weight, labels,
+                            ignore_index=ignore_index,
+                            reduction=reduction, chunk=int(chunk))
+        return _flce_op(hidden, weight, labels, bias,
+                        ignore_index=ignore_index,
                         reduction=reduction, chunk=int(chunk))
-    return _flce_op(hidden, weight, labels, bias,
-                    ignore_index=ignore_index,
-                    reduction=reduction, chunk=int(chunk))

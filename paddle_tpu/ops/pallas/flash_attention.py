@@ -369,6 +369,7 @@ def _flash_fwd_impl(q, k, v, bias, seed, scale, causal, block_q, block_k,
         kernel = _inject_none(kernel, *missing)
     result = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b, h, nq, nk),
         in_specs=[s for s in in_specs if s is not None],
         out_specs=out_specs,
@@ -429,6 +430,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, need_dbias,
     ]
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(b, h, nq, nk),
         in_specs=[s for s in dq_specs if s is not None],
         out_specs=pl.BlockSpec((1, 1, block_q, d), qmap),
@@ -466,6 +468,7 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, need_dbias,
     ]
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(b, h, nk, nq),
         in_specs=[s for s in dkv_specs if s is not None],
         out_specs=[
@@ -605,6 +608,7 @@ def _flash_cached_impl(q, k, v, qpos, klen, scale, block_q, block_k,
                                block_k=block_k)
     return pl.pallas_call(
         kernel,
+        name="flash_cached_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h, nq, nk),

@@ -419,6 +419,7 @@ def _fwd_impl(q, k, v, bias, seed, h, scale, causal, block_q, block_k,
         kernel = _inject_none(kernel, *missing)
     return pl.pallas_call(
         kernel,
+        name="flash_packed_fwd",
         grid=(b, ng, nq, nk),
         in_specs=[s for s in in_specs if s is not None],
         out_specs=out_specs,
@@ -488,6 +489,7 @@ def _bwd(h, scale, causal, block_q, block_k, interpret, dropout_p, bwd_block,
     operands += [g, out, lse]
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_packed_bwd",
         grid=(b, ng, nk, nq),
         in_specs=[sp for sp in in_specs if sp is not None],
         out_specs=[

@@ -210,6 +210,7 @@ def ce_forward(x, w, b, y, *, block_n=DEFAULT_BLOCK_N,
     ]
     loss, lse = pl.pallas_call(
         kernel,
+        name="cross_entropy_fwd",
         grid=(nn, nv),
         in_specs=[sp for sp in in_specs if sp is not None],
         out_specs=[
@@ -261,6 +262,7 @@ def ce_backward(x, w, b, y, g, lse, *, block_n=DEFAULT_BLOCK_N,
     ]
     dx = pl.pallas_call(
         dx_kernel,
+        name="cross_entropy_bwd_dx",
         grid=(nn, nv),
         in_specs=[sp for sp in dx_specs if sp is not None],
         out_specs=pl.BlockSpec((block_n, hdim), lambda ni, vi: (ni, 0)),
@@ -298,6 +300,7 @@ def ce_backward(x, w, b, y, g, lse, *, block_n=DEFAULT_BLOCK_N,
         scratch.append(pltpu.VMEM((1, block_v), jnp.float32))
     out = pl.pallas_call(
         dw_k,
+        name="cross_entropy_bwd_dw",
         grid=(nv, nn),
         in_specs=[sp for sp in dw_specs if sp is not None],
         out_specs=dw_out_specs,

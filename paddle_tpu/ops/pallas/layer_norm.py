@@ -65,6 +65,7 @@ def _ln(x, gamma, beta, eps, block_rows, interpret):
     rows, feat = x.shape
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="layer_norm_fwd",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, feat), lambda i: (i, 0)),
@@ -86,6 +87,7 @@ def _ln_bwd(eps, block_rows, interpret, res, dy):
     rows, feat = x.shape
     dx, dgamma, dbeta = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
+        name="layer_norm_bwd",
         grid=(rows // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, feat), lambda i: (i, 0)),

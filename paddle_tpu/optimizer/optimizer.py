@@ -12,6 +12,7 @@ arrays, exposed as a pytree for jit-functionalization via ``_state_pytree``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..framework import dtype as dtypes
@@ -190,6 +191,10 @@ class Optimizer:
 
     @no_grad()
     def step(self):
+        with jax.named_scope("optimizer_update"):
+            self._step()
+
+    def _step(self):
         self._step_count += 1
         pgs = self._collect_params_grads()
         if self._grad_clip is not None:

@@ -1,13 +1,30 @@
-"""Runtime telemetry for the async device pipeline.
+"""Runtime telemetry for the async device pipeline and the serving tick.
 
-PR 1 made the train loop asynchronous (``io.DeviceLoader`` prefetch, donated
-compiled steps, deferred metric readback) but opaque: a slow step could be
-data-wait, compilation, dispatch, or readback and nothing said which. This
-module is the measurement substrate: a process-wide registry of counters,
-gauges and time-histograms (extending :class:`~paddle_tpu.utils.log_writer.
-Monitor`) plus a per-step *phase timeline* kept in a bounded ring buffer.
+The measurement substrate: a process-wide registry of counters, gauges and
+time-histograms (extending :class:`~paddle_tpu.utils.log_writer.Monitor`)
+plus a *phase timeline* kept in bounded rings.
 
-Phases (:data:`PHASES`):
+**One call per boundary, three sinks.** A boundary in the program is marked
+once, with ``with telemetry.phase_span(name):``. That one call
+
+  (a) files the phase here (histogram ``phase.<name>``, the raw-span ring,
+      and the open step/tick record) when telemetry is on;
+  (b) files the parent-linked :class:`~paddle_tpu.profiler.tracing.Span`
+      (same name, same start and end stamps, the current span's trace id)
+      when request tracing is on and a span is current on the thread;
+  (c) whenever either is on, enters
+      ``jax.profiler.TraceAnnotation("paddle_tpu:<name>")``, so that under
+      any live profiler session (``jax.profiler.start_trace``,
+      :class:`~paddle_tpu.profiler.Profiler`) the program's spans sit in the
+      xplane on the device's own clock.
+
+With both off it returns the shared no-op singleton
+(``tracing.NULL_SPAN``) and does *no* timing, allocation or locking.
+``tracing.span`` / ``start_span`` stay for request-scoped spans that are no
+phase of a step or tick (``request``, ``queue``, ``prefill``, ``decode``).
+
+Phases of a train step (:data:`PHASES`; one *step record* per
+``step_begin()``..``step_end()``, opened by ``Model.fit``):
 
   * ``data_wait`` — consumer blocked on the ``DeviceLoader`` hand-off queue
   * ``h2d_copy``  — host→device staging time in the stager thread
@@ -15,14 +32,29 @@ Phases (:data:`PHASES`):
   * ``dispatch``  — a cached ``CompiledStep`` call (host enqueue time)
   * ``readback``  — blocking device→host fences (``AsyncMetricBuffer.drain``)
 
-Zero overhead when disabled (the default): every instrumentation site guards
-on the module-level :func:`enabled` bool and does *no* timing, allocation or
-locking until :func:`enable` flips it. ``phase_span`` returns a shared no-op
-singleton while disabled.
+Phases of a serving tick (:data:`SERVE_PHASES`; one *tick record* per
+``Scheduler.step()``, ``kind == "serve.tick"``, ``index`` the scheduler's own
+``_step_idx``, ``owner`` the scheduler's id — a reader picks ticks by index,
+without a clock): ``serve.tick`` ⊃ ``serve.expire``, ``serve.admit`` (⊃ per
+admitted request ``serve.prefill_dispatch`` + ``serve.prefill_readback``),
+``serve.prefill_chunk``, ``serve.decode_feed``, ``serve.decode_dispatch``,
+``serve.decode_readback`` (the blocking token read-back), ``serve.bookkeep``;
+speculative ticks put ``serve.draft``, ``serve.verify_dispatch``,
+``serve.verify_readback``, ``serve.accept`` in the decode phases' place. One
+``serve.queue_wait`` (due → admit) per admitted request lands in the record
+of the tick that admitted it. A record keeps each phase's sum (``phases``)
+and its raw intervals (``spans``).
+
+A call that compiles is split where the time goes: ``jax.monitoring``
+duration listeners (registered once, at the first ``enable()``) attribute
+JAX's own trace / lowering / backend-compile-or-cache-load durations to the
+``CompiledStep`` whose call is in flight: :meth:`Telemetry.compile_seconds`
+is ``{step: {trace_s, lower_s, backend_s, first_run_s}}`` and a persistent
+cache hit counts in ``compile.cache_hits``.
 
 Instrumented producers run on two threads (the fit-loop consumer and the
 ``DeviceLoader`` stager); the registry is lock-protected and stager-side
-phases are attributed to whichever step record is currently open — the
+phases are attributed to whichever record is currently open — the
 overlapped-pipeline reading of "this step's h2d time".
 
 Export surfaces: :meth:`Telemetry.export_scalars` writes JSONL scalars
@@ -41,10 +73,17 @@ import threading
 import time
 import warnings
 
+# at import, on the importing thread: a first import from inside a stager
+# thread's phase could hold the import lock across a DataLoader's fork
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from ..utils.log_writer import Monitor
+from . import tracing as _tracing
+from .tracing import NULL_SPAN as _NULL_SPAN
 
 __all__ = [
     "PHASES",
+    "SERVE_PHASES",
     "Telemetry",
     "get_telemetry",
     "enable",
@@ -62,6 +101,19 @@ __all__ = [
 #: canonical per-step pipeline phases, in pipeline order
 PHASES = ("data_wait", "h2d_copy", "compile", "dispatch", "readback")
 
+#: the top-level phases of one serving tick, in tick order: disjoint
+#: children of ``serve.tick`` (their sum is no longer than it). A plain tick
+#: runs the ``decode_*`` three, a speculative one ``draft`` / ``verify_*`` /
+#: ``accept`` (and the ``decode_*`` three too when it falls back)
+SERVE_PHASES = ("serve.expire", "serve.admit", "serve.prefill_chunk",
+                "serve.draft", "serve.verify_dispatch",
+                "serve.verify_readback", "serve.accept",
+                "serve.decode_feed", "serve.decode_dispatch",
+                "serve.decode_readback", "serve.bookkeep")
+
+#: prefix of the program's host annotations in a profiler trace
+ANNOTATION_PREFIX = "paddle_tpu:"
+
 _ENABLED = False
 
 
@@ -70,58 +122,174 @@ def enabled():
     return _ENABLED
 
 
-class _NullSpan:
-    """Shared no-op context manager returned by ``phase_span`` when
-    telemetry is disabled — identity-testable for zero-overhead checks."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _PhaseSpan:
-    __slots__ = ("name", "_t0")
+    """The one boundary call's live object: stamps once, feeds three sinks
+    (module docstring). ``name`` may be reassigned inside the body (a
+    ``CompiledStep`` call learns only afterwards that it compiled): the
+    phase and the ``Span`` are filed under the final name, the profiler
+    annotation keeps the one it was entered with."""
 
-    def __init__(self, name):
+    __slots__ = ("name", "key", "start_ns", "end_ns", "_attrs", "_span",
+                 "_ann")
+
+    def __init__(self, name, attrs, key):
         self.name = name
-        self._t0 = None
+        self.key = key
+        self.start_ns = self.end_ns = None
+        self._attrs = attrs
+        self._span = None
+        self._ann = None
+
+    def set_attr(self, key, value):
+        """Attribute of the request-trace ``Span`` (dropped when tracing
+        is off: telemetry keeps names and times only)."""
+        if self._span is not None:
+            self._span.set_attr(key, value)
+        return self
 
     def __enter__(self):
-        self._t0 = time.perf_counter_ns()
+        self._ann = _TraceAnnotation(ANNOTATION_PREFIX + self.name)
+        self._ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        if _tracing.enabled():
+            tracer = _tracing.get_tracer()
+            # a phase joins a trace, it never roots one: with no span
+            # current (a bare train loop) only telemetry hears of it
+            if tracer.current() is not None:
+                self._span = tracer.start_span(
+                    self.name, attrs=self._attrs, start_ns=self.start_ns)
+                tracer._push(self._span)
         return self
 
     def __exit__(self, *exc):
-        if self._t0 is not None:
-            _TELEMETRY.add_phase(self.name, self._t0, time.perf_counter_ns())
-            self._t0 = None
+        self.end_ns = time.perf_counter_ns()
+        if self._span is not None:
+            _tracing.get_tracer()._pop(self._span)
+            self._span.name = self.name
+            self._span.end(self.end_ns)
+        if _ENABLED:
+            _TELEMETRY.add_phase(self.name, self.start_ns, self.end_ns,
+                                 key=self.key)
+        self._ann.__exit__(*exc)
         return False
+
+    @property
+    def duration_s(self):
+        return (self.end_ns - self.start_ns) / 1e9
 
 
 class _StepRecord:
-    """One step's phase breakdown (seconds per phase)."""
+    """One step's (or one serving tick's) phase breakdown: seconds per
+    phase in ``phases``, the raw ``(name, start_ns, end_ns)`` intervals in
+    ``spans``. ``kind`` is ``"step"`` for a train step and ``"serve.tick"``
+    for a scheduler tick, whose ``index`` is the scheduler's ``_step_idx``
+    and whose ``owner`` is the scheduler's id."""
 
-    __slots__ = ("index", "start_ns", "end_ns", "phases")
+    __slots__ = ("index", "start_ns", "end_ns", "phases", "kind", "owner",
+                 "spans", "_prev")
 
-    def __init__(self, index, start_ns):
+    def __init__(self, index, start_ns, kind="step", owner=None):
         self.index = index
         self.start_ns = start_ns
         self.end_ns = start_ns
         self.phases = {}
+        self.kind = kind
+        self.owner = owner
+        self.spans = []
+        self._prev = None
 
     @property
     def wall_s(self):
         return max(self.end_ns - self.start_ns, 0) / 1e9
 
     def as_dict(self):
-        return {"step": self.index, "wall_s": self.wall_s,
-                "phases": dict(self.phases)}
+        return {"step": self.index, "kind": self.kind, "owner": self.owner,
+                "wall_s": self.wall_s, "phases": dict(self.phases),
+                "spans": list(self.spans)}
+
+
+#: jax.monitoring duration events -> the part of a compile they time
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+COMPILE_PARTS = ("trace_s", "lower_s", "backend_s", "first_run_s")
+
+_WATCH = threading.local()  # .cur: the in-flight CompiledStep call's events
+_LISTENING = False
+
+
+def _on_duration(event, secs, **_):
+    cur = getattr(_WATCH, "cur", None)
+    part = _COMPILE_EVENTS.get(event) if cur is not None else None
+    if part is not None:
+        end = time.perf_counter_ns()
+        cur[part].append((end - int(secs * 1e9), end))
+
+
+def _on_event(event, **_):
+    cur = getattr(_WATCH, "cur", None)
+    if cur is not None and event == _CACHE_HIT_EVENT:
+        cur["cache_hits"] += 1
+
+
+def _listen():
+    """Register the ``jax.monitoring`` listeners, once per process (they
+    cannot be taken off again; with no call in flight they return at the
+    first test)."""
+    global _LISTENING
+    if not _LISTENING:
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
+        _LISTENING = True
+
+
+def _new_watch(prev=None):
+    return {"trace_s": [], "lower_s": [], "backend_s": [], "cache_hits": 0,
+            "prev": prev}
+
+
+def compile_watch_begin():
+    """``CompiledStep.__call__``: from here to ``compile_watch_end`` JAX's
+    compile events on this thread belong to the calling step."""
+    _WATCH.cur = _new_watch(getattr(_WATCH, "cur", None))
+    return _WATCH.cur
+
+
+def compile_watch_end(watch):
+    _WATCH.cur = watch["prev"]
+
+
+def _union_ns(intervals, lo, hi):
+    """Length of the union of ``intervals`` clipped to ``[lo, hi]``."""
+    total, edge = 0, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, edge), min(b, hi)
+        if b > a:
+            total += b - a
+            edge = b
+    return total
+
+
+def _split_compile(watch, start_ns, end_ns):
+    """Seconds of one compiling call by part. JAX's events nest (thousands
+    of jitted helpers traced inside the step's trace, a trace made while
+    lowering), so every instant goes to one part only: backend over
+    lowering over trace; what no event covers is ``first_run_s`` (state
+    snapshot, argument split, the first execution's enqueue). Unions, so
+    the cost stays ``n log n`` in the number of events."""
+    backend = list(watch["backend_s"])
+    lower = backend + watch["lower_s"]
+    trace = lower + watch["trace_s"]
+    b, bl, blt = (_union_ns(iv, start_ns, end_ns)
+                  for iv in (backend, lower, trace))
+    return {"trace_s": (blt - bl) / 1e9, "lower_s": (bl - b) / 1e9,
+            "backend_s": b / 1e9,
+            "first_run_s": (end_ns - start_ns - blt) / 1e9}
 
 
 class Telemetry(Monitor):
@@ -204,7 +372,8 @@ class Telemetry(Monitor):
         the elastic heartbeat forwards for straggler detection). Caller
         holds the lock."""
         self._ring.append(cur)
-        self._gauges["step.time_s"] = cur.wall_s
+        if cur.kind == "step":
+            self._gauges["step.time_s"] = cur.wall_s
 
     def step_begin(self):
         """Open a step record, closing (and keeping) any open one that saw
@@ -212,7 +381,7 @@ class Telemetry(Monitor):
         each body so the next batch's data_wait lands in the next record."""
         with self._lock:
             cur = self._current
-            if cur is not None and cur.phases:
+            if cur is not None and cur.phases and cur.kind == "step":
                 self._close_record(cur)
             self._current = _StepRecord(self._next_step,
                                         time.perf_counter_ns())
@@ -226,37 +395,81 @@ class Telemetry(Monitor):
             if cur is not None and cur.phases:
                 self._close_record(cur)
 
-    def add_phase(self, name, start_ns, end_ns):
-        """Record one phase span: histogram + chrome span + the open step."""
+    def open_record(self, kind, index, owner=None):
+        """Open a record of another kind than a train step (a serving
+        tick) with the caller's own index; the record that was open waits
+        underneath until :meth:`close_record`."""
+        rec = _StepRecord(index, time.perf_counter_ns(), kind, owner)
+        with self._lock:
+            rec._prev = self._current
+            self._current = rec
+        return rec
+
+    def close_record(self, rec):
+        """Close (and keep) a record opened by :meth:`open_record`."""
+        with self._lock:
+            if self._current is rec:
+                self._current = rec._prev
+            rec._prev = None
+            self._close_record(rec)
+
+    def add_phase(self, name, start_ns, end_ns, key=None):
+        """Record one phase span: histogram + raw-span ring + the open
+        record. ``key`` says whose phase it is where several producers
+        share a name (the ``CompiledStep``'s name on ``dispatch``)."""
         secs = max(end_ns - start_ns, 0) / 1e9
         tid = threading.get_ident()
         with self._lock:
             self.add(f"phase.{name}", secs)
             self._phase_samples.setdefault(
                 name, collections.deque(maxlen=2048)).append(secs)
-            self._spans.append((name, start_ns, end_ns, tid))
+            self._spans.append((name, start_ns, end_ns, tid, key))
             cur = self._current
             if cur is not None:
                 cur.phases[name] = cur.phases.get(name, 0.0) + secs
+                cur.spans.append((name, start_ns, end_ns))
                 cur.end_ns = max(cur.end_ns, end_ns)
 
-    def steps(self):
-        """Closed step records, oldest first (bounded by ``ring_size``)."""
+    def steps(self, kind=None, owner=None):
+        """Closed records, oldest first (bounded by ``ring_size``);
+        optionally one kind's (``"step"``, ``"serve.tick"``) or one
+        owner's (a scheduler's id) only."""
         with self._lock:
-            return list(self._ring)
+            return [r for r in self._ring
+                    if (kind is None or r.kind == kind)
+                    and (owner is None or r.owner == owner)]
+
+    def phase_records(self, name=None, key=None):
+        """The raw-span ring as ``(name, start_ns, end_ns, tid, key)``,
+        oldest first (the last ``8 * ring_size`` phases), optionally one
+        name's / one key's only."""
+        with self._lock:
+            return [s for s in self._spans
+                    if (name is None or s[0] == name)
+                    and (key is None or s[4] == key)]
 
     # -- recompile detection ------------------------------------------------
-    def note_compile(self, key, start_ns, end_ns):
-        """A ``CompiledStep`` call that traced: count it per step-name and
-        warn once when the same step recompiles beyond the threshold —
-        recompilation churn means shape/dtype instability in the feed."""
-        self.add_phase("compile", start_ns, end_ns)
+    def note_compile(self, key, start_ns, end_ns, watch=None):
+        """A ``CompiledStep`` call that traced (its ``compile`` phase is
+        already filed by the call's own ``phase_span``): count it per
+        step-name, split its seconds by part from the ``jax.monitoring``
+        events ``watch`` collected, and warn once when the same step
+        recompiles beyond the threshold — recompilation churn means
+        shape/dtype instability in the feed."""
+        watch = watch or _new_watch()
+        split = _split_compile(watch, start_ns, end_ns)
         with self._lock:
             self._counters["compile.count"] = \
                 self._counters.get("compile.count", 0) + 1
+            if watch["cache_hits"]:
+                self._counters["compile.cache_hits"] = (
+                    self._counters.get("compile.cache_hits", 0)
+                    + watch["cache_hits"])
             n = self._compiles[key] = self._compiles.get(key, 0) + 1
-            self._compile_s[key] = (self._compile_s.get(key, 0.0)
-                                    + (end_ns - start_ns) / 1e9)
+            parts = self._compile_s.setdefault(
+                key, dict.fromkeys(COMPILE_PARTS, 0.0))
+            for part, secs in split.items():
+                parts[part] += secs
             threshold = max(self.recompile_warn_threshold,
                             self._declared.get(key, 1))
             warn = n > threshold and key not in self._warned
@@ -275,10 +488,13 @@ class Telemetry(Monitor):
             return dict(self._compiles)
 
     def compile_seconds(self):
-        """Seconds spent in calls that traced, per step-name: trace + XLA
-        compile (or persistent-cache load) + that call's own execution."""
+        """Seconds spent in calls that traced, per step-name and part:
+        ``{step: {trace_s, lower_s, backend_s, first_run_s}}`` — JAX's
+        trace, its lowering to MLIR, the backend's compile or
+        persistent-cache load, and the rest of those calls (the first
+        run). The four sum to the calls' wall time."""
         with self._lock:
-            return dict(self._compile_s)
+            return {k: dict(v) for k, v in self._compile_s.items()}
 
     def declare_variants(self, key, n):
         """Declare that step ``key`` legitimately compiles up to ``n``
@@ -380,7 +596,7 @@ class Telemetry(Monitor):
         """Buffered raw spans as (name, start_ns, end_ns, tid) tuples, on
         the same ``perf_counter_ns`` clock as the profiler's host events."""
         with self._lock:
-            return list(self._spans)
+            return [s[:4] for s in self._spans]
 
     def summary(self):
         with self._lock:
@@ -566,6 +782,7 @@ def enable(ring_size=None, recompile_warn_threshold=None):
                 _TELEMETRY._spans, maxlen=_TELEMETRY.ring_size * 8)
     if recompile_warn_threshold is not None:
         _TELEMETRY.recompile_warn_threshold = int(recompile_warn_threshold)
+    _listen()
     _ENABLED = True
     return _TELEMETRY
 
@@ -581,11 +798,16 @@ def reset():
     _TELEMETRY.reset()
 
 
-def phase_span(name):
-    """Context manager timing one phase; shared no-op when disabled."""
-    if not _ENABLED:
+def phase_span(name, attrs=None, key=None):
+    """THE boundary call: a context manager that stamps the body once and
+    feeds the phase timeline, the request trace and the profiler's trace
+    (module docstring). ``attrs`` go to the trace ``Span`` only; ``key``
+    tags the phase in :meth:`Telemetry.phase_records`. The shared no-op
+    singleton (``tracing.NULL_SPAN``) while telemetry and tracing are both
+    off."""
+    if not _ENABLED and not _tracing._ENABLED:
         return _NULL_SPAN
-    return _PhaseSpan(name)
+    return _PhaseSpan(name, attrs, key)
 
 
 def step_begin():

@@ -1,20 +1,32 @@
 """Request-scoped tracing: trace/span ids threaded through serving + training.
 
-The PR 2/5 telemetry answers *aggregate* questions (p95 TTFT, compile
-counts); it cannot answer "why was *this* request's TTFT 800 ms". This
-module mints a trace id per unit of work (a served request, a train epoch)
-and records parent-linked spans for every stage it passes through:
+Telemetry answers *aggregate* questions (p95 TTFT, compile counts, where a
+tick's time goes); it cannot answer "why was *this* request's TTFT 800 ms".
+This module mints a trace id per unit of work (a served request, a train
+epoch) and records parent-linked spans for every stage it passes through.
 
-* ``Scheduler.submit`` opens the request's root span and a ``queue`` child;
-  admit closes the queue span and wraps the prefill; every decode tick
-  records one ``decode_token`` span per *active request* (the batched
-  ``serve_decode`` dispatch is shared — each request's span carries a
-  ``decode_span`` attr linking to the shared one); evict closes the root.
-* ``CompiledStep`` reports trace-context compile events: a call that traced
-  while a span is current lands a ``compile`` child span, so the export
-  shows exactly which request (or train step) paid which compile.
-* ``hapi.Model.fit`` / ``GenerationEngine`` emit spans under the same API,
-  so train steps and standalone ``generate()`` calls get trace context too.
+Two kinds of span land in the same ring:
+
+* **request-scoped spans**, opened here with :func:`span` /
+  :func:`start_span`: ``Scheduler.submit`` opens the request's ``request``
+  root and a ``queue`` child; admit closes the queue span and opens
+  ``prefill``; the first decoded token opens ONE ``decode`` span per request,
+  closed at evict, whose attrs carry a stamp per token (``token_end_ns``),
+  the tokens, and the id of the shared ``decode_step`` span each rode
+  (``decode_steps``) — so a whole benchmark window of requests fits the
+  default ring; evict closes the root. ``hapi.Model.fit`` opens
+  ``<mode>_epoch`` / ``<mode>_step``, ``GenerationEngine.generate`` opens
+  ``generate``, a scheduler opens ``serve_session`` / ``decode_step``.
+* **phase spans**: every boundary the program marks with
+  ``telemetry.phase_span(name)`` (the one call per boundary: see
+  ``telemetry``'s docstring for the list) files a ``Span`` of the same name
+  and the same start/end stamps under whatever span is current on the thread
+  — ``serve.prefill_dispatch`` under a request's ``prefill``,
+  ``serve.decode_dispatch`` under the shared ``decode_step``, ``dispatch`` or
+  ``compile`` (attrs ``step``, ``compile_index``) under whoever made the
+  ``CompiledStep`` call, so the export shows which request (or train step)
+  paid which compile. A phase never roots a trace: with no span current it
+  reaches telemetry only.
 
 Same zero-overhead contract as ``telemetry``: everything guards on a
 module-level flag, ``span()``/``start_span()`` return shared no-op
@@ -25,8 +37,9 @@ Export: :meth:`Tracer.export_jsonl` (one span per line, ``trace``/``span``/
 ``parent`` ids + ns timestamps + attrs) and :meth:`Tracer.export_chrome`
 (chrome://tracing / Perfetto ``trace_events``; pass
 ``include_telemetry=True`` to merge the telemetry phase timeline — both run
-on the same ``perf_counter_ns`` clock, so a request's spans line up against
-``data_wait``/``compile``/``dispatch`` without translation).
+on the same ``perf_counter_ns`` clock). Under a live ``jax.profiler`` session
+the phase spans are in the profiler's own trace too, as
+``paddle_tpu:<name>`` annotations on the device's clock.
 """
 from __future__ import annotations
 
@@ -48,7 +61,6 @@ __all__ = [
     "start_span",
     "current_span",
     "activate",
-    "note_compile",
 ]
 
 _ENABLED = False
@@ -254,8 +266,7 @@ class Tracer:
 
     def record(self, name, start_ns, end_ns, parent=None, trace_id=None,
                attrs=None):
-        """Record an already-timed span (used for the shared decode
-        interval fan-out and compile events)."""
+        """Record an already-timed span (a request's terminal events)."""
         sp = self.start_span(name, parent=parent, trace_id=trace_id,
                              attrs=attrs, start_ns=start_ns)
         sp.end(end_ns)
@@ -408,20 +419,3 @@ def activate(span_):
     if not _ENABLED or not isinstance(span_, Span):
         return NULL_SPAN
     return _Activation(_TRACER, span_)
-
-
-def note_compile(step_name, start_ns, end_ns, compile_index=None):
-    """CompiledStep hook: a call that traced while a span was current files
-    a ``compile`` child span — the export shows which request/train-step
-    paid which (re)compile. No current span → the event is dropped (the
-    aggregate telemetry compile counters still cover it)."""
-    if not _ENABLED:
-        return None
-    cur = _TRACER.current()
-    if cur is None:
-        return None
-    attrs = {"step": step_name}
-    if compile_index is not None:
-        attrs["compile_index"] = compile_index
-    return _TRACER.record("compile", start_ns, end_ns, parent=cur,
-                          attrs=attrs)

@@ -448,20 +448,22 @@ class GenerationEngine:
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :prompt.size] = prompt
         self._declare_variants()
-        # span nests under the caller's context (a scheduler's per-request
-        # prefill span, or roots its own trace standalone); the compiled
-        # step's compile event lands inside it on a cold bucket
         # fault-injection point BEFORE the compiled call: the cache rides
         # donate_inputs, so a fault raised here leaves it un-donated and
         # the scheduler's retry runs against valid buffers
         _inject.check("serve.prefill")
-        with _tracing.span("serve_prefill",
-                           attrs={"slot": int(slot), "bucket": bucket,
-                                  "prompt_tokens": int(prompt.size)}):
+        # the phase nests under the caller's context (a scheduler's
+        # per-request prefill span, a tick's serve.admit); the compiled
+        # step's dispatch — or compile, on a cold bucket — lands inside it
+        with _telemetry.phase_span(
+                "serve.prefill_dispatch",
+                attrs={"slot": int(slot), "bucket": bucket,
+                       "prompt_tokens": int(prompt.size)}):
             tok, cache = self._prefill_step(
                 toks, np.int32(prompt.size), np.int32(slot), self.cache)
         self.cache = cache  # donated: the old buffers are consumed
-        return int(np.asarray(_leaf(tok)))
+        with _telemetry.phase_span("serve.prefill_readback"):
+            return int(np.asarray(_leaf(tok)))
 
     def chunked_prefill_fits(self, prompt_len):
         """True when a prompt of this length can prefill through the
@@ -513,31 +515,38 @@ class GenerationEngine:
         toks[0, :piece.size] = piece
         self._declare_variants()
         _inject.check("serve.prefill")  # pre-donation: retry-safe
-        with _tracing.span("serve_prefill_chunk",
-                           attrs={"slot": int(slot), "off": off,
-                                  "chunk_tokens": int(piece.size),
-                                  "prompt_tokens": int(prompt.size)}):
+        with _telemetry.phase_span(
+                "serve.prefill_dispatch",
+                attrs={"slot": int(slot), "off": off,
+                       "chunk_tokens": int(piece.size),
+                       "prompt_tokens": int(prompt.size)}):
             tok, cache = self._chunk_step(
                 toks, np.int32(piece.size), np.int32(off), np.int32(slot),
                 self.cache)
         self.cache = cache
         if off + piece.size >= prompt.size:
-            return int(np.asarray(_leaf(tok)))
+            with _telemetry.phase_span("serve.prefill_readback"):
+                return int(np.asarray(_leaf(tok)))
         return None
 
     def decode_once(self, last_tokens):
         """One batched decode step: ``last_tokens[b]`` is each slot's most
         recent token. Returns the next token per slot (np int32 [b])."""
-        feed = np.asarray(last_tokens, np.int32).reshape(self.max_batch, 1)
-        self._declare_variants()
-        _inject.check("serve.decode")  # pre-donation: cache-safe on retry
-        with _tracing.span("serve_decode"):
+        # the phase is everything the host does to hand the step over
+        with _telemetry.phase_span("serve.decode_dispatch"):
+            feed = np.asarray(last_tokens, np.int32).reshape(
+                self.max_batch, 1)
+            self._declare_variants()
+            _inject.check("serve.decode")  # pre-donation: retry-safe
             tok, keys, cache = self._decode_step(
                 feed, self.cache, self._keys, self._temps,
                 self._top_ks, self._top_ps)
-        self.cache = cache
-        self._keys = _leaf(keys)
-        return np.asarray(_leaf(tok))
+            self.cache = cache
+            self._keys = _leaf(keys)
+        # the one blocking wait of a tick: the host sits here while the
+        # device runs the step it was just handed
+        with _telemetry.phase_span("serve.decode_readback"):
+            return np.asarray(_leaf(tok))
 
     def verify_once(self, window_tokens):
         """One speculative verify step over ``[max_batch, spec_k + 1]``
@@ -559,13 +568,15 @@ class GenerationEngine:
             self.max_batch, w)
         self._declare_variants()
         _inject.check("serve.verify")  # pre-donation: cache-safe on retry
-        with _tracing.span("serve_verify", attrs={"window": w}):
+        with _telemetry.phase_span("serve.verify_dispatch",
+                                   attrs={"window": w}):
             greedy, tok0, keys, cache = self._verify_step(
                 feed, self.cache, self._keys, self._temps,
                 self._top_ks, self._top_ps)
         self.cache = cache
         self._keys = _leaf(keys)
-        return (np.asarray(_leaf(greedy)), np.asarray(_leaf(tok0)))
+        with _telemetry.phase_span("serve.verify_readback"):
+            return (np.asarray(_leaf(greedy)), np.asarray(_leaf(tok0)))
 
     def commit_lengths(self, advance):
         """Advance per-slot cached lengths by ``advance[b]`` tokens after

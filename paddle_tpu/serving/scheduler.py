@@ -79,6 +79,22 @@ gauge (running accepted/proposed), and per-request ``serve.ttft_s`` /
 ``serve.tpot_s`` / ``serve.latency_s`` histograms —
 ``tools/bench_serve.py`` summarizes them into the SERVE json.
 
+Where a tick's time goes (telemetry on): every ``step()`` leaves one tick
+record in telemetry's ring (``kind "serve.tick"``, ``index`` = this
+scheduler's ``_step_idx``, ``owner`` = its ``sched_id``) holding the tick's
+phases, each marked by the one boundary call ``telemetry.phase_span``:
+``serve.tick`` ⊃ ``serve.expire``, ``serve.admit`` (⊃ per admitted request
+the engine's ``serve.prefill_dispatch`` and ``serve.prefill_readback``),
+``serve.prefill_chunk``, ``serve.decode_feed``, ``serve.decode_dispatch``,
+``serve.decode_readback`` (the blocking token read-back),
+``serve.bookkeep`` (token append, evict, gauges, SLO check); a speculative
+tick has ``serve.draft`` / ``serve.verify_dispatch`` /
+``serve.verify_readback`` / ``serve.accept`` in the decode phases' place.
+Each admitted request adds one ``serve.queue_wait`` (due → admit) to the
+record of the tick that admitted it. Under a live ``jax.profiler`` session
+the same phases are ``paddle_tpu:serve.*`` annotations on the device's
+clock: a tick of seconds names its phase.
+
 Speculative fault surface: the host-side draft pass checks the
 ``serve.draft`` injection point (a fault skips drafting — the tick
 decodes plain, parity unaffected); the verify dispatch checks
@@ -99,11 +115,15 @@ deterministic (footprint, slot) max.
 Request-scoped tracing (``profiler/tracing.py``, opt-in): ``submit`` mints
 the request's trace — a ``request`` root span plus a ``queue`` child that
 closes at admit; the prefill runs inside a ``prefill`` child (so the
-engine's span and any compile events parent under it); every decode tick
-records one ``decode_token`` span per *active* request over the shared
-batched-dispatch interval (each carries a ``decode_span`` attr naming the
-shared ``decode_step`` span it rode); evict closes the root with the
-finish reason and latency stats. Abnormal terminations additionally record
+engine's phases and any compile parent under it); the first decoded token
+opens ONE ``decode`` child per request, closed at evict, whose attrs carry
+a stamp per token (``token_end_ns``), the tokens and, per token, the id of
+the shared ``decode_step`` span it rode (``decode_steps``); evict closes the
+root with the finish reason and latency stats. The tick's own phases hang
+under a ``serve_session`` trace (``serve.tick`` per tick, the decode phases
+under the tick's shared ``decode_step`` span). A saturated 51 s window (~160
+requests, ~8,000 tokens, ~250 ticks) fits the default 8,192-span ring with
+nothing dropped. Abnormal terminations additionally record
 an instantaneous event span named after the reason (``shed`` / ``timeout``
 / ``oom_evicted`` / ``error`` / ``drained``) under the request root, so a
 trace query for shed/timeout events needs no attr filtering. One JSONL
@@ -146,6 +166,7 @@ FINISH_REASONS = ("eos", "length", "timeout", "shed", "oom_evicted",
                   "error", "drained")
 
 _rid_counter = itertools.count()
+_sched_counter = itertools.count()
 
 #: distinct from None ("more chunks to go") — a chunked prefill that
 #: exhausted its retry budget and must fail terminally
@@ -187,6 +208,11 @@ class Request:
     tokens: list = field(default_factory=list)
     slot: int | None = None
     submit_ns: int | None = None
+    #: when the request was DUE (``time.perf_counter_ns`` clock): an
+    #: open-loop load generator sets it to the scheduled arrival so that a
+    #: late submit counts as waiting; ``submit()`` defaults it to
+    #: ``submit_ns``. ``ttft_s`` and ``serve.queue_wait`` count from it
+    due_ns: int | None = None
     first_token_ns: int | None = None
     done_ns: int | None = None
     finish_reason: str | None = None
@@ -197,6 +223,7 @@ class Request:
     trace_span: object = field(default=None, repr=False, compare=False)
     queue_span: object = field(default=None, repr=False, compare=False)
     prefill_span: object = field(default=None, repr=False, compare=False)
+    decode_span: object = field(default=None, repr=False, compare=False)
 
     @property
     def sampled(self):
@@ -213,10 +240,11 @@ class Request:
 
     @property
     def ttft_s(self):
-        """Time to first token (submit → prefill's token readback)."""
-        if self.first_token_ns is None or self.submit_ns is None:
+        """Time to first token (due → prefill's token readback; due is
+        the submit unless a load generator set ``due_ns``)."""
+        if self.first_token_ns is None or self.due_ns is None:
             return None
-        return (self.first_token_ns - self.submit_ns) / 1e9
+        return (self.first_token_ns - self.due_ns) / 1e9
 
     @property
     def tpot_s(self):
@@ -358,6 +386,8 @@ class Scheduler:
                  admission=None, retry_tries=3, retry_base_delay=0.02,
                  retry_sleep=time.sleep, speculative=None, draft=None):
         self.engine = engine
+        #: process-wide id: the ``owner`` of this scheduler's tick records
+        self.sched_id = next(_sched_counter)
         self.queue = deque()
         self.active = {}  # slot -> Request (decoding)
         self.prefilling = {}  # slot -> Request (chunked prefill streaming)
@@ -412,6 +442,8 @@ class Scheduler:
                 f"prompt ({n}) + max_new_tokens ({request.max_new_tokens}) "
                 f"exceeds the cache capacity max_len={self.engine.max_len}")
         request.submit_ns = time.perf_counter_ns()
+        if request.due_ns is None:
+            request.due_ns = request.submit_ns
         if _tracing.enabled():
             # the request's whole life lives under this root span; the
             # queue child measures submit→admit wait explicitly
@@ -454,38 +486,62 @@ class Scheduler:
         if tr and self._session_span is None:
             self._session_span = _tracing.start_span(
                 "serve_session", attrs={"max_batch": self.engine.max_batch})
+        # the tick's record: telemetry files every phase below into it,
+        # under this scheduler's own tick index
+        rec = (tm.open_record("serve.tick", self._step_idx, self.sched_id)
+               if tm is not None else None)
+        try:
+            with _tracing.activate(self._session_span), \
+                    _telemetry.phase_span(
+                        "serve.tick", attrs={"sched_step": self._step_idx}):
+                return self._tick(tm, tr)
+        finally:
+            if rec is not None:
+                tm.close_record(rec)
+
+    def _tick(self, tm, tr):
+        span = _telemetry.phase_span
         done_now = []
 
         # expire: deadline / queue-wait budgets, BEFORE admit so freed
         # slots are handed to queued work this very tick
-        self._expire(done_now, tm)
+        with span("serve.expire"):
+            self._expire(done_now, tm)
 
         # admit: fill free slots from the queue (FIFO, lowest slot first)
-        while self.queue and self._free:
-            req = self.queue.popleft()
-            slot = heapq.heappop(self._free)
-            self._admit_one(req, slot, done_now, tm, tr)
+        if self.queue and self._free:
+            with span("serve.admit"):
+                while self.queue and self._free:
+                    req = self.queue.popleft()
+                    slot = heapq.heappop(self._free)
+                    self._admit_one(req, slot, done_now, tm, tr)
 
         # prefill chunk: at most ONE chunk dispatch per tick (lowest slot
         # first), so a tick's worst case is one bounded chunk + one
         # decode no matter how long the admitted prompts are — active
         # streams never stall for a whole long-prompt prefill
         if self.prefilling:
-            self._advance_chunk(done_now, tm)
+            with span("serve.prefill_chunk"):
+                self._advance_chunk(done_now, tm)
 
         # decode: one batched step over every active slot; a
         # RESOURCE_EXHAUSTED tick degrades (evict victim, retry) instead
         # of killing every in-flight request
+        emitted = decode_span = None
         if self.active:
-            self._decode_phase(done_now, tm, tr)
+            emitted, decode_span = self._decode_phase(done_now, tm, tr)
 
-        self._step_idx += 1
-        if tm is not None:
-            tm.set_gauge("serve.requests_in_flight",
-                         len(self.active) + len(self.prefilling))
-            tm.set_gauge("serve.queue_depth", len(self.queue))
-        if self.slo is not None and self._step_idx % self.slo_check_every == 0:
-            self.slo.check()
+        with span("serve.bookkeep"):
+            if emitted is not None:
+                self._commit_tokens(emitted, decode_span, done_now, tm)
+            self._step_idx += 1
+            if tm is not None:
+                tm.set_gauge("serve.requests_in_flight",
+                             len(self.active) + len(self.prefilling))
+                tm.set_gauge("serve.queue_depth", len(self.queue))
+            if self.slo is not None \
+                    and self._step_idx % self.slo_check_every == 0:
+                self.slo.check()
         return done_now
 
     def _admit_one(self, req, slot, done_now, tm, tr):
@@ -494,6 +550,10 @@ class Scheduler:
         or the PREFILLING parking state for multi-chunk prompts when the
         engine has chunked prefill."""
         req.slot = slot
+        if tm is not None:
+            # one per admitted request, in this tick's record: due -> admit
+            tm.add_phase("serve.queue_wait", req.due_ns,
+                         time.perf_counter_ns())
         prefill_span = None
         if tr and req.trace_span is not None:
             if req.queue_span is not None:
@@ -520,7 +580,7 @@ class Scheduler:
             if tm is not None:
                 tm.inc("serve.admitted")
             return
-        # activated so the engine's serve_prefill span (and the bucket
+        # activated so the engine's serve.prefill_* phases (and the bucket
         # compile, if this prompt hits a cold bucket) parent under it
         with _tracing.activate(prefill_span):
             tok = self._prefill_with_recovery(req, slot, done_now, tm)
@@ -601,8 +661,10 @@ class Scheduler:
     def _decode_phase(self, done_now, tm, tr):
         """One batched decode tick: speculative verify when armed and
         every live slot has window headroom, else plain serve_decode.
-        Token bookkeeping is shared — both paths produce a per-slot
-        emitted-token dict."""
+        Returns ``(emitted, decode_span)``: the per-slot emitted-token dict
+        both paths produce (None when every active request was evicted
+        before a step landed) for ``_commit_tokens``, and the tick's shared
+        ``decode_step`` trace span."""
         decode_span = None
         if tr:
             decode_span = _tracing.start_span(
@@ -612,13 +674,17 @@ class Scheduler:
         with _tracing.activate(decode_span):
             emitted = None
             if self.speculative and self._spec_headroom():
-                emitted = self._spec_tick(done_now, tm, tr, decode_span)
+                emitted = self._spec_tick(tm)
             if emitted is None and self.active:
                 emitted = self._plain_tick(done_now, tm)
         if decode_span is not None:
             decode_span.end()
-        if emitted is None:
-            return  # every active request was evicted before a step landed
+        return emitted, decode_span
+
+    def _commit_tokens(self, emitted, decode_span, done_now, tm):
+        """The decode tick's bookkeeping (inside ``serve.bookkeep``):
+        counters, token append, the request's ``decode`` trace span, and
+        the eviction of whoever is done."""
         self.decode_steps += 1
         self.slot_steps += len(self.active)
         if tm is not None:
@@ -630,18 +696,26 @@ class Scheduler:
             req = self.active[slot]
             toks = emitted.get(slot, [])
             req.tokens.extend(toks)
-            if decode_span is not None and req.trace_span is not None:
-                # the batched dispatch is SHARED: one span per active
-                # request over the same interval, linked to the shared
-                # decode_step span — per-token intervals per request
-                _tracing.get_tracer().record(
-                    "decode_token", decode_span.start_ns,
-                    decode_span.end_ns, parent=req.trace_span,
-                    attrs={"slot": slot, "token": req.tokens[-1],
-                           "index": len(req.tokens) - 1,
-                           "emitted": len(toks),
-                           "decode_span": decode_span.span_id,
-                           "decode_trace": decode_span.trace_id})
+            if decode_span is not None and req.trace_span is not None \
+                    and toks:
+                # the batched dispatch is SHARED: the request's one decode
+                # span (first token -> evict) gets a stamp per token and
+                # the id of the shared decode_step span each rode
+                d = req.decode_span
+                if d is None:
+                    d = req.decode_span = _tracing.get_tracer().start_span(
+                        "decode", parent=req.trace_span,
+                        start_ns=decode_span.start_ns,
+                        attrs={"slot": slot,
+                               "first_index": len(req.tokens) - len(toks),
+                               "tokens": [], "token_end_ns": [],
+                               "decode_steps": [],
+                               "decode_trace": decode_span.trace_id})
+                d.attrs["tokens"].extend(toks)
+                d.attrs["token_end_ns"].extend(
+                    [decode_span.end_ns] * len(toks))
+                d.attrs["decode_steps"].extend(
+                    [decode_span.span_id] * len(toks))
             if self._exhausted(req):
                 done_now.append(self._evict(req))
 
@@ -649,9 +723,10 @@ class Scheduler:
         """The non-speculative tick: one ``serve_decode``, one token per
         active slot. Returns ``{slot: [token]}`` or None when recovery
         evicted every active request."""
-        feed = np.zeros((self.engine.max_batch,), np.int32)
-        for slot, req in self.active.items():
-            feed[slot] = req.tokens[-1]
+        with _telemetry.phase_span("serve.decode_feed"):
+            feed = np.zeros((self.engine.max_batch,), np.int32)
+            for slot, req in self.active.items():
+                feed[slot] = req.tokens[-1]
         out = self._decode_with_recovery(feed, done_now, tm)
         if out is None:
             return None
@@ -678,42 +753,40 @@ class Scheduler:
                 return False
         return True
 
-    def _spec_tick(self, done_now, tm, tr, decode_span):
+    def _spec_tick(self, tm):
         """One speculative tick: host-side DRAFT → one batched VERIFY
         forward → host-side ACCEPT of the longest draft prefix matching
         the verifier's own greedy argmax (plus one verifier token — on
         total rejection the tick degenerates to exactly a plain greedy
         step). Returns the per-slot emitted dict, or None to make the
         caller run a plain tick instead (no drafts, or verify faulted)."""
-        del done_now  # no evictions here: verify failure falls back whole
+        # no evictions here: a verify failure falls back whole
         eng = self.engine
         k = eng.spec_k
         # DRAFT (host): proposals for greedy slots only — an injected
         # draft fault skips proposing and the tick decodes plain
         drafts = {}
-        t0 = time.perf_counter_ns()
-        try:
-            _inject.check("serve.draft")
-            for slot in sorted(self.active):
-                req = self.active[slot]
-                if req.sampled:
-                    continue
-                d = self.draft.propose(list(req.prompt) + req.tokens, k)
-                if d:
-                    drafts[slot] = [int(t) for t in d[:k]]
-        except TransientError:
-            drafts = {}
-        if tr and decode_span is not None:
-            _tracing.get_tracer().record(
-                "draft", t0, time.perf_counter_ns(), parent=decode_span,
-                attrs={"proposed": sum(len(d) for d in drafts.values())})
-        if not drafts:
-            return None  # nothing to verify: the plain tick is cheaper
-        feed = np.zeros((eng.max_batch, k + 1), np.int32)
-        for slot, req in self.active.items():
-            feed[slot, 0] = req.tokens[-1]
-        for slot, d in drafts.items():
-            feed[slot, 1:1 + len(d)] = d
+        with _telemetry.phase_span("serve.draft") as draft_span:
+            try:
+                _inject.check("serve.draft")
+                for slot in sorted(self.active):
+                    req = self.active[slot]
+                    if req.sampled:
+                        continue
+                    d = self.draft.propose(list(req.prompt) + req.tokens, k)
+                    if d:
+                        drafts[slot] = [int(t) for t in d[:k]]
+            except TransientError:
+                drafts = {}
+            draft_span.set_attr(
+                "proposed", sum(len(d) for d in drafts.values()))
+            if not drafts:
+                return None  # nothing to verify: the plain tick is cheaper
+            feed = np.zeros((eng.max_batch, k + 1), np.int32)
+            for slot, req in self.active.items():
+                feed[slot, 0] = req.tokens[-1]
+            for slot, d in drafts.items():
+                feed[slot, 1:1 + len(d)] = d
         # VERIFY: any failure — injected serve.verify fault or a real
         # OOM — falls back to the plain tick and its degrade machinery;
         # the injection point fires pre-donation, so the cache is intact
@@ -726,50 +799,48 @@ class Scheduler:
                 tm.inc("serve.spec_fallback_ticks")
             return None
         # ACCEPT (host): compare drafts to the verifier's greedy stream
-        t1 = time.perf_counter_ns()
-        emitted = {}
-        advance = np.zeros((eng.max_batch,), np.int32)
-        proposed = accepted = 0
-        for slot in sorted(self.active):
-            req = self.active[slot]
-            if req.sampled:
-                # sampled slots commit their window-position-0 draw:
-                # byte-identical to what a plain tick would have drawn
-                toks = [int(tok0[slot])]
-            else:
-                d = drafts.get(slot, [])
-                a = 0
-                while a < len(d) and d[a] == int(greedy[slot, a]):
-                    a += 1
-                proposed += len(d)
-                accepted += a
-                toks = d[:a] + [int(greedy[slot, a])]
-                if d:
-                    self.draft.observe(list(req.prompt) + req.tokens, a)
-            # budget first, then EOS — the same order plain eviction
-            # applies them (_exhausted checks eos before length)
-            toks = toks[:max(1, req.max_new_tokens - len(req.tokens))]
-            if req.eos_id is not None and req.eos_id in toks:
-                toks = toks[:toks.index(req.eos_id) + 1]
-            emitted[slot] = toks
-            advance[slot] = len(toks)
-        # K/V rows for every committed token were already written by the
-        # verify step itself — committing is just the length add
-        eng.commit_lengths(advance)
-        self._spec_proposed += proposed
-        self._spec_accepted += accepted
-        if tm is not None:
-            tm.inc("serve.spec_ticks")
-            if proposed:
-                tm.inc("serve.spec_proposed", proposed)
-                tm.inc("serve.spec_accepted", accepted)
-            if self._spec_proposed:
-                tm.set_gauge("serve.spec_acceptance_rate",
-                             self._spec_accepted / self._spec_proposed)
-        if tr and decode_span is not None:
-            _tracing.get_tracer().record(
-                "accept", t1, time.perf_counter_ns(), parent=decode_span,
-                attrs={"proposed": proposed, "accepted": accepted})
+        with _telemetry.phase_span("serve.accept") as accept_span:
+            emitted = {}
+            advance = np.zeros((eng.max_batch,), np.int32)
+            proposed = accepted = 0
+            for slot in sorted(self.active):
+                req = self.active[slot]
+                if req.sampled:
+                    # sampled slots commit their window-position-0 draw:
+                    # byte-identical to what a plain tick would have drawn
+                    toks = [int(tok0[slot])]
+                else:
+                    d = drafts.get(slot, [])
+                    a = 0
+                    while a < len(d) and d[a] == int(greedy[slot, a]):
+                        a += 1
+                    proposed += len(d)
+                    accepted += a
+                    toks = d[:a] + [int(greedy[slot, a])]
+                    if d:
+                        self.draft.observe(list(req.prompt) + req.tokens, a)
+                # budget first, then EOS — the same order plain eviction
+                # applies them (_exhausted checks eos before length)
+                toks = toks[:max(1, req.max_new_tokens - len(req.tokens))]
+                if req.eos_id is not None and req.eos_id in toks:
+                    toks = toks[:toks.index(req.eos_id) + 1]
+                emitted[slot] = toks
+                advance[slot] = len(toks)
+            # K/V rows for every committed token were already written by
+            # the verify step itself — committing is just the length add
+            eng.commit_lengths(advance)
+            self._spec_proposed += proposed
+            self._spec_accepted += accepted
+            if tm is not None:
+                tm.inc("serve.spec_ticks")
+                if proposed:
+                    tm.inc("serve.spec_proposed", proposed)
+                    tm.inc("serve.spec_accepted", accepted)
+                if self._spec_proposed:
+                    tm.set_gauge("serve.spec_acceptance_rate",
+                                 self._spec_accepted / self._spec_proposed)
+            accept_span.set_attr("proposed", proposed)
+            accept_span.set_attr("accepted", accepted)
         return emitted
 
     # -- resilience ----------------------------------------------------------
@@ -1053,6 +1124,9 @@ class Scheduler:
             req.prefill_span.set_attr("prefill_off", req.prefill_off)
             req.prefill_span.end()
             req.prefill_span = None
+        if req.decode_span is not None:
+            req.decode_span.end()
+            req.decode_span = None
         if req.trace_span is not None:
             if req.finish_reason not in ("eos", "length"):
                 self._record_event_span(req, req.finish_reason,

@@ -435,3 +435,108 @@ def test_kernel_on_concrete_sharded_operands_partitions_itself():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(_ref_cached(q, k, v, q_pos, kv_len)),
         atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# stable kernel names: what a device trace (and its readers) holds on to
+# ---------------------------------------------------------------------------
+def _pallas_names(jaxpr):
+    """Names of every ``pallas_call`` in a jaxpr, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names.extend(_pallas_names(sub))
+    return names
+
+
+def _names_of(fn, *args):
+    with pallas.interpret_mode():
+        return _pallas_names(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def _sum_grad(fn, n):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=tuple(range(n)))
+
+
+def _site_flash():
+    q, k, v = _rand_qkv(b=1, s=128)
+    return _sum_grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128), 3), (q, k, v)
+
+
+def _site_flash_cached():
+    from paddle_tpu.ops.pallas import flash_attention_cached
+
+    q, k, v = _rand_qkv(b=1, s=128)
+    q_pos = np.arange(128, dtype=np.int32)[None]
+    return (lambda q, k, v: flash_attention_cached(
+        q, k, v, q_pos, np.asarray([128], np.int32), block_q=128,
+        block_k=128)), (q, k, v)
+
+
+def _site_flash_packed():
+    from paddle_tpu.ops.pallas.flash_attention_packed import (
+        flash_attention_packed)
+
+    x = jnp.ones((1, 128, 2 * 64), jnp.float32)
+    return _sum_grad(lambda q, k, v: flash_attention_packed(
+        q, k, v, 2, causal=True, block_q=128, block_k=128,
+        interpret=True), 3), (x, x, x)
+
+
+def _site_layer_norm():
+    x = jnp.ones((2, 8, 256), jnp.float32)
+    g = jnp.ones((256,), jnp.float32)
+    return _sum_grad(lambda x, g, b: fused_layer_norm(x, g, b), 3), (x, g, g)
+
+
+def _site_fused_ce():
+    from paddle_tpu.ops.pallas import fused_ce
+
+    x = jnp.ones((64, 128), jnp.float32)
+    w = jnp.ones((256, 128), jnp.float32)
+    y = jnp.zeros((64,), jnp.int32)
+
+    def both(x, w):
+        loss, lse = fused_ce.ce_forward(x, w, None, y, interpret=True)
+        return fused_ce.ce_backward(x, w, None, y, jnp.ones_like(loss), lse,
+                                    interpret=True)
+
+    return both, (x, w)
+
+
+@pytest.mark.parametrize("site,expected", [
+    (_site_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    (_site_flash_cached, {"flash_cached_fwd"}),
+    (_site_flash_packed, {"flash_packed_fwd", "flash_packed_bwd"}),
+    (_site_layer_norm, {"layer_norm_fwd", "layer_norm_bwd"}),
+    (_site_fused_ce, {"cross_entropy_fwd", "cross_entropy_bwd_dx",
+                      "cross_entropy_bwd_dw"}),
+], ids=["flash", "flash_cached", "flash_packed", "layer_norm", "fused_ce"])
+def test_every_pallas_call_site_carries_its_name(site, expected):
+    """Each of the eleven ``pl.pallas_call`` sites names its kernel: the
+    name is the custom call's in the device trace (``%jvp_flash_packed_fwd_``
+    on the v5e), which is what a reader's pattern holds on to."""
+    fn, args = site()
+    assert set(_names_of(fn, *args)) == expected
+
+
+def test_no_pallas_call_site_is_left_unnamed():
+    import pathlib
+    import re
+
+    src_dir = pathlib.Path(pallas.__file__).parent
+    calls = named = 0
+    for path in src_dir.glob("*.py"):
+        text = path.read_text()
+        for m in re.finditer(r"pl\.pallas_call\(", text):
+            calls += 1
+            named += bool(re.match(r"\s*[\w.()=, ]+,\s*name=\"\w+\"",
+                                   text[m.end():m.end() + 200]))
+    assert calls == 11 and named == calls

@@ -366,6 +366,157 @@ def test_scheduler_publishes_telemetry():
     assert latency.get("count") == 4
 
 
+def test_tick_record_holds_the_named_phases():
+    """Every Scheduler.step() leaves one tick record in telemetry's ring:
+    the scheduler's own tick index and id, ``serve.tick`` around the named
+    phases, each nested inside it, their sum no longer than it."""
+    model = _gpt(max_pos=64)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        eng = GenerationEngine(model, max_batch=2, max_len=64,
+                               prefill_buckets=(8, 16))
+        other = Scheduler(eng)          # an earlier scheduler: another id
+        other.submit(Request(prompt=[1, 2, 3], max_new_tokens=2))
+        other.run()
+        sched = Scheduler(eng)
+        assert sched.sched_id > other.sched_id
+        for r in _request_stream(2, 4):
+            r.max_new_tokens = 4
+            sched.submit(r)
+        steps = 0
+        while sched.queue or sched.active:
+            sched.step()
+            steps += 1
+        tm = telemetry.get_telemetry()
+        ticks = tm.steps(kind="serve.tick", owner=sched.sched_id)
+        others = tm.steps(kind="serve.tick", owner=other.sched_id)
+        waits = tm.get("phase.serve.queue_wait")
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert [t.index for t in ticks] == list(range(steps))
+    assert [t.index for t in others] == list(range(len(others))) != []
+    top = set(telemetry.SERVE_PHASES)
+    admits = 0
+    for t in ticks:
+        (tick,) = [sp for sp in t.spans if sp[0] == "serve.tick"]
+        inside = [sp for sp in t.spans
+                  if sp[0] not in ("serve.tick", "serve.queue_wait")]
+        # every phase lies inside the tick ...
+        assert all(tick[1] <= a <= b <= tick[2] for _, a, b in inside)
+        # ... the top-level ones are disjoint and in tick order ...
+        tops = [sp for sp in inside if sp[0] in top]
+        assert all(x[2] <= y[1] for x, y in zip(tops, tops[1:]))
+        assert sum(t.phases[n] for n in top if n in t.phases) \
+            <= t.phases["serve.tick"]
+        # ... and the tick ran the plain decode path's phases
+        assert [n for n, _, _ in tops if n != "serve.admit"] == [
+            "serve.expire", "serve.decode_feed", "serve.decode_dispatch",
+            "serve.decode_readback", "serve.bookkeep"]
+        if "serve.admit" in t.phases:
+            (admit,) = [sp for sp in tops if sp[0] == "serve.admit"]
+            kids = [sp for sp in inside if sp[0].startswith("serve.prefill_")]
+            n = sum(1 for sp in t.spans if sp[0] == "serve.queue_wait")
+            admits += n
+            assert [k[0] for k in kids] == [
+                "serve.prefill_dispatch", "serve.prefill_readback"] * n
+            assert all(admit[1] <= a <= b <= admit[2] for _, a, b in kids)
+        # the compiled step's own dispatch (or compile) nests in the phase
+        (disp,) = [sp for sp in tops if sp[0] == "serve.decode_dispatch"]
+        inner = [sp for sp in inside if sp[0] in ("dispatch", "compile")
+                 and disp[1] <= sp[1] and sp[2] <= disp[2]]
+        assert len(inner) == 1
+    # one queue wait per admitted request, in the tick that admitted it
+    assert admits == 4 == waits["count"] - 1  # the other scheduler's one
+
+
+def test_ttft_and_queue_wait_count_from_due():
+    """A load generator sets ``due_ns``; a late submit then counts as
+    waiting. Without it a request is due when it is submitted."""
+    import time
+
+    model = _gpt(max_pos=64)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        eng = GenerationEngine(model, max_batch=2, max_len=64,
+                               prefill_buckets=(8,))
+        sched = Scheduler(eng)
+        late = Request(prompt=[1, 2, 3], max_new_tokens=2,
+                       due_ns=time.perf_counter_ns() - 250_000_000)
+        plain = Request(prompt=[1, 2, 3], max_new_tokens=2)
+        sched.submit(late)
+        sched.submit(plain)
+        sched.run()
+        tm = telemetry.get_telemetry()
+        (tick,) = [t for t in tm.steps(kind="serve.tick",
+                                       owner=sched.sched_id)
+                   if "serve.queue_wait" in t.phases]
+        ttft = tm.get("serve.ttft_s")
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert plain.due_ns == plain.submit_ns
+    assert late.due_ns < late.submit_ns
+    assert late.ttft_s == pytest.approx(
+        (late.first_token_ns - late.due_ns) / 1e9)
+    assert late.ttft_s >= 0.25
+    # admitted in FIFO order: the late request's wait is the first filed
+    w_late, w_plain = [(b - a) / 1e9 for n, a, b in tick.spans
+                       if n == "serve.queue_wait"]
+    assert 0.25 <= w_late <= late.ttft_s
+    assert w_plain <= plain.ttft_s
+    assert plain.ttft_s == pytest.approx(
+        (plain.first_token_ns - plain.submit_ns) / 1e9)
+    assert ttft["sum"] == pytest.approx(late.ttft_s + plain.ttft_s)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill", "verify", "chunk"])
+def test_engine_steps_lower_under_their_step_names(which):
+    """Every serving program has a name a trace reader can hold:
+    ``jit_serve_decode`` and not the one closure name all steps shared."""
+    eng = GenerationEngine(_gpt(max_pos=64), max_batch=2, max_len=64,
+                           prefill_buckets=(8,), spec_k=2, prefill_chunk=8)
+    step, args = {
+        "decode": (eng.decode_step, eng.example_decode_args([1])),
+        "prefill": (eng.prefill_step,
+                    (np.zeros((1, 8), np.int32), np.int32(1), np.int32(0),
+                     eng._example_cache([0]))),
+        "verify": (eng.verify_step, eng.example_verify_args([1])),
+        "chunk": (eng.chunk_step, eng.example_chunk_args([0])),
+    }[which]
+    name = {"chunk": "serve_prefill_chunk"}.get(which, f"serve_{which}")
+    assert step.name == name
+    assert f"module @jit_{name}" in step.lower(*args).as_text()
+
+
+def test_speculative_tick_files_draft_verify_accept_phases():
+    model = _gpt(max_pos=64)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        eng = GenerationEngine(model, max_batch=2, max_len=64,
+                               prefill_buckets=(8,), spec_k=2)
+        sched = Scheduler(eng)
+        sched.submit(Request(prompt=[5, 6, 5, 6, 5, 6], max_new_tokens=8))
+        sched.run()
+        tm = telemetry.get_telemetry()
+        ticks = tm.steps(kind="serve.tick", owner=sched.sched_id)
+        spec_ticks = tm.counters().get("serve.spec_ticks", 0)
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    spec = [t for t in ticks if "serve.verify_dispatch" in t.phases]
+    assert len(spec) == spec_ticks >= 1
+    for t in spec:
+        names = [n for n, _, _ in t.spans if n in telemetry.SERVE_PHASES]
+        assert names[-5:] == ["serve.draft", "serve.verify_dispatch",
+                              "serve.verify_readback", "serve.accept",
+                              "serve.bookkeep"]
+        assert sum(t.phases[n] for n in set(names)) <= t.phases["serve.tick"]
+
+
 def test_scheduler_gauges_retired_on_drain_and_shutdown():
     """Regression (ISSUE 8 satellite, mirrors the PR 5 DeviceLoader fix):
     a drained or shut-down scheduler must not leave stale

@@ -53,8 +53,11 @@ def test_disabled_by_default_and_null_span_singleton():
     # the disabled-path span is a shared no-op object: no allocation, no
     # timing, no locking — the zero-overhead contract
     s1 = telemetry.phase_span("data_wait")
-    s2 = telemetry.phase_span("dispatch")
+    s2 = telemetry.phase_span("dispatch", attrs={"k": 1}, key="train_step")
     assert s1 is s2
+    # ... the very singleton tracing hands out: one no-op for both systems
+    from paddle_tpu.profiler import tracing
+    assert s1 is tracing.NULL_SPAN
     with s1:
         pass
     tm = telemetry.get_telemetry()
@@ -178,6 +181,139 @@ def test_compiled_step_compile_then_dispatch():
     c = tm.counters()
     assert c["compile.count"] == first_compiles  # cached: no retrace
     assert telemetry.summary()["phases"]["dispatch"]["count"] >= 2
+
+
+def test_compile_seconds_split_sums_to_the_compile_phase():
+    """jax.monitoring's trace / lowering / backend durations are
+    attributed to the step whose call is in flight; with the rest of the
+    call (``first_run_s``) they close to what the one ``compile`` phase
+    measured — the old, unsplit ``compile_seconds``."""
+    telemetry.enable()
+    step = _compiled_linear_step()
+    x = paddle.to_tensor(np.random.randn(4, 3).astype(np.float32))
+    step(x)
+    step(x)
+    tm = telemetry.get_telemetry()
+    parts = tm.compile_seconds()["train_step"]
+    assert set(parts) == set(telemetry.COMPILE_PARTS)
+    assert all(v >= 0.0 for v in parts.values())
+    # JAX did trace, lower and compile (or load) inside that call
+    assert parts["trace_s"] > 0 and parts["lower_s"] > 0
+    assert parts["backend_s"] > 0
+    compiled = [r for r in tm.phase_records("compile", key="train_step")]
+    assert len(compiled) == tm.compile_counts()["train_step"]
+    wall = sum(b - a for _, a, b, _, _ in compiled) / 1e9
+    assert sum(parts.values()) == pytest.approx(wall, rel=1e-6)
+    # the second call hit the executable: a keyed `dispatch`, no new part
+    assert tm.phase_records("dispatch", key="train_step")
+    assert tm.compile_seconds()["train_step"] == parts
+
+
+def test_split_compile_gives_each_instant_to_one_part():
+    ms = 1_000_000
+    watch = {"trace_s": [(0, 40 * ms), (5 * ms, 10 * ms)],  # nested helper
+             "lower_s": [(40 * ms, 70 * ms), (50 * ms, 55 * ms)],
+             "backend_s": [(70 * ms, 90 * ms)], "cache_hits": 0}
+    watch["trace_s"].append((50 * ms, 55 * ms))  # a trace made while lowering
+    split = telemetry._split_compile(watch, 0, 100 * ms)
+    assert split == pytest.approx({"trace_s": 0.040, "lower_s": 0.030,
+                                   "backend_s": 0.020, "first_run_s": 0.010})
+    # events of another call's time (before the start) are clipped away
+    watch["backend_s"].append((-30 * ms, -10 * ms))
+    assert telemetry._split_compile(watch, 0, 100 * ms) == pytest.approx(split)
+
+
+def test_persistent_cache_hit_is_counted(tmp_path):
+    import jax
+
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+            jax.config.jax_persistent_cache_min_entry_size_bytes)
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        telemetry.enable()
+        x = paddle.to_tensor(np.ones((5, 7), np.float32))
+        _compiled_linear_step(in_dim=7)(x)   # cold: writes the entry
+        tm = telemetry.get_telemetry()
+        cold = tm.counters().get("compile.cache_hits", 0)
+        _compiled_linear_step(in_dim=7)(x)   # same program: loads it
+        assert tm.counters().get("compile.cache_hits", 0) > cold
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev[1])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          prev[2])
+        cc.reset_cache()
+
+
+def test_compiled_step_lowers_under_its_step_name():
+    """The program's name in a device trace and in the compile cache's
+    key is the step's own, not the one closure name every step shared."""
+    step = _compiled_linear_step()
+    x = paddle.to_tensor(np.random.randn(4, 3).astype(np.float32))
+    assert step.name == "train_step"
+    assert "module @jit_train_step" in step.lower(x).as_text()
+
+
+def test_phase_spans_reach_a_live_profiler_trace(tmp_path):
+    """Sink (c): under any live jax.profiler session the program's phases
+    are ``paddle_tpu:<name>`` annotations in the xplane, on the profiler's
+    own clock, nested as the phases nest."""
+    import jax
+
+    telemetry.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.phase_span("serve.tick"):
+            with telemetry.phase_span("serve.decode_readback"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[-1]
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(telemetry.ANNOTATION_PREFIX):
+                    found[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    tick = found["paddle_tpu:serve.tick"]
+    wait = found["paddle_tpu:serve.decode_readback"]
+    assert tick[0] <= wait[0] <= wait[1] <= tick[1]
+    assert wait[1] - wait[0] >= 2_000_000
+    # and the same boundary call filed the phase in telemetry's own ring
+    assert [r[0] for r in telemetry.get_telemetry().phase_records()] == [
+        "serve.decode_readback", "serve.tick"]
+
+
+def test_open_record_keeps_index_owner_and_raw_spans():
+    telemetry.enable()
+    tm = telemetry.get_telemetry()
+    telemetry.step_begin()            # a train record is open underneath
+    rec = tm.open_record("serve.tick", index=41, owner=7)
+    with telemetry.phase_span("serve.tick"):
+        with telemetry.phase_span("serve.expire"):
+            pass
+    tm.close_record(rec)
+    with telemetry.phase_span("dispatch"):
+        pass                          # lands in the train record again
+    telemetry.step_end()
+    (tick,) = tm.steps(kind="serve.tick")
+    assert (tick.index, tick.owner) == (41, 7)
+    assert [n for n, _, _ in tick.spans] == ["serve.expire", "serve.tick"]
+    assert set(tick.phases) == {"serve.expire", "serve.tick"}
+    assert tm.steps(kind="serve.tick", owner=8) == []
+    (train,) = tm.steps(kind="step")
+    assert set(train.phases) == {"dispatch"}
+    # the elastic heartbeat's step-time gauge is a train step's only
+    assert tm.gauges()["step.time_s"] == train.wall_s
+    assert tick.as_dict()["kind"] == "serve.tick"
 
 
 def test_recompile_warning_on_shape_churn():

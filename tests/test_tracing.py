@@ -70,7 +70,11 @@ def test_disabled_by_default_null_singletons():
     with tracing.activate(s1):
         pass
     assert tracing.get_tracer().spans() == []
-    assert tracing.note_compile("step", 0, 1) is None
+    # the boundary call hands out the very same singleton while both
+    # systems are off, and files nothing anywhere
+    assert not telemetry.enabled()
+    assert telemetry.phase_span("dispatch") is tracing.NULL_SPAN
+    assert telemetry.get_telemetry().phase_records() == []
 
 
 def test_span_nesting_parenting_and_ids():
@@ -207,37 +211,42 @@ def test_request_trace_reconstructs_end_to_end(tmp_path, _clean_telemetry):
         root = by_name["request"][0]
         queue = by_name["queue"][0]
         prefill = by_name["prefill"][0]
-        decodes = sorted(by_name["decode_token"],
-                         key=lambda s: s.attrs["index"])
+        # ONE decode span per request: first decoded token -> evict
+        assert len(by_name["decode"]) == 1
+        decode = by_name["decode"][0]
+        stamps = decode.attrs["token_end_ns"]
 
         # all spans share the request's trace and hang off its root
         assert root.parent_id is None
         assert queue.parent_id == root.span_id
         assert prefill.parent_id == root.span_id
-        assert all(d.parent_id == root.span_id for d in decodes)
+        assert decode.parent_id == root.span_id
 
         # the life cycle is ordered: submit → queue wait → prefill →
-        # every decode token interval → evict
+        # every decode token's stamp → evict
         assert root.start_ns <= queue.start_ns <= queue.end_ns
         assert queue.end_ns <= prefill.start_ns <= prefill.end_ns
-        prev = prefill.end_ns
-        for d in decodes:
-            assert d.start_ns >= prev - 1  # shared batched interval
-            prev = d.end_ns
-        assert root.end_ns >= prev
+        assert decode.start_ns >= prefill.end_ns - 1
+        prev = decode.start_ns
+        for t in stamps:
+            assert t >= prev  # the shared batched interval's end
+            prev = t
+        assert root.end_ns >= decode.end_ns >= prev
 
-        # token accounting: prefill's token + one decode span per
-        # subsequent token
-        assert len(decodes) == len(req.tokens) - 1
-        assert [d.attrs["token"] for d in decodes] == req.tokens[1:]
+        # token accounting: prefill's token + one stamp (and one shared
+        # decode_step id) per subsequent token
+        assert len(stamps) == len(req.tokens) - 1
+        assert len(decode.attrs["decode_steps"]) == len(stamps)
+        assert decode.attrs["tokens"] == req.tokens[1:]
+        assert decode.attrs["first_index"] == 1
         assert root.attrs["finish_reason"] == req.finish_reason
         assert root.attrs["ttft_s"] == pytest.approx(req.ttft_s)
         assert root.attrs["latency_s"] == pytest.approx(req.latency_s)
 
-        # the engine's serve_prefill span nests inside the scheduler's
-        # prefill span — same trace, so compile attribution joins up
-        engine_pf = by_name["serve_prefill"][0]
-        assert engine_pf.parent_id == prefill.span_id
+        # the engine's prefill phases nest inside the scheduler's prefill
+        # span — same trace, so compile attribution joins up
+        for phase in ("serve.prefill_dispatch", "serve.prefill_readback"):
+            assert by_name[phase][0].parent_id == prefill.span_id
 
     # compile attribution: the FIRST request through a cold bucket carries
     # the serve_prefill compile span inside its own trace
@@ -246,22 +255,31 @@ def test_request_trace_reconstructs_end_to_end(tmp_path, _clean_telemetry):
     assert comp, "no compile span attributed to the first request"
     assert comp[0].attrs["step"] == "serve_prefill"
     assert comp[0].attrs["compile_index"] == 1
+    disp = [s for s in tr.spans(first.trace_id)
+            if s.name == "serve.prefill_dispatch"]
+    assert comp[0].parent_id == disp[0].span_id
+    # a warm bucket's call is a plain `dispatch` under the same phase
+    warm = [s for s in tr.spans(reqs[1].trace_id) if s.name == "dispatch"]
+    assert warm and not [s for s in tr.spans(reqs[1].trace_id)
+                         if s.name == "compile"]
 
     # JSONL export round-trips the whole reconstruction
     p = tmp_path / "req.jsonl"
     tr.export_jsonl(str(p), trace_id=first.trace_id)
     rows = [json.loads(l) for l in p.read_text().splitlines()]
     assert {r["trace"] for r in rows} == {first.trace_id}
-    assert {"request", "queue", "prefill", "decode_token",
+    assert {"request", "queue", "prefill", "decode",
             "compile"} <= {r["name"] for r in rows}
+    assert [r for r in rows if r["name"] == "decode"][0]["attrs"][
+        "tokens"] == first.tokens[1:]
 
     # PR 6 contract unchanged under tracing: decode compiled EXACTLY once
     assert telemetry.get_telemetry().compile_counts()["serve_decode"] == 1
 
 
 def test_shared_decode_step_one_span_per_active_request(_clean_telemetry):
-    """Two requests decoding in the same batched step: each gets its OWN
-    decode_token span over the shared interval, linked to the shared
+    """Two requests decoding in the same batched step: each request's OWN
+    decode span holds a stamp for that step, linked to the shared
     decode_step span."""
     telemetry.enable()
     tracing.enable()
@@ -273,17 +291,153 @@ def test_shared_decode_step_one_span_per_active_request(_clean_telemetry):
     assert session and shared
     assert all(s.parent_id == session[0].span_id for s in shared)
     # both requests were admitted in tick 0, so every decode_step ran 2
-    # slots: per shared span, exactly one decode_token per request
+    # slots: per shared span, exactly one token stamp per request
+    decodes = [s for s in tr.spans() if s.name == "decode"]
+    assert len(decodes) == 2  # one per request, not one per token
     for ds in shared:
-        linked = [s for s in tr.spans()
-                  if s.name == "decode_token"
-                  and s.attrs.get("decode_span") == ds.span_id]
+        linked = [s for s in decodes
+                  if ds.span_id in s.attrs["decode_steps"]]
         assert len(linked) == ds.attrs["active"] == 2
         assert ({s.trace_id for s in linked}
                 == {r.trace_id for r in reqs})
-        # the fan-out reuses the shared dispatch interval verbatim
-        assert all(s.start_ns == ds.start_ns and s.end_ns == ds.end_ns
-                   for s in linked)
+        assert all(s.attrs["decode_trace"] == ds.trace_id for s in linked)
+        # each stamp is the shared dispatch interval's end, verbatim
+        for s in linked:
+            i = s.attrs["decode_steps"].index(ds.span_id)
+            assert s.attrs["token_end_ns"][i] == ds.end_ns
+            assert s.start_ns <= ds.start_ns
+    # the shared span holds the tick's decode phases, on the session trace
+    for name in ("serve.decode_feed", "serve.decode_dispatch",
+                 "serve.decode_readback"):
+        kids = [s for s in tr.spans() if s.name == name]
+        assert len(kids) == len(shared)
+        assert {s.parent_id for s in kids} == {ds.span_id for ds in shared}
+
+
+def test_one_boundary_call_feeds_both_sinks_identically(_clean_telemetry):
+    """``telemetry.phase_span`` is THE boundary call: the phase in
+    telemetry's ring and the ``Span`` in the tracer's carry the same name
+    and the very same start and end stamps; nothing is timed twice."""
+    telemetry.enable()
+    tracing.enable()
+    with tracing.span("root") as root:
+        with telemetry.phase_span("serve.decode_readback",
+                                  attrs={"slot": 3}, key="k") as ph:
+            assert tracing.current_span().name == "serve.decode_readback"
+            ph.set_attr("late", True)
+    (phase,) = telemetry.get_telemetry().phase_records("serve.decode_readback")
+    (span,) = [s for s in tracing.get_tracer().spans()
+               if s.name == "serve.decode_readback"]
+    assert (phase[1], phase[2]) == (span.start_ns, span.end_ns)
+    assert (ph.start_ns, ph.end_ns) == (span.start_ns, span.end_ns)
+    assert phase[4] == "k"
+    assert span.parent_id == root.span_id and span.trace_id == root.trace_id
+    assert span.attrs == {"slot": 3, "late": True}
+    # telemetry alone: the phase, no Span; tracing alone: the Span, no phase
+    tracing.disable()
+    with telemetry.phase_span("dispatch"):
+        pass
+    assert not [s for s in tracing.get_tracer().spans()
+                if s.name == "dispatch"]
+    telemetry.disable()
+    tracing.enable()
+    n = len(telemetry.get_telemetry().phase_records())
+    with tracing.span("root2"):
+        with telemetry.phase_span("h2d_copy"):
+            pass
+    assert [s for s in tracing.get_tracer().spans() if s.name == "h2d_copy"]
+    assert len(telemetry.get_telemetry().phase_records()) == n
+    # a phase joins a trace, it never roots one
+    with telemetry.phase_span("data_wait"):
+        pass
+    assert not [s for s in tracing.get_tracer().spans()
+                if s.name == "data_wait"]
+
+
+def test_compiled_step_call_is_one_span_dispatch_or_compile(_clean_telemetry):
+    """CompiledStep.__call__ marks its boundary once: the call that traces
+    is a ``compile`` in both sinks (same stamps), a cached one a
+    ``dispatch``; there is no second timing of either."""
+    from paddle_tpu.jit.functionalize import CompiledStep
+
+    telemetry.enable()
+    tracing.enable()
+    lin = paddle.nn.Linear(3, 3)
+    step = CompiledStep(lambda x: lin(x).square().mean(), stateful=[lin])
+    step.name = "toy_step"
+    x = paddle.to_tensor(np.ones((2, 3), np.float32))
+    with tracing.span("root"):
+        step(x)
+        step(x)
+    tm = telemetry.get_telemetry()
+    spans = {s.name: s for s in tracing.get_tracer().spans()}
+    for name in ("compile", "dispatch"):
+        (phase,) = tm.phase_records(name, key="toy_step")
+        assert (phase[1], phase[2]) == (spans[name].start_ns,
+                                        spans[name].end_ns)
+    assert spans["compile"].attrs == {"step": "toy_step", "compile_index": 1}
+    assert tm.compile_counts() == {"toy_step": 1}
+
+
+class _StubEngine:
+    """Scheduler-facing engine surface with no model behind it: every slot
+    decodes token 1. Lets a test drive hundreds of full-width ticks."""
+
+    def __init__(self, max_batch, max_len=512):
+        self.max_batch, self.max_len = max_batch, max_len
+        self.prefill_buckets = (max_len,)
+        self.spec_k = 0
+        self.prefill_chunk = None
+
+    def prefill(self, slot, prompt):
+        with telemetry.phase_span("serve.prefill_dispatch"):
+            pass
+        with telemetry.phase_span("serve.prefill_readback"):
+            return 1
+
+    def decode_once(self, feed):
+        with telemetry.phase_span("serve.decode_dispatch"):
+            pass
+        with telemetry.phase_span("serve.decode_readback"):
+            return np.ones((self.max_batch,), np.int32)
+
+
+def test_a_saturated_window_fits_the_default_ring(_clean_telemetry):
+    """200 ticks at 32 slots with requests of ~50 tokens (what a saturated
+    benchmark window holds: ~6,400 tokens, ~130 requests): the default
+    8,192-span ring drops nothing and keeps every request's queue and
+    prefill span. The per-tick ``decode_token`` fan-out this replaced
+    would have filed 6,400 spans for the tokens alone."""
+    telemetry.enable()
+    tracing.enable()
+    tr = tracing.get_tracer()
+    assert tr.ring_size == 8192
+    sched = Scheduler(_StubEngine(32))
+    rng = np.random.RandomState(0)
+    reqs = []
+    for tick in range(200):
+        while len(sched.queue) + len(sched.active) < 36:
+            reqs.append(sched.submit(Request(
+                prompt=[1] * 8, max_new_tokens=int(rng.randint(40, 60)))))
+        sched.step()
+    assert sched.decode_steps == 200 and sched.occupancy() == 1.0
+    sched.shutdown()
+    assert sum(len(r.tokens) for r in reqs) >= 6000
+    assert tr.dropped == 0
+    names = [s.name for s in tr.spans()]
+    admitted = [r for r in reqs if r.tokens]
+    assert names.count("queue") == len(reqs) >= 120
+    assert names.count("prefill") == len(admitted)
+    assert names.count("decode") == len(admitted)
+    assert names.count("serve.tick") == 200
+    # every token is still on the record: in its request's decode span
+    decoded = sum(len(s.attrs["token_end_ns"]) for s in tr.spans()
+                  if s.name == "decode")
+    assert decoded == sum(len(r.tokens) - 1 for r in admitted)
+    # and telemetry's ring holds all 200 tick records of this scheduler
+    ticks = telemetry.get_telemetry().steps(kind="serve.tick",
+                                            owner=sched.sched_id)
+    assert [t.index for t in ticks] == list(range(200))
 
 
 def test_scheduler_tracing_off_is_free(_clean_telemetry):
@@ -306,8 +460,10 @@ def test_generate_emits_its_own_trace():
     assert len(gen) == 1
     inside = tr.spans(gen[0].trace_id)
     names = [s.name for s in inside]
-    assert names.count("serve_prefill") == 1
-    assert names.count("serve_decode") == len(out) - 1
+    assert names.count("serve.prefill_dispatch") == 1
+    assert names.count("serve.prefill_readback") == 1
+    assert names.count("serve.decode_dispatch") == len(out) - 1
+    assert names.count("serve.decode_readback") == len(out) - 1
 
 
 # ---------------------------------------------------------------------------
