@@ -56,7 +56,8 @@ TRAIN_STEPS = 6
 #: cached flash kernel at sq1024; on the speed-v2 engine prompts <= 128 take
 #: the short buckets (einsum) and longer ones stream through
 #: serve_prefill_chunk (cached kernel at sq128 x sk1024); decode and verify
-#: (sq 1 and 5) go through the blockwise scan on both.
+#: (sq 1 and 5) take the decode-shaped kernel on both: nothing is left for
+#: the blockwise scan (the rehearsal interprets the same kernels).
 SERVE_LEGS = (
     ("default", {}, [12, 100, 600, 7, 90, 13], [16, 12, 24, 16, 8, 20]),
     ("speed-v2", {"spec_k": 4, "prefill_chunk": 128},
@@ -287,6 +288,7 @@ def leg_kernels(size):
 
     from paddle_tpu.nn.functional.attention import (LengthMask, _sdpa_flash,
                                                     _sdpa_flash_cached,
+                                                    _sdpa_flash_decode,
                                                     _sdpa_raw)
     from paddle_tpu.nn.functional.norm import (_layer_norm_pallas,
                                                _layer_norm_raw)
@@ -294,7 +296,7 @@ def leg_kernels(size):
     bf, f32 = jnp.bfloat16, jnp.float32
     b, s, h = size.batch, size.seq, size.heads
     d = size.hidden // h
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 24))
 
     def rnd(shape, dtype):
         return jax.random.normal(next(keys), shape, f32).astype(dtype)
@@ -356,6 +358,24 @@ def leg_kernels(size):
         compare(f"flash_sdpa_cached sq{sq} sk{sk} b1 bf16", cached, dense,
                 (rnd((1, sq, h, d), bf), rnd((1, sk, h, d), bf),
                  rnd((1, sk, h, d), bf)), 0)
+    # the decode kernel as serving reaches it: every slot's one query row
+    # (verify: spec_k + 1 rows) over its own cache row, each slot at a length
+    # of its own: the first slot, a block boundary, the last slot, the rest
+    db = 32
+    lens = jnp.asarray(np.random.RandomState(0).randint(16, sk // 2, (db,)),
+                       jnp.int32).at[:3].set(jnp.asarray([0, 255, sk - 5]))
+    for sq in (1, 5):
+        mask = LengthMask(lens[:, None] + jnp.arange(sq, dtype=jnp.int32))
+
+        def decode(q, k, v, mask=mask):
+            return _sdpa_flash_decode.raw(q, k, v, mask.q_pos)
+
+        def dense(q, k, v, mask=mask, sk=sk):
+            return _sdpa_raw.raw(q, k, v, mask.additive(sk, q.dtype))
+
+        compare(f"flash_sdpa_decode sq{sq} sk{sk} b{db} bf16", decode, dense,
+                (rnd((db, sq, h, d), bf), rnd((db, sk, h, d), bf),
+                 rnd((db, sk, h, d), bf)), 0)
 
 
 def _periodic_prompt(rng, vocab, n):
@@ -444,13 +464,16 @@ def leg_serve(size, label, engine_kw, prompt_lens, new_tokens):
         # a speculative engine may never need its plain decode step
         ok = got <= n if (name == "serve_decode" and eng.spec_k) else got == n
         check(ok, f"{label}: {name} compiled {got} times, expected {n}")
-    check(seen["flash_sdpa_cached"] and seen["blockwise_sdpa"]
+    check(seen["flash_sdpa_cached"] and seen["flash_sdpa_decode"]
+          and not seen["blockwise_sdpa"]
           and seen["sdpa"] and seen["fused_layer_norm"]
           and not seen["layer_norm_op"],
-          f"{label}: a serving route did not run: {dict(seen)}")
+          f"{label}: a serving route did not run, or the blockwise scan "
+          f"did: {dict(seen)}")
     say(f"serve[{label}]: {len(first)} requests x2 (prompts {prompt_lens}), "
         f"all eos|length, replay identical; compiles {counts}; ops traced "
-        f"flash_sdpa_cached x{seen['flash_sdpa_cached']} blockwise_sdpa "
+        f"flash_sdpa_cached x{seen['flash_sdpa_cached']} flash_sdpa_decode "
+        f"x{seen['flash_sdpa_decode']} blockwise_sdpa "
         f"x{seen['blockwise_sdpa']} sdpa x{seen['sdpa']}")
     say(f"serve[{label}]: seconds in calls that compiled, per step "
         f"(trace, lower, backend, first run) "

@@ -8,9 +8,13 @@ and no attention dropout is requested; an XLA einsum path otherwise.
 Long context adds a third path: a blockwise online-softmax ``lax.scan`` over
 KV blocks (``_sdpa_blockwise``) that keeps the live logits at
 O(seq·block) instead of O(seq²) on every backend, selected for causal
-training above ``blockwise_attention_min_kv`` keys and for every cached
-(:class:`LengthMask`) serving call — prefill, chunked prefill, decode and
-speculative verify never materialize ``[b, h, q, max_len]`` scores.
+training above ``blockwise_attention_min_kv`` keys and for cached
+(:class:`LengthMask`) serving calls — prefill, chunked prefill, decode and
+speculative verify never materialize ``[b, h, q, max_len]`` scores. On the
+TPU the cached calls go to Pallas instead (``_route_length_masked``): the
+decode-shaped kernel for decode and verify, the length-masked flash kernel
+for 128-aligned query blocks; the scan is what XLA:CPU runs, and what takes
+a query shape neither kernel does.
 
 Routing is an EXPLICIT capability check (``_flash_ok`` /
 ``_blockwise_ok``), never a silent ``except`` fallback: if a kernel is
@@ -84,7 +88,13 @@ def _pick_block(n, pref):
 def _bw_fwd(q, k, v, q_pos, kv_len, scale, block_k):
     """Forward scan over KV blocks. Carry: running (max, denom, acc) per
     query row; the only O(block)-wide temporary is the ``[b, h, sq,
-    block_k]`` score tile of the current block."""
+    block_k]`` score tile of the current block.
+
+    Written for backends without Pallas (XLA:CPU, tier-1) and for query
+    shapes no kernel takes. It is not a decode path for the TPU: K and V are
+    copied to float32 and re-laid out block-major before the scan, and every
+    block is visited whatever the lengths say (168 of a 195 ms GPT-2 large
+    decode step on the v5e before ``flash_sdpa_decode`` took that route)."""
     f32 = jnp.float32
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -249,9 +259,12 @@ def _blockwise_blocks(sq, sk):
 
 
 def _route_length_masked(query, key, value, lm, dropout_p, training, scale):
-    """Cached-attention routing: Pallas length-masked kernel when the shapes
-    tile onto the MXU, blockwise scan otherwise, dense on-the-fly mask below
-    the min-kv threshold (or under attention dropout)."""
+    """Cached-attention routing. With Pallas: the decode-shaped kernel for a
+    few query rows (decode, verify), the length-masked flash kernel for
+    128-aligned query blocks (long prefill buckets, prefill chunks). The
+    blockwise scan is what is left: every backend without Pallas (XLA:CPU,
+    tier-1) and query shapes neither kernel takes. Below the min-kv
+    threshold (or under attention dropout): dense on-the-fly mask."""
     b, sq, h, d = query.shape
     sk = key.shape[1]
     active_p = dropout_p if training else 0.0
@@ -261,7 +274,11 @@ def _route_length_masked(query, key, value, lm, dropout_p, training, scale):
         s = scale if scale is not None else 1.0 / math.sqrt(d)
         if pallas.is_available():
             from ...ops.pallas.flash_attention import supports_cached
+            from ...ops.pallas.flash_decode import supports_decode
 
+            if supports_decode(sq, sk, h, d, key.dtype.itemsize):
+                return _sdpa_flash_decode(query, key, value, lm.q_pos,
+                                          lm.kv_len, scale=s)
             if supports_cached(sq, sk, d):
                 return _sdpa_flash_cached(query, key, value, lm.q_pos,
                                           lm.kv_len, scale=s)
@@ -352,6 +369,16 @@ def _sdpa_flash_cached(q, k, v, q_pos, kv_len=None, scale=None):
     from ...ops.pallas.flash_attention import flash_attention_cached
 
     return flash_attention_cached(q, k, v, q_pos, kv_len, scale=scale)
+
+
+@op("flash_sdpa_decode")
+def _sdpa_flash_decode(q, k, v, q_pos, kv_len=None, scale=None):
+    """Pallas decode-shaped kernel: a few query rows per cache row (decode,
+    speculative verify), reading the cache once, in its own dtype and
+    layout, up to the live length — inference path."""
+    from ...ops.pallas.flash_decode import flash_attention_decode
+
+    return flash_attention_decode(q, k, v, q_pos, kv_len, scale=scale)
 
 
 @op("sdpa")

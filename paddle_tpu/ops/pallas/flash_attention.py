@@ -662,8 +662,11 @@ _flash_cached.defvjp(_flash_cached_vjp_fwd, _flash_cached_vjp_bwd)
 def supports_cached(seq_q, seq_k, head_dim=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
     """Shape gate for the length-masked kernel: both sequence dims must tile
-    into 128-aligned blocks (decode's seq_q=1 and sub-lane prefill chunks
-    route to the blockwise XLA scan instead)."""
+    into 128-aligned blocks. Decode's seq_q=1 and verify's few rows have a
+    kernel of their own (``flash_decode.supports_decode``, asked first);
+    what neither takes (a sub-lane prefill chunk) is left to the blockwise
+    XLA scan, which is slow on the TPU and meant for backends without
+    Pallas."""
     return _pick_block(seq_q, block_q) > 0 and _pick_block(seq_k, block_k) > 0
 
 

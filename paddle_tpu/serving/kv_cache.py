@@ -21,9 +21,10 @@ Masking carries the variable part: attention always runs over the full
 tail. The engine's step bodies express that as a
 ``functional.LengthMask`` (ISSUE 15) — a description of the valid
 region, not a materialized ``[b, 1, q, max_len]`` tensor — so at long
-``max_len`` sdpa routes to the blockwise online-softmax KV scan (or the
-Pallas flash cached kernel on TPU) and the O(q·max_len) score matrix is
-never built; short caches fall back to the same additive mask as before.
+``max_len`` sdpa routes to the blockwise online-softmax KV scan (on the
+TPU: the Pallas decode kernel for decode and verify, the flash cached
+kernel for prefill blocks) and the O(q·max_len) score matrix is never
+built; short caches fall back to the same additive mask as before.
 Correctness invariant either way: position ``j`` of a slot's buffer holds
 garbage only while ``j >= length`` — and the mask admits exactly
 ``j <= position-of-the-query`` — so garbage is never attended to and is

@@ -251,3 +251,121 @@ def test_decode_still_compiles_once_with_blockwise_forced():
     assert len(out) == 65
     assert counts.get("serve_decode") == 1, counts
     assert counts.get("serve_prefill") == 1, counts
+
+
+# ---------------------------------------------------------------------------
+# the decode-shaped Pallas route (ISSUE 27): chosen from platform and shape
+# ---------------------------------------------------------------------------
+def _traced_ops(fn):
+    """Framework ops by registry name, counted while ``fn`` runs (the
+    counter ``chip_smoke.py`` checks the same routes with on the chip)."""
+    from chip_smoke import ops_traced
+
+    with ops_traced() as seen:
+        fn()
+    return seen
+
+
+@pytest.mark.parametrize("interpret,sq,sk,expected", [
+    (False, 1, 128, "blockwise_sdpa"),     # XLA:CPU, tier-1: the scan stays
+    (False, 128, 128, "blockwise_sdpa"),
+    (True, 1, 128, "flash_sdpa_decode"),   # decode
+    (True, 5, 128, "flash_sdpa_decode"),   # speculative verify, spec_k = 4
+    (True, 128, 256, "flash_sdpa_cached"),  # a prefill chunk
+    (True, 12, 128, "blockwise_sdpa"),     # neither kernel takes 12 rows
+    (True, 1, 96, "blockwise_sdpa"),       # no 128-aligned KV block
+])
+def test_length_masked_routing_by_platform_and_shape(interpret, sq, sk,
+                                                     expected):
+    import contextlib
+
+    from paddle_tpu.ops import pallas
+
+    set_flags({"blockwise_attention_min_kv": 1})
+    q, k, v = _qkv(2, sq, sk, h=2, d=64)
+    lm = LengthMask(np.tile(sk - sq + np.arange(sq, dtype=np.int32), (2, 1)))
+    with pallas.interpret_mode() if interpret else contextlib.nullcontext():
+        seen = _traced_ops(lambda: _sdpa_lm(q, k, v, lm))
+    routes = {n for n in seen if n.endswith("sdpa") or "sdpa_" in n}
+    assert routes == {expected}, dict(seen)
+
+
+def test_short_caches_keep_the_dense_route_under_interpret():
+    """Below ``blockwise_attention_min_kv`` nothing changes route: the same
+    ``_blockwise_ok`` gate stands before both kernels."""
+    from paddle_tpu.ops import pallas
+
+    q, k, v = _qkv(2, 1, 128, h=2, d=64)
+    lm = LengthMask(np.full((2, 1), 100, np.int32))
+    with pallas.interpret_mode():
+        seen = _traced_ops(lambda: _sdpa_lm(q, k, v, lm))
+    assert seen["sdpa"] == 1 and not seen["flash_sdpa_decode"], dict(seen)
+
+
+def _kernel_model(seed=0):
+    """Two heads of 64 over a 128-slot cache: shapes both kernels tile."""
+    with unique_name.guard():
+        paddle.seed(seed)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
+            max_position_embeddings=128, hidden_dropout=0.0,
+            attention_dropout=0.0, initializer_range=0.6))
+    model.eval()
+    return model
+
+
+@pytest.mark.parametrize("engine_kw,prompt_len", [
+    ({}, 7), ({"spec_k": 4}, 9)], ids=["decode", "verify"])
+def test_greedy_serving_byte_identical_scan_and_decode_kernel(
+        _no_persistent_compile_cache, engine_kw, prompt_len):
+    """The same engine through the blockwise scan (XLA:CPU) and through the
+    decode kernel (interpret mode): the same greedy tokens, and each route's
+    op is the one its decode (or verify) step traced."""
+    from paddle_tpu.ops import pallas
+
+    set_flags({"blockwise_attention_min_kv": 1})
+    model = _kernel_model()
+    rng = np.random.RandomState(11)
+    # a periodic prompt: the n-gram proposer drafts from the first tick
+    prompt = np.tile(rng.randint(0, 512, 3), 4)[:prompt_len].tolist()
+
+    def gen():
+        eng = GenerationEngine(model, max_batch=2, max_len=128,
+                               prefill_buckets=(16,), **engine_kw)
+        return eng.generate(prompt, max_new_tokens=24)
+
+    out = {}
+    seen_scan = _traced_ops(lambda: out.update(scan=gen()))
+    with pallas.interpret_mode():
+        seen_kernel = _traced_ops(lambda: out.update(kernel=gen()))
+    assert len(set(out["scan"])) > 2, "degenerate model; parity is vacuous"
+    assert out["kernel"] == out["scan"]
+    # the one 16-wide prefill bucket (2 layers) is the scan's either way;
+    # every other length-masked call moved to the kernel
+    assert not seen_scan["flash_sdpa_decode"], dict(seen_scan)
+    assert seen_kernel["blockwise_sdpa"] == 2, dict(seen_kernel)
+    assert (seen_kernel["flash_sdpa_decode"]
+            == seen_scan["blockwise_sdpa"] - 2 > 0), dict(seen_kernel)
+
+
+def test_decode_still_compiles_once_through_the_decode_kernel():
+    from paddle_tpu.ops import pallas
+
+    set_flags({"blockwise_attention_min_kv": 1})
+    model = _kernel_model()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        with pallas.interpret_mode():
+            eng = GenerationEngine(model, max_batch=2, max_len=128,
+                                   prefill_buckets=(8, 16))
+            out = eng.generate([5, 6, 7], max_new_tokens=40)
+        counts = telemetry.get_telemetry().compile_counts()
+        recompiles = telemetry.get_telemetry().recompile_count
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert len(out) == 40
+    assert counts.get("serve_decode") == 1, counts
+    assert counts.get("serve_prefill") == 1, counts
+    assert recompiles == 0

@@ -360,6 +360,79 @@ def test_flash_cached_parity_per_batch_kv_len(batch):
                                atol=2e-5, rtol=2e-5)
 
 
+def _decode_case(batch, pos, sq=1, sk=512, dtype=jnp.float32):
+    """Decode-shaped operands: row 0 queries ``pos``; the other rows hold
+    lengths of their own, row 1 an idle slot (position 0: only the key just
+    written) and row 2 no valid key at all."""
+    q, _, _ = _rand_qkv(b=batch, s=sq, seed=5)
+    _, k, v = _rand_qkv(b=batch, s=sk, seed=6)
+    pos0 = np.asarray([pos, 0, -sq, 300][:batch], np.int32)
+    q_pos = pos0[:, None] + np.arange(sq, dtype=np.int32)[None, :]
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), q_pos
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("pos", [0, 1, 127, 128, 511])
+@pytest.mark.parametrize("batch", [1, 4])
+def test_flash_decode_parity(batch, pos, dtype):
+    """One query row per cache row, each row masked by its OWN position
+    (``pos`` sits before, on and after a block boundary and on the last
+    slot); blocks past the live length are neither fetched nor computed.
+    Same values as the dense reference and as the blockwise scan; a row
+    with no valid key gives zeros, not NaN."""
+    from paddle_tpu.nn.functional.attention import _sdpa_blockwise
+    from paddle_tpu.ops.pallas.flash_decode import flash_attention_decode
+
+    q, k, v, q_pos = _decode_case(batch, pos, dtype=jnp.dtype(dtype))
+    with pallas.interpret_mode():
+        out = flash_attention_decode(q, k, v, q_pos, block_k=128)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    out = np.asarray(out, np.float32)
+    f32 = lambda x: x.astype(jnp.float32)
+    ref = np.array(_ref_cached(f32(q), f32(k), f32(v), q_pos, None))
+    scan = np.asarray(_sdpa_blockwise.raw(q, k, v, q_pos, None, block_q=1,
+                                          block_k=128), np.float32)
+    if batch > 2:
+        ref[2] = 0.0  # the dense softmax is uniform over a fully masked row
+        np.testing.assert_array_equal(out[2], np.zeros_like(out[2]))
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
+    np.testing.assert_allclose(out, scan, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("sq,with_kv_len", [(5, False), (8, False),
+                                            (1, True), (5, True)])
+def test_flash_decode_verify_window_and_kv_len(sq, with_kv_len):
+    """Speculative verify's window (``spec_k + 1`` rows, each with its own
+    position) takes the same kernel; an explicit ``kv_len`` bounds every row
+    of its batch entry (0: nothing valid, zeros)."""
+    from paddle_tpu.ops.pallas.flash_decode import flash_attention_decode
+
+    q, k, v, q_pos = _decode_case(4, 250, sq=sq)
+    q_pos = np.maximum(q_pos, 0)
+    kv_len = np.asarray([200, 512, 0, 301], np.int32) if with_kv_len else None
+    with pallas.interpret_mode():
+        out = np.asarray(flash_attention_decode(q, k, v, q_pos, kv_len))
+    ref = np.array(_ref_cached(q, k, v, q_pos, kv_len))
+    if with_kv_len:
+        ref[2] = 0.0
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+def test_flash_decode_refuses_what_it_cannot_tile():
+    from paddle_tpu.ops.pallas.flash_decode import (flash_attention_decode,
+                                                    supports_decode)
+
+    assert supports_decode(1, 1024, 20, 64) and supports_decode(8, 128, 2, 64)
+    assert not supports_decode(9, 1024, 20, 64)      # a prefill chunk
+    assert not supports_decode(1, 1000, 20, 64)      # no 128-aligned block
+    assert not supports_decode(1, 1024, 512, 128)    # tiles over VMEM
+    q, k, v, q_pos = _decode_case(1, 3, sq=9, sk=128)
+    with pytest.raises(ValueError, match="seq_q"):
+        flash_attention_decode(q, k, v, q_pos, interpret=True)
+
+
 def _dp_mesh(n=4):
     from paddle_tpu.distributed.mesh import build_mesh
 
@@ -415,22 +488,29 @@ def test_kernels_partition_over_the_step_mesh():
     assert grads[0].sharding.spec == P("dp")
 
 
-def test_kernel_on_concrete_sharded_operands_partitions_itself():
+@pytest.mark.parametrize("sq", [128, 1], ids=["cached", "decode"])
+def test_kernel_on_concrete_sharded_operands_partitions_itself(sq):
     """Outside any compiled step the mesh is read off the operands."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from paddle_tpu.ops.pallas import flash_attention_cached
+    from paddle_tpu.ops.pallas.flash_decode import flash_attention_decode
 
     mesh = _dp_mesh()
     rows = NamedSharding(mesh, P("dp"))
-    q, _, _ = _rand_qkv(b=4, s=128, seed=3)
+    q, _, _ = _rand_qkv(b=4, s=sq, seed=3)
     _, k, v = _rand_qkv(b=4, s=256, seed=4)
-    q_pos = np.tile(128 + np.arange(128, dtype=np.int32), (4, 1))
+    q_pos = np.tile(128 + np.arange(sq, dtype=np.int32), (4, 1))
     kv_len = np.asarray([256, 140, 200, 129], np.int32)
     put = lambda a: jax.device_put(a, rows)
     with pallas.interpret_mode():
-        out = flash_attention_cached(put(q), put(k), put(v), put(q_pos),
-                                     put(kv_len), block_q=128, block_k=128)
+        if sq == 1:
+            out = flash_attention_decode(put(q), put(k), put(v), put(q_pos),
+                                         put(kv_len), block_k=128)
+        else:
+            out = flash_attention_cached(put(q), put(k), put(v), put(q_pos),
+                                         put(kv_len), block_q=128,
+                                         block_k=128)
     assert out.sharding.spec == P("dp")
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(_ref_cached(q, k, v, q_pos, kv_len)),
@@ -480,6 +560,13 @@ def _site_flash_cached():
         block_k=128)), (q, k, v)
 
 
+def _site_flash_decode():
+    from paddle_tpu.ops.pallas.flash_decode import flash_attention_decode
+
+    q, k, v, q_pos = _decode_case(1, 200)
+    return (lambda q, k, v: flash_attention_decode(q, k, v, q_pos)), (q, k, v)
+
+
 def _site_flash_packed():
     from paddle_tpu.ops.pallas.flash_attention_packed import (
         flash_attention_packed)
@@ -514,13 +601,15 @@ def _site_fused_ce():
 @pytest.mark.parametrize("site,expected", [
     (_site_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     (_site_flash_cached, {"flash_cached_fwd"}),
+    (_site_flash_decode, {"flash_decode_fwd"}),
     (_site_flash_packed, {"flash_packed_fwd", "flash_packed_bwd"}),
     (_site_layer_norm, {"layer_norm_fwd", "layer_norm_bwd"}),
     (_site_fused_ce, {"cross_entropy_fwd", "cross_entropy_bwd_dx",
                       "cross_entropy_bwd_dw"}),
-], ids=["flash", "flash_cached", "flash_packed", "layer_norm", "fused_ce"])
+], ids=["flash", "flash_cached", "flash_decode", "flash_packed",
+        "layer_norm", "fused_ce"])
 def test_every_pallas_call_site_carries_its_name(site, expected):
-    """Each of the eleven ``pl.pallas_call`` sites names its kernel: the
+    """Each of the twelve ``pl.pallas_call`` sites names its kernel: the
     name is the custom call's in the device trace (``%jvp_flash_packed_fwd_``
     on the v5e), which is what a reader's pattern holds on to."""
     fn, args = site()
@@ -539,4 +628,4 @@ def test_no_pallas_call_site_is_left_unnamed():
             calls += 1
             named += bool(re.match(r"\s*[\w.()=, ]+,\s*name=\"\w+\"",
                                    text[m.end():m.end() + 200]))
-    assert calls == 11 and named == calls
+    assert calls == 12 and named == calls

@@ -50,6 +50,22 @@ def test_cached_kernel_compiles_at_batch_1_and_8(preflight):
     assert "ok   flash cached b8 sq128 sk1024" in out, out
 
 
+def test_decode_kernel_compiles_and_copies_no_cache_buffer(preflight):
+    # decode (one query row a slot) and verify (spec_k + 1 rows) pass Mosaic,
+    # and the kernel's view of K and V is the layout XLA:TPU already keeps
+    # them in: no temporary as large as a cache buffer (a re-layout would
+    # be a full copy of every layer's K and V in every decode step)
+    import re
+
+    out = preflight.stdout
+    assert "ok   flash decode b8 sq1 sk1024" in out, out
+    assert "ok   flash decode b8 sq5 sk1024" in out, out
+    assert "ok   dp4: flash decode b8" in out, out
+    temps = [int(t) for t in re.findall(r"temporaries (\d+) bytes", out)]
+    assert len(temps) == 2, out
+    assert max(temps) < 8 * 1024 * 12 * 64 * 2, temps  # one bf16 cache buffer
+
+
 def test_sharded_step_compiles_and_kernels_see_the_local_batch(preflight):
     # "Mosaic kernels cannot be automatically partitioned": LN + flash
     # inside one jit over the 4-device mesh must partition themselves, and
@@ -61,5 +77,6 @@ def test_sharded_step_compiles_and_kernels_see_the_local_batch(preflight):
                 if "per-device Mosaic operands" in l]
     assert any("bf16[2,1024,768]" in l for l in operands), out
     assert any("bf16[2048,768]" in l for l in operands), out
+    assert any("bf16[2,12,64,1024]" in l for l in operands), out
     assert not any("bf16[8," in l or "bf16[8192," in l
                    for l in operands), out

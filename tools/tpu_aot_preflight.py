@@ -119,6 +119,17 @@ def kernel_programs(devs):
                        q, kv, kv, _sds((cb, sq), jnp.int32, one),
                        _sds((cb,), jnp.int32, one)))
 
+    # serving reaches the decode kernel at the engine's batch: one query row
+    # a slot (decode) or spec_k + 1 (verify) over each slot's whole cache row
+    from paddle_tpu.ops.pallas.flash_decode import flash_attention_decode
+
+    for sq in (1, 5):
+        q = _sds((8, sq, h, d), bf, one)
+        kv = _sds((8, s, h, d), bf, one)
+        yield (f"flash decode b8 sq{sq} sk{s}",
+               lambda q=q, kv=kv, sq=sq: jax.jit(flash_attention_decode).lower(
+                   q, kv, kv, _sds((8, sq), jnp.int32, one)))
+
     # LN + flash inside one jit sharded over the 4-device mesh
     mesh = Mesh(np.array(devs), ("dp",))
     row = NamedSharding(mesh, P("dp"))
@@ -143,6 +154,13 @@ def kernel_programs(devs):
     yield "dp4: flash cached b8", lambda: jax.jit(cached).lower(
         qs, kvs, kvs, _sds((gb, 128), jnp.int32, row),
         _sds((gb,), jnp.int32, row))
+
+    def decode(q, k, v, qp):
+        with partition_scope((mesh, ("dp",))):
+            return flash_attention_decode(q, k, v, qp)
+
+    yield "dp4: flash decode b8", lambda: jax.jit(decode).lower(
+        _sds((gb, 1, h, d), bf, row), kvs, kvs, _sds((gb, 1), jnp.int32, row))
 
 
 # ---------------------------------------------------------------------------
@@ -243,6 +261,13 @@ def main(argv=None):
     rep = Report()
     for name, build in kernel_programs(devs):
         compiled = rep.run(name, build)
+        if compiled is not None and name.startswith("flash decode"):
+            # the kernel takes the cache in the layout the TPU keeps it in:
+            # a program that had to re-lay a K or V buffer out would hold a
+            # copy of it among its temporaries
+            print(f"       temporaries "
+                  f"{compiled.memory_analysis().temp_size_in_bytes} bytes",
+                  flush=True)
         if compiled is not None and name.startswith("dp4"):
             for shapes in sorted(set(
                     mosaic_operand_shapes(compiled.as_text()))):
