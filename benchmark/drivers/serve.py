@@ -18,6 +18,7 @@ Cell parameters: ``engine`` (max_batch, max_len), ``traffic`` (see
 """
 from __future__ import annotations
 
+import collections
 import faulthandler
 import gc
 import time
@@ -27,6 +28,10 @@ import numpy as np
 from harness import heap, loadgen, trace_reduce
 
 DRAIN_LIMIT_S = 60.0
+
+#: one ``Scheduler.step()`` as the loop saw it: the seconds it took, when it
+#: began, the requests it admitted, the gaps between tokens it made
+Tick = collections.namedtuple("Tick", "took_s began_s admitted gaps")
 
 
 def build(ctx, hooks):
@@ -76,7 +81,7 @@ class Window:
         self.ttft_s = {}    # rid -> first token - due
         self.gaps_s = []
         self.done_t = {}    # rid -> time it finished
-        self.tick_log = []  # (seconds it took, when it began)
+        self.tick_log = []  # a Tick for each
         self.work = dict.fromkeys(
             ("ticks", "decode_steps", "decode_positions",
              "decode_live_tokens", "n_positions", "n_keys", "n_outputs"), 0)
@@ -87,8 +92,11 @@ class Window:
         while len(self.reqs) < len(self.arrivals) \
                 and self.arrivals[len(self.reqs)].due_s <= now:
             a = self.arrivals[len(self.reqs)]
-            r = self.sched.submit(Request(prompt=a.prompt,
-                                          max_new_tokens=a.max_new))
+            # the program's own serve.queue_wait counts from the same
+            # instant as the benchmark's TTFT: when the request was due
+            r = self.sched.submit(Request(
+                prompt=a.prompt, max_new_tokens=a.max_new,
+                due_ns=int((t0 + a.due_s) * 1e9)))
             self.due_s[r.rid] = a.due_s
             self.reqs.append(r)
             self.sent_s.append(time.perf_counter() - t0)
@@ -113,7 +121,7 @@ class Window:
         if self.stall_dump_s:
             faulthandler.cancel_dump_traceback_later()
         t = time.perf_counter() - t0
-        self.tick_log.append((t - began, began))
+        admitted, gaps_before = 0, len(self.gaps_s)
         w["ticks"] += 1
         w["decode_steps"] += s.decode_steps - steps_before
         for r in list(s.active.values()) + done:
@@ -122,6 +130,7 @@ class Window:
                 continue
             p = len(r.prompt)
             if k == 0:  # prefilled in this tick: p positions, one output
+                admitted += 1
                 self.ttft_s[r.rid] = t - self.due_s[r.rid]
                 w["n_positions"] += p
                 w["n_keys"] += p * (p + 1) // 2
@@ -140,7 +149,62 @@ class Window:
             self.last_t[r.rid] = t
         for r in done:
             self.done_t[r.rid] = t
+        self.tick_log.append(Tick(t - began, began, admitted,
+                                  len(self.gaps_s) - gaps_before))
         return t
+
+    def in_system_at_quarters(self):
+        """Requests in the system at each quarter of the window and at its
+        close: a cell above capacity holds a full engine at every one."""
+        return [self.in_system(self.total_s * q) for q in (.25, .5, .75, 1)]
+
+    def live_slots(self):
+        """Slots that decoded, mean over the window's decode steps."""
+        return self.at_close["decode_positions"] \
+            / max(1, self.at_close["decode_steps"])
+
+    def slowest_ticks(self, top):
+        """Indices of the ``top`` slowest ticks, the slowest first."""
+        return sorted(range(len(self.tick_log)),
+                      key=lambda i: -self.tick_log[i].took_s)[:top]
+
+    def slowest_ticks_s_at(self, top):
+        return [(round(self.tick_log[i].took_s, 3),
+                 round(self.tick_log[i].began_s, 2))
+                for i in self.slowest_ticks(top)]
+
+    def tick_kinds(self):
+        """The window's ticks by how many requests each admitted (0, 1,
+        2+): its share of the ticks and of the gaps between tokens (a tick
+        makes one gap for each request that was live before it), and its
+        time. ``itl_p95_ms`` is a percentile over those gaps, so it sits
+        where the cumulative share of gaps, by tick time, passes 95%."""
+        ticks = [t for t in self.tick_log if t.began_s < self.closed_s]
+        n_gaps = max(1, sum(t.gaps for t in ticks))
+        out = {}
+        for most, kind in enumerate(("0", "1", "2+")):
+            mine = [t for t in ticks if min(t.admitted, 2) == most]
+            if not mine:
+                continue
+            ms = np.array([t.took_s for t in mine]) * 1e3
+            out[kind] = {
+                "share_of_ticks": 100.0 * len(mine) / len(ticks),
+                "share_of_gaps": 100.0 * sum(t.gaps for t in mine) / n_gaps,
+                "ms_p50": float(np.median(ms)), "ms_mean": float(ms.mean()),
+                "ms_p95": float(np.quantile(ms, 0.95))}
+        return out
+
+
+def _slowest_ticks_phases(tm, win, top=3):
+    """The program's own record of the window's slowest ticks (those the
+    ring still holds: the last 1,024): which phase the time went to. For
+    whoever has to explain a wide run; no metric reads it."""
+    mine = {r.index: r for r in tm.steps(kind="serve.tick",
+                                         owner=win.sched.sched_id)}
+    return [{"tick": i, "at_s": round(win.tick_log[i].began_s, 2),
+             "ms": {k: round(v * 1e3, 2) for k, v in mine[i].phases.items()
+                    if v >= 5e-4}}
+            for i in win.slowest_ticks(top) if i in mine]
 
 
 def _p95(xs):
@@ -186,6 +250,7 @@ def measure(ctx, eng, arrivals, length, lead=0.0, drain=False,
     # neither the tokens nor the gaps
     win.at_close = dict(win.work, requests=len(win.reqs),
                         gaps=len(win.gaps_s))
+    win.queued_at_window_close = len(win.sched.queue)
     if tracing is not None:
         tracing.__exit__(None, None, None)
         win.traced_work = {k: v - at_trace[k] for k, v in win.work.items()}
@@ -288,13 +353,18 @@ def run(ctx, hooks=None):
              "backlog_at_close": len(win.reqs) - len(
                  [r for r in win.reqs if r.rid in win.done_t]),
              "queue_at_close": len(win.sched.queue),
+             "queued_at_window_close": win.queued_at_window_close,
+             "in_system_quarter_half_3quarter_close":
+             win.in_system_at_quarters(),
+             "tick_kinds": win.tick_kinds(),
+             "live_slots_per_decode_step": win.live_slots(),
              "itl_samples": len(gaps), "ticks": at_close["ticks"],
              "generator_late_ms_mean": late_mean,
              "generator_late_ms_max": late_max, "work": at_close,
              "traced_work": getattr(win, "traced_work", None),
              "ttft_p50_ms": float(np.median(ttft)) * 1e3 if ttft else None,
-             "slowest_ticks_s_at": [(round(d, 3), round(b, 2)) for d, b in
-                                    sorted(win.tick_log, reverse=True)[:4]],
+             "slowest_ticks_s_at": win.slowest_ticks_s_at(4),
+             "slowest_ticks_phases": _slowest_ticks_phases(tm, win),
              **pauses.facts()}
     ctx.log(f"window: {facts}")
     sample = [(list(r.prompt), list(r.tokens))

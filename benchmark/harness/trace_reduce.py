@@ -4,13 +4,14 @@
 is also the form of the recorded fixture under ``tests/data``):
 
     {"window": [start_ns, end_ns],
-     "spans": [[name, start_ns, dur_ns], ...],        # the benchmark's own
+     "spans": [[name, start_ns, dur_ns], ...],  # host: bench:*, paddle_tpu:*
      "devices": {plane: {"ops": [[name, start_ns, dur_ns], ...],
                          "modules": [[name, start_ns, dur_ns], ...]}}}
 
 The window is the benchmark's ``bench:window`` annotation, which the
 profiler records on the same clock as the device's operations; the other
-``bench:*`` annotations are the host spans that idle gaps are attributed to.
+``bench:*`` annotations and the program's own ``paddle_tpu:*`` phases are
+the host spans that idle gaps are attributed to (no metric reads the latter).
 Busy time is the *union* of one line's op intervals clipped to the window:
 a sum over lines, or over a loop and the ops nested in it, would read over
 the window. A trace with no device operation in the window is an error.
@@ -25,7 +26,8 @@ import os
 import re
 
 WINDOW = "bench:window"
-SPAN_PREFIX = "bench:"
+#: host annotations kept: the benchmark's own, and the program's phases
+SPAN_PREFIXES = ("bench:", "paddle_tpu:")
 #: a device plane's lines: the one with every HLO op, the one with programs
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 #: on the chip an op's name is its whole HLO text; this much of it is kept
@@ -69,7 +71,7 @@ def load(path, platform):
                             for e in line.events]
             elif plane.name == "/host:CPU":
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
+                    if e.name.startswith(SPAN_PREFIXES):
                         spans.append([e.name, e.start_ns, e.duration_ns])
                     elif platform == "cpu":
                         st = dict(e.stats)
@@ -186,8 +188,9 @@ def span_s(trace, span_name):
 def breakdown(trace, top=10):
     """``device_ops``: self time by op name on the first device (a loop's
     time goes to the ops nested in it). ``idle_gaps``: the idle time of
-    that device by what the benchmark's host span says the host was doing
-    (the span covering most of each gap; ``unattributed`` if none)."""
+    that device by what the host was doing: each gap goes to the innermost
+    (shortest) host span that covers over half of it, failing that to the
+    span covering most of it, and to ``unattributed`` if none touches it."""
     lo, hi = trace["window"]
     dev = next(iter(trace["devices"].values()))
     ev = sorted(_clip(dev["ops"], lo, hi), key=lambda e: (e[0], -e[1]))
@@ -200,23 +203,35 @@ def breakdown(trace, top=10):
             self_t[stack[-1][1]] -= b - a
         self_t[name] += b - a
         stack.append((b, name))
-    spans = _clip(trace["spans"], lo, hi)
+    spans = sorted(_clip(trace["spans"], lo, hi))
     gaps = collections.Counter()
-    edge = lo
+    edge, nxt, near = lo, 0, []  # near: the spans that can touch this gap
     for a, b in _union(ev) + [[hi, hi]]:
         if a > edge:
-            best, cover = "unattributed", 0.0
-            for x, y, name in spans:
-                c = min(a, y) - max(edge, x)
-                if c > cover:
-                    best, cover = name[len(SPAN_PREFIX):], c
-            gaps[best] += a - edge
+            while nxt < len(spans) and spans[nxt][0] < a:
+                near.append(spans[nxt])
+                nxt += 1
+            near = [s for s in near if s[1] > edge]
+            gaps[_host_span_of(near, edge, a)] += a - edge
         edge = max(edge, b)
     by_kind = collections.Counter()
     for name, ns in self_t.items():
         by_kind[short_name(name)] += ns
     fmt = lambda c: [[k, v / 1e9] for k, v in c.most_common(top) if v > 0]
     return {"device_ops": fmt(by_kind), "idle_gaps": fmt(gaps)}
+
+
+def _host_span_of(spans, lo, hi):
+    """The name, less its prefix, of the host span a gap ``[lo, hi)`` goes
+    to (see ``breakdown``)."""
+    best, cover, inner, width = "unattributed", 0.0, None, None
+    for x, y, name in spans:
+        c = min(hi, y) - max(lo, x)
+        if c > cover:
+            best, cover = name, c
+        if 2 * c > hi - lo and (width is None or y - x < width):
+            inner, width = name, y - x
+    return (inner or best).partition(":")[2] or best
 
 
 def short_name(hlo):

@@ -53,6 +53,35 @@ def test_idle_gaps_go_to_the_host_span_that_covers_them():
     assert ops.get("while", 0.0) == pytest.approx(0.005)  # its own 25..30
 
 
+def test_idle_gap_goes_to_the_innermost_span_covering_most_of_it():
+    """The program's ``paddle_tpu:*`` phases nest inside the benchmark's
+    tick: a gap is named by the phase, not by the tick around it."""
+    ms = 1e6
+    t = {"window": [0.0, 100 * ms],
+         "spans": [["bench:sched_step", 0.0, 100 * ms],
+                   ["paddle_tpu:serve.tick", 1 * ms, 98 * ms],
+                   ["paddle_tpu:serve.admit", 2 * ms, 28 * ms],
+                   ["paddle_tpu:serve.prefill_dispatch", 2 * ms, 7 * ms],
+                   ["paddle_tpu:serve.decode_dispatch", 30 * ms, 10 * ms],
+                   ["paddle_tpu:serve.decode_readback", 40 * ms, 58 * ms]],
+         "devices": {"/device:TPU:0": {"modules": [], "ops": [
+             ["%fusion.1 = f32[8]{0} fusion()", 10 * ms, 18 * ms],
+             ["%fusion.2 = f32[8]{0} fusion()", 36 * ms, 60 * ms]]}}}
+    gaps = dict(tr.breakdown(t)["idle_gaps"])
+    # 0..10: prefill_dispatch covers 7 of 10 and is the shortest that does;
+    # 28..36: admit covers 2, decode_dispatch 6; 96..100: the read-back 2,
+    # under half, so the shortest span over half of it: the tick
+    assert gaps == {"serve.prefill_dispatch": pytest.approx(0.010),
+                    "serve.decode_dispatch": pytest.approx(0.008),
+                    "serve.tick": pytest.approx(0.004)}
+    t["spans"] = t["spans"][:1]      # the benchmark's span alone: as before
+    assert dict(tr.breakdown(t)["idle_gaps"]) == {
+        "sched_step": pytest.approx(0.022)}
+    t["spans"] = []
+    assert dict(tr.breakdown(t)["idle_gaps"]) == {
+        "unattributed": pytest.approx(0.022)}
+
+
 def test_cache_live_share_and_nothing_to_read():
     from readers import cache
 
@@ -121,6 +150,50 @@ def test_recorded_serving_ticks():
                                                               rel=1e-6)
 
 
+def test_recorded_serving_phases():
+    """A few ticks of the steady serving cell recorded on a v5e (PR 29),
+    one of them admitting a request, with the program's own
+    ``paddle_tpu:*`` phases beside the benchmark's spans: the idle gaps are
+    named by phase, and every metric read from the trace reads what it
+    reads with those spans taken out again."""
+    import types
+
+    import run as bench
+
+    t = fixture("serve_phases_v5e.json.gz")
+    names = {s[0] for s in t["spans"]}
+    assert {"bench:sched_step", "paddle_tpu:serve.tick",
+            "paddle_tpu:serve.admit", "paddle_tpu:serve.decode_dispatch",
+            "paddle_tpu:serve.decode_readback"} <= names
+    busy, window = tr.busy_s(t), tr.window_s(t)
+    b = tr.breakdown(t)
+    gaps = dict(b["idle_gaps"])
+    assert any(k.startswith("serve.") for k in gaps)
+    assert gaps.get("sched_step", 0.0) < 0.1 * (window - busy)
+    assert sum(gaps.values()) == pytest.approx(window - busy, rel=1e-6)
+    bare = dict(t, spans=[s for s in t["spans"] if s[0].startswith("bench:")])
+    was = tr.breakdown(bare)
+    assert was["device_ops"] == b["device_ops"]
+    assert dict(was["idle_gaps"]).keys() <= {"sched_step", "submit",
+                                              "unattributed"}
+    cell = load_json("workloads", "gpt2_large.serve_chat_steady")
+    ctx = types.SimpleNamespace(
+        sizes=sizes_of(load_json("configs", cell["config"]), False), chips=1)
+    steps = max(n for n, _ in tr.programs(t, "serve_decode").values())
+    facts = {"cache_slots": 32 * 1024, "traced_work": {
+        "decode_steps": steps, "decode_positions": 23 * steps,
+        "decode_live_tokens": 5000 * steps, "n_positions": 400,
+        "n_keys": 5000 * steps, "n_outputs": 23 * steps}}
+    read = lambda trace: {
+        m: bench.read_metric(m, {"trace": trace, "facts": facts, "values": {
+            "ttft_mean_ms": 59.0}, "device_kind": "TPU v5 lite"}, ctx)[0]
+        for m in cell["per_layer"]}
+    got = read(t)
+    assert got == read(bare) and None not in got.values()
+    assert 0 < got["decode_step_roofline.steady"] < 100
+    assert 0 < got["host_ms_per_tick.steady"] < 20
+
+
 # -- the last line -----------------------------------------------------------
 def good_line(**over):
     kw = dict(correct=True, attempted=10, failed=0,
@@ -160,6 +233,33 @@ def test_lastline_accepts_a_good_line():
 def test_lastline_rejects(over):
     with pytest.raises(lastline.MalformedLine):
         lastline.build(**good_line(**over))
+
+
+CELLS = sorted(f[:-len(".json")] for f in os.listdir(os.path.join(
+    os.path.dirname(DATA), os.pardir, "workloads")))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_lastline_holds_an_untraced_line_to_every_end_to_end_metric(cell):
+    """Whatever a cell names under ``end_to_end`` (``ttft_p95_ms`` in the
+    steady cell, if it is there) has to be in its ``--trace 0`` line, with
+    the unit and the bound its own file gives."""
+    wanted = load_json("workloads", cell)["end_to_end"]
+    specs = {m: load_json("metrics", m) for m in wanted}
+    assert "setup_s" in wanted and len(wanted) >= 2
+    assert all(0 < s["bound"] <= 0.1 and s["source"] in
+               ("host_clock", "device_trace") for s in specs.values())
+    kw = good_line(values={m: 1.5 for m in wanted}, wanted=wanted,
+                   units={m: specs[m]["unit"] for m in wanted}, traced=False,
+                   device={"platform": "tpu", "kind": "TPU v5 lite",
+                           "count": 1, "memory_peak_bytes": 10 ** 10})
+    line = json.loads(lastline.build(**kw))
+    assert list(line["metrics"]) == wanted
+    for m in wanted:
+        short = dict(kw, values={k: v for k, v in kw["values"].items()
+                                 if k != m})
+        with pytest.raises(lastline.MalformedLine):
+            lastline.build(**short)
 
 
 # -- work counts, by hand ----------------------------------------------------
@@ -221,6 +321,109 @@ def test_loadgen_backlog_at_open():
     assert [x.due_s for x in a[:5]] == [0.0] * 5 and a[5].due_s > 0.0
     key = lambda xs: sorted((len(x.prompt), x.max_new) for x in xs)
     assert key(a) == key(b) and a != b
+
+
+SERVING = ["gpt2_large.serve_chat_sat", "gpt2_large.serve_chat_steady"]
+
+
+@pytest.mark.parametrize("seconds", [51.0, 12.0])  # a run; a traced run
+@pytest.mark.parametrize("cell", SERVING)
+def test_loadgen_every_seed_the_same_work_at_the_cells_rate(cell, seconds):
+    """What the spread of a serving cell rests on: at the rate in its file
+    every seed is sent the same count of requests and the same totals of
+    prompt and output tokens, over a whole run and over a traced one (8 s
+    of lead and 4 s under the profiler)."""
+    spec = load_json("workloads", cell)
+    assert spec["trace_lead_s"] + spec["trace_seconds"] == 12.0
+    tr_ = spec["traffic"]
+    want = None
+    for seed in (1, 77, 2147483659, 3000000019):
+        a = loadgen.schedule(tr_, seed, seconds, 50257)
+        got = (len(a), sum(len(x.prompt) for x in a),
+               sum(x.max_new for x in a))
+        want = want or got
+        assert got == want
+        assert all(0.0 <= x.due_s < seconds for x in a)
+        assert [x.due_s for x in a] == sorted(x.due_s for x in a)
+    assert want[0] == round(tr_["rate_per_s"] * seconds) + tr_.get(
+        "backlog_at_open", 0)
+
+
+def test_sat_opens_on_two_full_engines():
+    """``backlog_at_open`` 64 = 2 x ``max_batch``: no lull in the Poisson
+    schedule empties the queue before the arrivals overtake the engine."""
+    spec = load_json("workloads", "gpt2_large.serve_chat_sat")
+    assert spec["traffic"]["backlog_at_open"] == 64 \
+        == 2 * spec["engine"]["max_batch"] and spec["drain"] is False
+    a = loadgen.schedule(spec["traffic"], 2147483659, 51.0, 50257)
+    assert [x.due_s for x in a[:64]] == [0.0] * 64 and a[64].due_s > 0.0
+    steady = load_json("workloads", "gpt2_large.serve_chat_steady")
+    assert "backlog_at_open" not in steady["traffic"] and steady["drain"]
+    # the two cells differ in their rates and the backlog alone
+    same = lambda t: {k: v for k, v in t.items()
+                      if k not in ("rate_per_s", "backlog_at_open")}
+    assert same(spec["traffic"]) == same(steady["traffic"])
+    assert spec["traffic"]["rate_per_s"] > steady["traffic"]["rate_per_s"]
+
+
+class _StubScheduler:
+    """Admits whatever is queued, and gives every live request a token a
+    tick: enough of ``serving.Scheduler`` for ``drivers.serve.Window``."""
+
+    def __init__(self, eng):
+        self.queue, self.active, self.prefilling = [], {}, {}
+        self.decode_steps = 0
+
+    def submit(self, r):
+        self.queue.append(r)
+        return r
+
+    def step(self):
+        for r in self.active.values():
+            r.tokens.append(1)
+        self.decode_steps += 1
+        for r in self.queue:
+            r.tokens.append(1)
+            self.active[r.rid] = r
+        self.queue = []
+        done = [r for r in self.active.values()
+                if len(r.tokens) >= r.max_new_tokens]
+        for r in done:
+            del self.active[r.rid]
+        return done
+
+
+def test_window_hands_the_program_each_due_time_and_sorts_its_ticks(
+        monkeypatch):
+    import time
+
+    import paddle_tpu.serving as serving
+    from drivers import serve
+
+    monkeypatch.setattr(serving, "Scheduler", _StubScheduler)
+    arrivals = [loadgen.Arrival(0.0, [1, 2, 3], 4),
+                loadgen.Arrival(0.0, [1, 2], 3),
+                loadgen.Arrival(0.001, [5], 2)]
+    win = serve.Window(None, arrivals)
+    t0 = time.perf_counter()
+    win.submit_due(0.0005, t0)           # the two that are due
+    assert [r.due_ns for r in win.reqs] == [int(t0 * 1e9)] * 2
+    win.tick(t0)                         # admits 2, no gap yet
+    win.submit_due(0.5, t0)
+    assert win.reqs[2].due_ns == int((t0 + 0.001) * 1e9)
+    win.tick(t0)                         # admits 1; the two live: 2 gaps
+    win.tick(t0)                         # admits none; three live: 3 gaps
+    assert [(t.admitted, t.gaps) for t in win.tick_log] == [
+        (2, 0), (1, 2), (0, 3)]
+    win.closed_s, win.total_s = 1e9, 4.0
+    kinds = win.tick_kinds()
+    assert {k: round(v["share_of_ticks"], 3) for k, v in kinds.items()} == {
+        "0": 33.333, "1": 33.333, "2+": 33.333}
+    assert {k: v["share_of_gaps"] for k, v in kinds.items()} == {
+        "0": 60.0, "1": 40.0, "2+": 0.0}
+    assert all(v["ms_p50"] <= v["ms_p95"] for v in kinds.values())
+    # two finished in the last tick; the first still owes a token
+    assert win.in_system_at_quarters()[-1] == 1
 
 
 def test_train_batches_rows_all_differ():

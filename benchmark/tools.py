@@ -12,8 +12,12 @@ None of it gives a result, and the driver never calls it.
   over the cell's ``traffic`` (nested objects merged); one set-up, a window
   each, the system emptied in between. ``[{"rate_per_s": 1.0}, ...]`` is the
   sweep from which the knee is read; other keys show what a choice of the
-  traffic moves; ``seed`` and ``drain`` in an object stand for the run's. Windows open on an unsettled heap, and a tick over a second
-  writes every thread's Python stack to stderr (``harness/heap.py``).
+  traffic moves; ``seed`` and ``drain`` in an object stand for the run's.
+  ``knee_sweep.json`` is the sweep and the window far above capacity that
+  the serving cells' rates were set from (PERF.md section 6). Windows open
+  on an unsettled heap unless ``--settle`` is given (the state ``run.py``
+  measures in), and a tick over a second writes every thread's Python
+  stack to stderr (``harness/heap.py``).
 - ``--keep-trace FILE`` / ``--describe-trace FILE`` with ``--trace 1``: the
   reduced trace trimmed to a fixture (``tests/data``); a page of text about
   the raw profiler trace, for choosing a reader's pattern by hand.
@@ -109,18 +113,27 @@ def vary(args, variants):
                 np.random.default_rng([ctx.seed, 4]), vocab)
     rows = []
     for variant, drain, arrivals in plan:
+        if args.settle:
+            heap.settle()
         with heap.Pauses() as pauses:
+            # the watchdog belongs to the hunt for stalls on an unsettled
+            # heap: it walks the main thread's frames from another thread,
+            # and a sweep of settled windows died in such a walk (PR 29)
             win = serve.measure(ctx, eng, arrivals, ctx.seconds, drain=drain,
-                                stall_dump_s=1.0)
+                                stall_dump_s=None if args.settle else 1.0)
         ttft = [win.ttft_s.get(r.rid, serve.DRAIN_LIMIT_S)
                 for r in win.reqs]
         gaps = win.gaps_s[:win.at_close["gaps"]]
         rows.append({
             "variant": variant, "due": len(win.reqs),
             "tokens_per_s": win.at_close["n_outputs"] / win.closed_s,
-            "in_system_quarter_half_3quarter_close": [
-                win.in_system(win.total_s * q) for q in (.25, .5, .75, 1)],
+            "in_system_quarter_half_3quarter_close":
+            win.in_system_at_quarters(),
             "queue_at_close": len(win.sched.queue),
+            "queued_at_window_close": win.queued_at_window_close,
+            "mean_output_len_offered": float(np.mean(
+                [a.max_new for a in arrivals])),
+            "tick_kinds": win.tick_kinds(),
             "ttft_p50_ms": float(np.median(ttft)) * 1e3,
             "ttft_mean_ms": float(np.mean(ttft)) * 1e3,
             "ttft_p95_ms": serve._p95(ttft) * 1e3,
@@ -128,9 +141,9 @@ def vary(args, variants):
             "itl_p95_ms": serve._p95(gaps) * 1e3,
             "live_tokens_per_decode_step": win.at_close["decode_live_tokens"]
             / max(1, win.at_close["decode_steps"]),
+            "live_slots_per_decode_step": win.live_slots(),
             "ticks": win.at_close["ticks"],
-            "slowest_ticks_s_at": [(round(d, 3), round(b, 2)) for d, b in
-                                   sorted(win.tick_log, reverse=True)[:3]],
+            "slowest_ticks_s_at": win.slowest_ticks_s_at(3),
             **pauses.facts()})
         bench.log(f"vary: {json.dumps(rows[-1])}")
         win.sched.drain()  # empty the system before the next variant
@@ -142,6 +155,7 @@ def main():
     ap.add_argument("--seeds", default="")
     ap.add_argument("--control", action="store_true")
     ap.add_argument("--vary", default="")
+    ap.add_argument("--settle", action="store_true")
     ap.add_argument("--keep-trace", default="")
     ap.add_argument("--keep-ops", type=int, default=4000)
     ap.add_argument("--describe-trace", default="")
