@@ -9,6 +9,11 @@ from .gpt import (  # noqa: F401
     GPTEmbeddings,
     build_gpt_pipeline_descs,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHModel,
+    NemotronHForCausalLM,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertModel,
@@ -36,6 +41,9 @@ __all__ = [
     "GPTDecoderLayer",
     "GPTEmbeddings",
     "build_gpt_pipeline_descs",
+    "NemotronHConfig",
+    "NemotronHModel",
+    "NemotronHForCausalLM",
     "BertConfig",
     "BertModel",
     "BertForPretraining",

@@ -253,6 +253,16 @@ class GPTForCausalLM(Layer):
         self.cfg = cfg
         self.gpt = GPTModel(cfg)
 
+    def cache_spec(self):
+        """What a served slot keeps, layer by layer
+        (``serving.GenerationEngine`` allocates it): K and V rows of
+        ``num_heads`` heads in every layer, in the embedding's dtype."""
+        cfg = self.cfg
+        dtype = self.gpt.embeddings.word_embeddings.weight.dtype
+        return [{"kind": "kv", "heads": cfg.num_heads,
+                 "head_dim": cfg.hidden_size // cfg.num_heads,
+                 "dtype": dtype}] * cfg.num_layers
+
     def forward(self, input_ids, position_ids=None, attn_mask=None,
                 cache=None):
         w = self.gpt.embeddings.word_embeddings.weight  # [vocab, hidden]

@@ -13,6 +13,8 @@ from .layer.loss import *  # noqa: F401,F403
 from .decode import BeamSearchDecoder, Decoder, dynamic_decode  # noqa: F401
 from .layer.transformer import *  # noqa: F401,F403
 from .layer.rnn import *  # noqa: F401,F403
+from .layer.experts import DroplessExperts  # noqa: F401
+from .layer.mamba import Mamba2Mixer  # noqa: F401
 from . import quant  # noqa: F401
 
 from ..utils.clip import ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue  # noqa: F401

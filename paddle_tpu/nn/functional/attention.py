@@ -258,6 +258,50 @@ def _blockwise_blocks(sq, sk):
     return bq, bk
 
 
+#: query rows of a decode-shaped call (decode 1; verify spec_k + 1): the
+#: calls whose route ``attn.decode_route`` counts
+DECODE_ROWS = 8
+
+
+def _count_decode_route(route):
+    """Counter ``attn.decode_route.<route>``: bumped when a decode-shaped
+    cached call is TRACED (the route is a property of the compiled step,
+    not of a tick), so a model that fell off the kernel says so."""
+    from ...profiler import telemetry
+
+    if telemetry.enabled():
+        telemetry.get_telemetry().inc(f"attn.decode_route.{route}")
+
+
+def _repeat_kv_heads(key, value, heads):
+    """Each K/V head once per query head of its group (uncached calls and
+    prefill buckets: a few MB; never the cache)."""
+    from ... import ops
+
+    rep = heads // key.shape[2]
+    return (ops.repeat_interleave(key, rep, axis=2),
+            ops.repeat_interleave(value, rep, axis=2))
+
+
+@op("sdpa_grouped_decode")
+def _sdpa_grouped_decode(q, k, v, q_pos, kv_len=None, scale=None):
+    """A few query rows over a cache whose K/V heads each serve a GROUP of
+    query heads: one einsum per product with the group as a batch
+    dimension, the cache read in its own dtype and never repeated per
+    query head; scores and softmax in float32."""
+    b, sq, h, d = q.shape
+    sk, hk = k.shape[1], k.shape[2]
+    s = scale if scale is not None else 1.0 / math.sqrt(d)
+    qg = q.reshape(b, sq, hk, h // hk, d)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                        preferred_element_type=jnp.float32) * s
+    ok = LengthMask(q_pos, kv_len).valid(sk)[:, :, None]   # [b,1,1,q,sk]
+    probs = jax.nn.softmax(jnp.where(ok, logits, NEG_INF), axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, sq, h, d).astype(q.dtype)
+
+
 def _route_length_masked(query, key, value, lm, dropout_p, training, scale):
     """Cached-attention routing. With Pallas: the decode-shaped kernel for a
     few query rows (decode, verify), the length-masked flash kernel for
@@ -268,6 +312,14 @@ def _route_length_masked(query, key, value, lm, dropout_p, training, scale):
     b, sq, h, d = query.shape
     sk = key.shape[1]
     active_p = dropout_p if training else 0.0
+    if key.shape[2] != h:
+        if sq <= DECODE_ROWS and active_p == 0.0:
+            _count_decode_route("einsum_grouped")
+            return _sdpa_grouped_decode(query, key, value, lm.q_pos,
+                                        lm.kv_len, scale=scale)
+        # a prefill bucket holds its own keys and values only: repeating
+        # them per query head is a few MB, and every route below takes it
+        key, value = _repeat_kv_heads(key, value, h)
     if _blockwise_ok(query.shape, key.shape, dropout_p, training):
         from ...ops import pallas
 
@@ -277,14 +329,19 @@ def _route_length_masked(query, key, value, lm, dropout_p, training, scale):
             from ...ops.pallas.flash_decode import supports_decode
 
             if supports_decode(sq, sk, h, d, key.dtype.itemsize):
+                _count_decode_route("flash_decode")
                 return _sdpa_flash_decode(query, key, value, lm.q_pos,
                                           lm.kv_len, scale=s)
             if supports_cached(sq, sk, d):
                 return _sdpa_flash_cached(query, key, value, lm.q_pos,
                                           lm.kv_len, scale=s)
+        if sq <= DECODE_ROWS:
+            _count_decode_route("blockwise")
         bq, bk = _blockwise_blocks(sq, sk)
         return _sdpa_blockwise(query, key, value, lm.q_pos, lm.kv_len,
                                scale=s, block_q=bq, block_k=bk)
+    if sq <= DECODE_ROWS:
+        _count_decode_route("einsum")
     mask = lm.additive(sk, query.dtype)
     dropout_mask = None
     if active_p > 0.0:
@@ -417,6 +474,8 @@ def _sdpa(query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
     if isinstance(attn_mask, LengthMask):
         return _route_length_masked(query, key, value, attn_mask, dropout_p,
                                     training, scale)
+    if key.shape[2] != query.shape[2]:  # grouped K/V heads, no cache
+        key, value = _repeat_kv_heads(key, value, query.shape[2])
     trainable = (attn_mask is not None
                  and getattr(attn_mask, "stop_gradient", True) is False)
     if _flash_ok(query.shape, key.shape, attn_mask, dropout_p, training,

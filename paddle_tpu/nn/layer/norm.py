@@ -231,8 +231,12 @@ class RMSNorm(Layer):
         def _rms(xv, w):
             from jax import lax
 
-            ms = jnp.mean(jnp.square(xv), axis=-1, keepdims=True)
-            return xv * lax.rsqrt(ms + eps) * w
+            # float32 inside, the input's dtype outside: a bfloat16
+            # stream is not squared in bfloat16, nor promoted by the gain
+            xf = xv.astype(jnp.float32)
+            ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+            return (xf * lax.rsqrt(ms + eps)
+                    * w.astype(jnp.float32)).astype(xv.dtype)
 
         return _rms(x, self.weight)
 

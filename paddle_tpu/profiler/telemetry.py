@@ -186,7 +186,7 @@ class _StepRecord:
     and whose ``owner`` is the scheduler's id."""
 
     __slots__ = ("index", "start_ns", "end_ns", "phases", "kind", "owner",
-                 "spans", "_prev")
+                 "spans", "counts", "_prev")
 
     def __init__(self, index, start_ns, kind="step", owner=None):
         self.index = index
@@ -196,6 +196,7 @@ class _StepRecord:
         self.kind = kind
         self.owner = owner
         self.spans = []
+        self.counts = {}
         self._prev = None
 
     @property
@@ -205,7 +206,7 @@ class _StepRecord:
     def as_dict(self):
         return {"step": self.index, "kind": self.kind, "owner": self.owner,
                 "wall_s": self.wall_s, "phases": dict(self.phases),
-                "spans": list(self.spans)}
+                "spans": list(self.spans), "counts": dict(self.counts)}
 
 
 #: jax.monitoring duration events -> the part of a compile they time
@@ -429,6 +430,16 @@ class Telemetry(Monitor):
                 cur.phases[name] = cur.phases.get(name, 0.0) + secs
                 cur.spans.append((name, start_ns, end_ns))
                 cur.end_ns = max(cur.end_ns, end_ns)
+
+    def add_count(self, name, n=1):
+        """Count ``n`` of ``name`` in the open record (a serving tick's
+        ``counts``: what the tick's steps routed, held, kept live) and in
+        the process-wide counter of the same name."""
+        with self._lock:
+            self._counters[name] = self._counters.get(name, 0) + n
+            cur = self._current
+            if cur is not None:
+                cur.counts[name] = cur.counts.get(name, 0) + n
 
     def steps(self, kind=None, owner=None):
         """Closed records, oldest first (bounded by ``ring_size``);

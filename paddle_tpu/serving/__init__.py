@@ -14,13 +14,20 @@ deterministic chaos harness.
 from .kv_cache import (  # noqa: F401
     KVCache,
     ChunkView,
+    CountsView,
     DecodeView,
     PrefillView,
+    StateDecodeView,
+    StatePrefillView,
     default_buckets,
     pick_bucket,
 )
 from .draft import DraftProposer, NgramProposer  # noqa: F401
-from .engine import GenerationEngine, EncoderScorer  # noqa: F401
+from .engine import (  # noqa: F401
+    EncoderScorer,
+    GenerationEngine,
+    RecurrentStateError,
+)
 from .scheduler import (  # noqa: F401
     FINISH_REASONS,
     CostAwareAdmission,
@@ -34,6 +41,10 @@ __all__ = [
     "ChunkView",
     "DecodeView",
     "PrefillView",
+    "StateDecodeView",
+    "StatePrefillView",
+    "CountsView",
+    "RecurrentStateError",
     "DraftProposer",
     "NgramProposer",
     "default_buckets",

@@ -60,15 +60,26 @@ from ..profiler import tracing as _tracing
 from .kv_cache import (
     MASK_MIN,
     ChunkView,
+    CountsView,
     DecodeView,
     KVCache,
     PrefillView,
+    StateDecodeView,
+    StatePrefillView,
     _leaf,
     default_buckets,
     pick_bucket,
 )
 
-__all__ = ["GenerationEngine", "EncoderScorer"]
+__all__ = ["GenerationEngine", "EncoderScorer", "RecurrentStateError"]
+
+
+class RecurrentStateError(ValueError):
+    """The model keeps recurrent state per slot and the engine was asked for
+    a step that would have to rewind or resume it: speculative verify
+    (rejected drafts have already advanced the state) or chunked prefill
+    (a later chunk continues from the state the last one left, while decode
+    ticks in between advance the same slot)."""
 
 
 def _sample_next(logits, keys, temps, top_ks, top_ps):
@@ -121,10 +132,16 @@ def _sample_next(logits, keys, temps, top_ks, top_ps):
 
 
 class GenerationEngine:
-    """Serve a decoder-only LM (``GPTForCausalLM``-shaped: callable as
-    ``model(ids, position_ids=, attn_mask=, cache=) -> (logits, cache)``,
-    with a ``cfg`` exposing ``num_layers/num_heads/hidden_size/
-    max_position_embeddings``) with O(1) static-shape decode.
+    """Serve a decoder-only LM with O(1) static-shape decode. The model is
+    callable as ``model(ids, position_ids=, attn_mask=, cache=) -> (logits,
+    cache)``, has a ``cfg`` with ``max_position_embeddings``, and DECLARES
+    what a slot keeps: ``model.cache_spec()`` lists, layer by layer, K/V
+    rows, fixed-shape recurrent state, or nothing (``kv_cache.KVCache``).
+    The engine allocates that, hands every layer its view of it in each
+    step (``cache=`` is the list of views) and threads the whole through
+    the steps' donation. Counts a layer notes on its view (an expert
+    layer's routing) come back with the step's tokens, in the same
+    read-back, and are filed in the serving tick's record.
 
     Args:
         model: the language model; switched to ``eval()``.
@@ -133,8 +150,8 @@ class GenerationEngine:
             defaults to, and may not exceed, the model's position table.
         prefill_buckets: prompt pad widths; defaults to powers of two up
             to ``max_len``. One prefill compile per bucket ever touched.
-        cache_dtype: K/V buffer dtype; defaults to the model's embedding
-            weight dtype (bf16 weights → bf16 cache).
+        cache_dtype: K/V buffer dtype; defaults to the dtype the model
+            declares (bf16 weights → bf16 cache).
         freeze_weights: fold the weights into the compiled executables as
             constants instead of threading them as (donated) state.
             ``"auto"`` (default) freezes on the CPU backend only —
@@ -156,6 +173,9 @@ class GenerationEngine:
             one-shot-per-bucket only. Prompts whose padded chunk count
             would overrun ``max_len`` (see :meth:`chunked_prefill_fits`)
             fall back to the bucketed one-shot path.
+
+    A model with recurrent state takes neither ``spec_k`` nor
+    ``prefill_chunk``: :class:`RecurrentStateError`.
     """
 
     def __init__(self, model, *, max_batch=8, max_len=None,
@@ -191,16 +211,27 @@ class GenerationEngine:
             raise ValueError(
                 f"prefill_chunk={prefill_chunk} outside "
                 f"[1, max_len={self.max_len}]")
-        self.num_layers = cfg.num_layers
-        self.num_heads = cfg.num_heads
-        self.head_dim = cfg.hidden_size // cfg.num_heads
-        if cache_dtype is None:
-            w = model.gpt.embeddings.word_embeddings.weight
-            cache_dtype = _leaf(w).dtype
-        self.cache_dtype = jnp.dtype(cache_dtype)
-        self.cache = KVCache.alloc(
-            self.num_layers, self.max_batch, self.max_len,
-            self.num_heads, self.head_dim, self.cache_dtype)
+        self.cache_spec = list(model.cache_spec())
+        kinds = [layer["kind"] if layer else None
+                 for layer in self.cache_spec]
+        self.has_state = "state" in kinds
+        if self.has_state and (self.spec_k or self.prefill_chunk):
+            raise RecurrentStateError(
+                f"the model keeps recurrent state in {kinds.count('state')} "
+                f"layers: spec_k={self.spec_k} would have to roll it back "
+                f"after a rejected draft and prefill_chunk="
+                f"{self.prefill_chunk} to resume it across ticks; neither "
+                f"is built")
+        #: names of the counts the model's layers note, in order
+        self.count_names = next(
+            (tuple(layer["names"]) for layer in self.cache_spec
+             if layer and layer["kind"] == "counts"), ())
+        self.cache_dtype = None if cache_dtype is None \
+            else jnp.dtype(cache_dtype)
+        self.cache = self._alloc_cache()
+        #: slots that hold a request (prefilled, not yet released): whose
+        #: tokens a counting layer counts, whose state is live
+        self._live = np.zeros((self.max_batch,), bool)
         if freeze_weights == "auto":
             freeze_weights = jax.default_backend() == "cpu"
         self.freeze_weights = bool(freeze_weights)
@@ -230,6 +261,65 @@ class GenerationEngine:
                 self._make_chunk_prefill(), stateful=stateful,
                 donate_state=True, donate_inputs=["args[4]"])
 
+    # -- the declared cache --------------------------------------------------
+    def _alloc_cache(self):
+        return KVCache.from_spec(self.cache_spec, self.max_batch,
+                                 self.max_len, self.cache_dtype)
+
+    def _views(self, cache, kv, state, valid):
+        """One view a layer, by what the layer declared: ``kv(k, v)`` and
+        ``state(arrays)`` build this step's views of K/V and of recurrent
+        state, a counting layer is told which tokens are ``valid``, a layer
+        that keeps nothing gets None."""
+        views = []
+        for layer, k, v, st in zip(self.cache_spec, cache.ks, cache.vs,
+                                   cache.states):
+            kind = layer["kind"] if layer else None
+            views.append(kv(k, v) if kind == "kv"
+                         else state(st) if kind == "state"
+                         else CountsView(valid) if kind == "counts"
+                         else None)
+        return views
+
+    @staticmethod
+    def _collect(views, lengths):
+        """The next cache from the step's views, and the sum of what the
+        counting layers noted (None without one)."""
+        counts = [v.counts for v in views if isinstance(v, CountsView)]
+        cache = KVCache(
+            [getattr(v, "k", None) for v in views],
+            [getattr(v, "v", None) for v in views], lengths,
+            [getattr(v, "arrays", None) for v in views])
+        return cache, (sum(counts[1:], counts[0]) if counts else None)
+
+    @staticmethod
+    def _pack(tokens, counts):
+        """The step's tokens and, behind them, its layers' counts: ONE int32
+        array, so the counts cost no read-back of their own."""
+        if counts is None:
+            return Tensor(tokens)
+        return Tensor(jnp.concatenate([tokens.reshape(-1), counts]))
+
+    def _unpack(self, packed, n_tokens, state_live=None):
+        """Host side of :meth:`_pack`: the tokens; the counts are filed in
+        the open serving tick's record (``Telemetry.add_count``), a decode
+        step's under the layers' names, a prefill's under ``<name>.prefill``
+        (``state_live`` is given by decode alone)."""
+        out = np.asarray(_leaf(packed)).reshape(-1)
+        if _telemetry.enabled():
+            tm = _telemetry.get_telemetry()
+            suffix = ".prefill" if state_live is None else ""
+            for name, n in zip(self.count_names, out[n_tokens:]):
+                tm.add_count(name + suffix, int(n))
+            if state_live is not None and self.has_state:
+                tm.add_count("serve.state_live_slots", int(state_live))
+        return out[:n_tokens]
+
+    def release_slot(self, slot):
+        """The scheduler's word that ``slot`` holds no request any more:
+        its tokens stop counting as routed, its state as live."""
+        self._live[int(slot)] = False
+
     # -- traced step bodies --------------------------------------------------
     def _make_prefill(self):
         model = self.model
@@ -247,8 +337,9 @@ class GenerationEngine:
             # (q_pos, kv_len) so the blockwise/Pallas attention paths never
             # materialize the [1, 1, bucket, bucket] score mask.
             lmask = LengthMask(i[None, :], ln[None])
-            views = [PrefillView(cache.ks[l], cache.vs[l], sl)
-                     for l in range(len(cache.ks))]
+            views = self._views(
+                cache, lambda k, v: PrefillView(k, v, sl),
+                lambda st: StatePrefillView(st, sl, ln), (i < ln)[None, :])
             logits, views = model(
                 tokens, position_ids=Tensor(i[None, :]),
                 attn_mask=lmask, cache=views)
@@ -261,9 +352,8 @@ class GenerationEngine:
             next_tok = jnp.argmax(last).astype(jnp.int32)
             new_len = jax.lax.dynamic_update_slice(
                 _leaf(cache.lengths), jnp.minimum(ln, max_len)[None], (sl,))
-            new_cache = KVCache(tuple(v.k for v in views),
-                                tuple(v.v for v in views), new_len)
-            return Tensor(next_tok), new_cache
+            new_cache, counts = self._collect(views, new_len)
+            return self._pack(next_tok, counts), new_cache
 
         return serve_prefill
 
@@ -285,8 +375,9 @@ class GenerationEngine:
             # key j is valid for chunk row i iff j <= of + i — exactly the
             # LengthMask q_pos semantics over the slot's full cached row
             lmask = LengthMask(pos[None, :])
-            views = [ChunkView(cache.ks[l], cache.vs[l], sl, of)
-                     for l in range(len(cache.ks))]
+            views = self._views(
+                cache, lambda k, v: ChunkView(k, v, sl, of), None,
+                (i < cl)[None, :])
             logits, views = model(
                 tokens, position_ids=Tensor(pos[None, :]),
                 attn_mask=lmask, cache=views)
@@ -301,9 +392,8 @@ class GenerationEngine:
             new_len = jax.lax.dynamic_update_slice(
                 _leaf(cache.lengths),
                 jnp.minimum(of + cl, max_len)[None], (sl,))
-            new_cache = KVCache(tuple(v.k for v in views),
-                                tuple(v.v for v in views), new_len)
-            return Tensor(next_tok), new_cache
+            new_cache, counts = self._collect(views, new_len)
+            return self._pack(next_tok, counts), new_cache
 
         return serve_prefill_chunk
 
@@ -311,16 +401,20 @@ class GenerationEngine:
         model = self.model
         max_len = self.max_len
 
-        def serve_decode(tokens, cache, keys, temps, top_ks, top_ps):
+        def serve_decode(tokens, cache, keys, temps, top_ks, top_ps,
+                         live=None):
             # tokens [max_batch, 1] int32 — each slot's last token, fed at
-            # that slot's own position; shapes NEVER vary step to step
+            # that slot's own position; shapes NEVER vary step to step.
+            # ``live [max_batch]`` (only a model whose layers count is
+            # handed it): the slots that hold a request
             ln = _leaf(cache.lengths).astype(jnp.int32)
             pos = jnp.minimum(ln, max_len - 1)  # [b]
             # each slot's single query row sits at its own position; keys
             # j <= pos[b] are valid — no [b, 1, 1, max_len] mask tensor
             lmask = LengthMask(pos[:, None])
-            views = [DecodeView(cache.ks[l], cache.vs[l], pos)
-                     for l in range(len(cache.ks))]
+            views = self._views(
+                cache, lambda k, v: DecodeView(k, v, pos), StateDecodeView,
+                None if live is None else _leaf(live)[:, None])
             logits, views = model(
                 tokens, position_ids=Tensor(pos[:, None]),
                 attn_mask=lmask, cache=views)
@@ -330,10 +424,8 @@ class GenerationEngine:
             next_tok, new_keys = _sample_next(
                 last, _leaf(keys), _leaf(temps),
                 _leaf(top_ks), _leaf(top_ps))
-            new_cache = KVCache(tuple(v.k for v in views),
-                                tuple(v.v for v in views),
-                                Tensor(ln + 1))
-            return Tensor(next_tok), Tensor(new_keys), new_cache
+            new_cache, counts = self._collect(views, Tensor(ln + 1))
+            return self._pack(next_tok, counts), Tensor(new_keys), new_cache
 
         return serve_decode
 
@@ -360,8 +452,8 @@ class GenerationEngine:
             # window row i of slot b queries position pos[b, i]; keys
             # j <= pos[b, i] are valid — no [b, 1, W, max_len] mask tensor
             lmask = LengthMask(pos)
-            views = [DecodeView(cache.ks[l], cache.vs[l], pos0)
-                     for l in range(len(cache.ks))]
+            views = self._views(
+                cache, lambda k, v: DecodeView(k, v, pos0), None, None)
             logits, views = model(
                 tokens, position_ids=Tensor(pos),
                 attn_mask=lmask, cache=views)
@@ -378,8 +470,7 @@ class GenerationEngine:
                 _leaf(top_ks), _leaf(top_ps))
             # lengths UNCHANGED — the host commits the accepted count
             # (commit_lengths) after comparing drafts to greedy
-            new_cache = KVCache(tuple(v.k for v in views),
-                                tuple(v.v for v in views), Tensor(ln))
+            new_cache, _ = self._collect(views, Tensor(ln))
             return (Tensor(greedy), Tensor(tok0), Tensor(new_keys),
                     new_cache)
 
@@ -462,8 +553,9 @@ class GenerationEngine:
             tok, cache = self._prefill_step(
                 toks, np.int32(prompt.size), np.int32(slot), self.cache)
         self.cache = cache  # donated: the old buffers are consumed
+        self._live[int(slot)] = True
         with _telemetry.phase_span("serve.prefill_readback"):
-            return int(np.asarray(_leaf(tok)))
+            return int(self._unpack(tok, 1)[0])
 
     def chunked_prefill_fits(self, prompt_len):
         """True when a prompt of this length can prefill through the
@@ -525,8 +617,9 @@ class GenerationEngine:
                 self.cache)
         self.cache = cache
         if off + piece.size >= prompt.size:
+            self._live[int(slot)] = True
             with _telemetry.phase_span("serve.prefill_readback"):
-                return int(np.asarray(_leaf(tok)))
+                return int(self._unpack(tok, 1)[0])
         return None
 
     def decode_once(self, last_tokens):
@@ -538,15 +631,16 @@ class GenerationEngine:
                 self.max_batch, 1)
             self._declare_variants()
             _inject.check("serve.decode")  # pre-donation: retry-safe
+            live = (self._live.copy(),) if self.count_names else ()
             tok, keys, cache = self._decode_step(
                 feed, self.cache, self._keys, self._temps,
-                self._top_ks, self._top_ps)
+                self._top_ks, self._top_ps, *live)
             self.cache = cache
             self._keys = _leaf(keys)
         # the one blocking wait of a tick: the host sits here while the
         # device runs the step it was just handed
         with _telemetry.phase_span("serve.decode_readback"):
-            return np.asarray(_leaf(tok))
+            return self._unpack(tok, self.max_batch, self._live.sum())
 
     def verify_once(self, window_tokens):
         """One speculative verify step over ``[max_batch, spec_k + 1]``
@@ -587,7 +681,8 @@ class GenerationEngine:
                           .reshape(self.max_batch))
         ln = _leaf(self.cache.lengths).astype(jnp.int32)
         self.cache = KVCache(self.cache.ks, self.cache.vs,
-                             jnp.minimum(ln + adv, self.max_len))
+                             jnp.minimum(ln + adv, self.max_len),
+                             self.cache.states)
 
     def generate(self, prompt_ids, max_new_tokens=32, eos_id=None):
         """Greedy single-request generation (slot 0; other slots idle).
@@ -690,10 +785,8 @@ class GenerationEngine:
     def _example_cache(self, lengths):
         ln = np.zeros((self.max_batch,), np.int32)
         ln[:len(lengths)] = np.asarray(lengths, np.int32)
-        cache = KVCache.alloc(self.num_layers, self.max_batch, self.max_len,
-                              self.num_heads, self.head_dim,
-                              self.cache_dtype)
-        return KVCache(cache.ks, cache.vs, jnp.asarray(ln))
+        cache = self._alloc_cache()
+        return KVCache(cache.ks, cache.vs, jnp.asarray(ln), cache.states)
 
     def example_decode_args(self, lengths):
         """A shape-faithful ``(tokens, cache, keys, temps, top_ks,
@@ -702,8 +795,10 @@ class GenerationEngine:
         positions lint identically — that IS the O(1) contract the
         ``kv-cache-concat`` rule checks."""
         tokens = np.zeros((self.max_batch, 1), np.int32)
+        live = (np.ones((self.max_batch,), bool),) if self.count_names \
+            else ()
         return (tokens, self._example_cache(lengths),
-                *self._example_sampling_args())
+                *self._example_sampling_args(), *live)
 
     def example_verify_args(self, lengths):
         """Shape-faithful example batch for linting the speculative

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from ..framework.tensor import Tensor
 
 __all__ = ["KVCache", "DecodeView", "PrefillView", "ChunkView",
+           "StateDecodeView", "StatePrefillView", "CountsView",
            "pick_bucket", "default_buckets"]
 
 #: additive-mask floor: large enough to zero a softmax lane in fp32/bf16
@@ -82,28 +83,40 @@ def pick_bucket(n, buckets):
 # ---------------------------------------------------------------------------
 @jax.tree_util.register_pytree_node_class
 class KVCache:
-    """Per-layer static K/V buffers + per-slot valid lengths.
+    """What the served slots keep, layer by layer, as the MODEL declares it
+    (``model.cache_spec()``, one entry a layer):
+
+    * ``{"kind": "kv", "heads", "head_dim", "dtype"}`` — K/V rows up to
+      ``max_len``: ``ks[l] / vs[l]: [batch, max_len, heads, head_dim]``;
+    * ``{"kind": "state", "arrays": {name: (shape, dtype)}}`` — fixed-shape
+      recurrent state: ``states[l][name]: [batch, *shape]``;
+    * anything else (``None``, ``{"kind": "counts", ...}``) — the layer
+      keeps nothing per slot: ``ks[l]``, ``vs[l]``, ``states[l]`` are None.
+
+    ``lengths[i]`` is the number of valid cached tokens in batch slot ``i``.
+    K/V rows beyond it are garbage by contract (masked until overwritten).
+    A recurrent state has no such mask — a stale one would be USED — so a
+    prefill always starts a slot's state from zeros
+    (:class:`StatePrefillView`) and overwrites what the last request left.
 
     A registered pytree, so it threads straight through ``CompiledStep``
     arguments (and its leaves can be donated with
     ``donate_inputs=["args[i]"]`` — every leaf path under the cache
-    argument matches the prefix). ``lengths[i]`` is the number of valid
-    cached tokens in batch slot ``i``; buffers beyond it are garbage by
-    contract (masked until overwritten).
-
-    Layout: ``ks[layer] / vs[layer]: [batch, max_len, heads, head_dim]``,
-    ``lengths: [batch] int32``.
+    argument matches the prefix). Leaves: the layers' K, then their V, then
+    ``lengths``, then the states.
     """
 
-    __slots__ = ("ks", "vs", "lengths")
+    __slots__ = ("ks", "vs", "lengths", "states")
 
-    def __init__(self, ks, vs, lengths):
+    def __init__(self, ks, vs, lengths, states=None):
         self.ks = tuple(ks)
         self.vs = tuple(vs)
         self.lengths = lengths
+        self.states = (None,) * len(self.ks) if states is None \
+            else tuple(states)
 
     def tree_flatten(self):
-        return ((self.ks, self.vs, self.lengths), None)
+        return ((self.ks, self.vs, self.lengths, self.states), None)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -111,12 +124,34 @@ class KVCache:
         return cls(*children)
 
     @classmethod
+    def from_spec(cls, spec, batch, max_len, kv_dtype=None):
+        """Zeroed buffers for ``batch`` slots of ``max_len`` positions as
+        ``spec`` declares them (``kv_dtype`` overrides the K/V dtype)."""
+        batch, max_len = int(batch), int(max_len)
+        ks, vs, states = [], [], []
+        for layer in spec:
+            kind = layer["kind"] if layer else None
+            k = v = state = None
+            if kind == "kv":
+                shape = (batch, max_len, int(layer["heads"]),
+                         int(layer["head_dim"]))
+                dtype = kv_dtype or layer["dtype"]
+                k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+            elif kind == "state":
+                state = {name: jnp.zeros((batch,) + tuple(shape), dtype)
+                         for name, (shape, dtype) in layer["arrays"].items()}
+            ks.append(k)
+            vs.append(v)
+            states.append(state)
+        return cls(ks, vs, jnp.zeros((batch,), jnp.int32), states)
+
+    @classmethod
     def alloc(cls, num_layers, batch, max_len, num_heads, head_dim,
               dtype=jnp.float32):
-        shape = (int(batch), int(max_len), int(num_heads), int(head_dim))
-        ks = tuple(jnp.zeros(shape, dtype) for _ in range(num_layers))
-        vs = tuple(jnp.zeros(shape, dtype) for _ in range(num_layers))
-        return cls(ks, vs, jnp.zeros((int(batch),), jnp.int32))
+        """``num_layers`` layers of K/V alone."""
+        return cls.from_spec(
+            [{"kind": "kv", "heads": num_heads, "head_dim": head_dim,
+              "dtype": dtype}] * int(num_layers), batch, max_len)
 
     # shape accessors read through Tensor leaves (inside a traced step the
     # leaves are Tensors wrapping tracers; outside, jax arrays)
@@ -124,31 +159,36 @@ class KVCache:
     def num_layers(self):
         return len(self.ks)
 
+    def _first_k(self):
+        return _leaf(next(k for k in self.ks if k is not None))
+
     @property
     def batch(self):
-        return int(_leaf(self.ks[0]).shape[0])
+        return int(_leaf(self.lengths).shape[0])
 
     @property
     def max_len(self):
-        return int(_leaf(self.ks[0]).shape[1])
+        return int(self._first_k().shape[1])
 
     @property
     def num_heads(self):
-        return int(_leaf(self.ks[0]).shape[2])
+        return int(self._first_k().shape[2])
 
     @property
     def head_dim(self):
-        return int(_leaf(self.ks[0]).shape[3])
+        return int(self._first_k().shape[3])
 
     def nbytes(self):
-        k = _leaf(self.ks[0])
-        per = k.size * jnp.dtype(k.dtype).itemsize
-        return 2 * self.num_layers * int(per)
+        """Bytes of every per-slot buffer (K/V and states; not lengths)."""
+        return sum(int(_leaf(a).size) * jnp.dtype(_leaf(a).dtype).itemsize
+                   for a in jax.tree_util.tree_leaves(
+                       (self.ks, self.vs, self.states)))
 
     def __repr__(self):
-        k = _leaf(self.ks[0])
-        return (f"KVCache(layers={self.num_layers}, "
-                f"shape={tuple(k.shape)}, dtype={k.dtype})")
+        kinds = ["kv" if k is not None else "state" if st is not None
+                 else "-" for k, st in zip(self.ks, self.states)]
+        return (f"KVCache(batch={self.batch}, layers={kinds}, "
+                f"bytes={self.nbytes()})")
 
 
 # ---------------------------------------------------------------------------
@@ -257,3 +297,67 @@ class ChunkView:
         row_k = jax.lax.dynamic_slice(self.k, (sl, z, z, z), row_shape)
         row_v = jax.lax.dynamic_slice(self.v, (sl, z, z, z), row_shape)
         return Tensor(row_k), Tensor(row_v), self
+
+
+# ---------------------------------------------------------------------------
+# views of layers that keep recurrent state, or nothing
+# ---------------------------------------------------------------------------
+class StateDecodeView:
+    """One layer's recurrent state in the batched decode step: every slot's
+    arrays, read whole and replaced whole (one new position a slot)."""
+
+    __slots__ = ("arrays",)
+    valid_len = None  # every position of the step is a real one
+
+    def __init__(self, arrays):
+        self.arrays = {k: _leaf(v) for k, v in arrays.items()}
+
+    def read(self):
+        return self.arrays
+
+    def write(self, **new):
+        self.arrays = {k: _leaf(new[k]).astype(v.dtype)
+                       for k, v in self.arrays.items()}
+
+
+class StatePrefillView:
+    """One layer's recurrent state in the single-request prefill step. The
+    request starts from ZEROS whatever the slot held (a reused slot's last
+    state is not masked by any length: it would be used), runs its padded
+    bucket with ``valid_len`` real positions, and writes the state after the
+    last of them into row ``slot``."""
+
+    __slots__ = ("arrays", "slot", "valid_len")
+
+    def __init__(self, arrays, slot, valid_len):
+        self.arrays = {k: _leaf(v) for k, v in arrays.items()}
+        self.slot = _leaf(slot).astype(jnp.int32)
+        self.valid_len = _leaf(valid_len).astype(jnp.int32)
+
+    def read(self):
+        return {k: jnp.zeros((1,) + v.shape[1:], v.dtype)
+                for k, v in self.arrays.items()}
+
+    def write(self, **new):
+        z = jnp.int32(0)
+        self.arrays = {
+            k: jax.lax.dynamic_update_slice(
+                v, _leaf(new[k]).astype(v.dtype),
+                (self.slot,) + (z,) * (v.ndim - 1))
+            for k, v in self.arrays.items()}
+
+
+class CountsView:
+    """What a layer that keeps nothing per slot (``{"kind": "counts",
+    "names": ...}``) is handed: ``valid [b, s]`` says which of the step's
+    tokens belong to a request, and ``note`` takes the layer's int32 counts,
+    which ride back to the host with the step's tokens."""
+
+    __slots__ = ("valid", "counts")
+
+    def __init__(self, valid):
+        self.valid = _leaf(valid)
+        self.counts = None
+
+    def note(self, counts):
+        self.counts = _leaf(counts).astype(jnp.int32)

@@ -1114,6 +1114,9 @@ class Scheduler:
             clear = getattr(self.engine, "clear_slot_sampling", None)
             if clear is not None:
                 clear(req.slot)
+        release = getattr(self.engine, "release_slot", None)
+        if release is not None:
+            release(req.slot)
         heapq.heappush(self._free, req.slot)
         self.events.append((self._step_idx, "evict", req.rid, req.slot))
         self.finished.append(req)

@@ -80,3 +80,20 @@ def test_sharded_step_compiles_and_kernels_see_the_local_batch(preflight):
     assert any("bf16[2,12,64,1024]" in l for l in operands), out
     assert not any("bf16[8," in l or "bf16[8192," in l
                    for l in operands), out
+
+
+def test_expert_and_state_kernels_compile_at_the_served_widths(preflight):
+    # the grouped expert product (an expert width that is no multiple of
+    # 128, decode- and prefill-sized tiles) and the recurrence's two kernels
+    # pass Mosaic, and no kernel is handed a copy of the 0.6 GB expert
+    # stack: XLA:TPU keeps [64, 1856, 2688] with 2688 minor, which is the
+    # order both products ask for
+    import re
+
+    out = preflight.stdout
+    for program in ("moe grouped up+down t64 tm16",
+                    "moe grouped up+down t1024 tm64",
+                    "ssm step b64 h64 p64 n128", "ssm scan carry 8 blocks"):
+        assert f"ok   {program}" in out, out
+    temps = [int(t) for t in re.findall(r"re-laid-out (\d+) bytes", out)]
+    assert len(temps) == 2 and max(temps) < 64 * 1856 * 2688, (temps, out)

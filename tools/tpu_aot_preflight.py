@@ -130,6 +130,41 @@ def kernel_programs(devs):
                lambda q=q, kv=kv, sq=sq: jax.jit(flash_attention_decode).lower(
                    q, kv, kv, _sds((8, sq), jnp.int32, one)))
 
+    # the expert and state-space kernels at the widths the benchmark's
+    # hybrid configuration serves: hidden 2688, expert width 1856 (no
+    # multiple of 128: the stacks are [experts, 1856, 2688] both ways), 64
+    # experts held, 64 slots of state [64, 64, 128]
+    from paddle_tpu.ops.pallas import moe_grouped, ssm_step
+
+    E, hid, wid = 64, 2688, 1856
+    for tokens, tm in ((64, 16), (1024, 64)):
+        tiles = -(-tokens * 6 // tm) + E
+        ints = (_sds((tiles,), jnp.int32, one), _sds((1,), jnp.int32, one))
+        stack = _sds((E, wid, hid), bf, one)
+        yield (f"moe grouped up+down t{tokens} tm{tm}",
+               lambda tiles=tiles, tm=tm, ints=ints, stack=stack: jax.jit(
+                   lambda x, up, down, te, na: moe_grouped._grouped_call(
+                       moe_grouped._grouped_call(x, up, te, na, tm, "relu2",
+                                                 True, False),
+                       down, te, na, tm, None, False, False)).lower(
+                           _sds((tiles * tm, hid), bf, one), stack, stack,
+                           *ints))
+    f32 = jnp.float32
+    slots, heads, hdim, groups, state = 64, 64, 64, 8, 128
+    yield "ssm step b64 h64 p64 n128", lambda: jax.jit(
+        lambda da, x, B, C, S: ssm_step._step_call(da, x, B, C, S,
+                                                   False)).lower(
+            _sds((slots, heads), f32, one),
+            _sds((slots, hdim, heads), f32, one),
+            _sds((slots, groups, state), f32, one),
+            _sds((slots, groups, state), f32, one),
+            _sds((slots, heads, hdim, state), f32, one))
+    yield "ssm scan carry 8 blocks", lambda: jax.jit(
+        lambda d, st, s0: ssm_step._carry_call(d, st, s0, False)).lower(
+            _sds((1, 8, heads), f32, one),
+            _sds((1, 8, heads, hdim, state), f32, one),
+            _sds((1, heads, hdim, state), f32, one))
+
     # LN + flash inside one jit sharded over the 4-device mesh
     mesh = Mesh(np.array(devs), ("dp",))
     row = NamedSharding(mesh, P("dp"))
@@ -268,6 +303,12 @@ def main(argv=None):
             print(f"       temporaries "
                   f"{compiled.memory_analysis().temp_size_in_bytes} bytes",
                   flush=True)
+        if compiled is not None and name.startswith("moe grouped"):
+            # both products contract over the stacks' minor dimension as the
+            # TPU keeps them: a program that had to re-lay a stack out would
+            # hold 0.6 GB of it here
+            print(f"       re-laid-out {compiled.memory_analysis().temp_size_in_bytes} "
+                  f"bytes", flush=True)
         if compiled is not None and name.startswith("dp4"):
             for shapes in sorted(set(
                     mosaic_operand_shapes(compiled.as_text()))):
