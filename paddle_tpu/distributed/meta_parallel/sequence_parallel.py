@@ -25,6 +25,7 @@ head dims keep their GSPMD shardings.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -83,6 +84,11 @@ def _chunk_attend(q, k, v, o, m, l, scale, mask_mode, q_idx, kv_idx, s_local,
     return o_new, m_new, l_new
 
 
+# One program per (shapes, mesh, flags). Called un-jitted, shard_map runs
+# eagerly: every primitive of the body is traced and lowered as a program of
+# its own on EVERY call (~100 lowerings a call at sep=4), and the dropout key,
+# closed over by ``local_fn``, is a fresh constant each time.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _ring_attention_impl(q, k, v, mesh, causal, scale, axis=AXIS_SEP,
                          dropout_p=0.0, dropout_key=None):
     """Global [b, S, h, d] arrays; runs the rotation ring manual over sep."""

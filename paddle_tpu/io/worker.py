@@ -240,7 +240,13 @@ class ProcessPool:
         self._restart_limit = int(
             getattr(loader, "worker_restart_limit", 0) or 0)
         self._restarts_used = 0
+        # Map-style tasks go to whichever worker is free: one queue. An
+        # iterable epoch is started once in EVERY worker: a queue each, or a
+        # worker that ends its shard early takes a slower sibling's start
+        # too and streams its own shard twice, the sibling's never.
         self._task_q = ctx.Queue()
+        self._task_qs = ([ctx.Queue() for _ in range(self._nw)]
+                         if self._iterable else [self._task_q] * self._nw)
         # bounded: back-pressure for iterable-mode workers (map-style is
         # already bounded by task issuance, which never exceeds this)
         self._capacity = max(2, self._nw * loader.prefetch_factor)
@@ -254,11 +260,12 @@ class ProcessPool:
         collate_fn = (numpy_collate_fn if self._wrap_tensors
                       else loader.collate_fn)
         # capture spawn args (not the loader: its __del__ owns this pool)
-        spawn_args = (self._nw, loader.dataset, collate_fn,
-                      self._task_q, self._result_q, loader.worker_init_fn,
-                      loader.use_shared_memory, iterable_cfg, base_seed)
+        head = (self._nw, loader.dataset, collate_fn)
+        tail = (self._result_q, loader.worker_init_fn,
+                loader.use_shared_memory, iterable_cfg, base_seed)
         self._spawn = lambda w: ctx.Process(
-            target=_worker_loop, args=(w,) + spawn_args, daemon=True)
+            target=_worker_loop,
+            args=(w,) + head + (self._task_qs[w],) + tail, daemon=True)
         self._procs = [self._spawn(w) for w in range(self._nw)]
         for p in self._procs:
             p.start()
@@ -385,8 +392,8 @@ class ProcessPool:
     def run_iterable_epoch(self):
         self._epoch += 1
         epoch = self._epoch
-        for _ in range(self._nw):
-            self._task_q.put(epoch)
+        for q in self._task_qs:
+            q.put(epoch)
         finished = 0
         while finished < self._nw:
             out = self._handle(self._poll(), epoch)
@@ -398,9 +405,9 @@ class ProcessPool:
             yield out[2]
 
     def shutdown(self):
-        for _ in self._procs:
+        for q in self._task_qs:
             try:
-                self._task_q.put(_STOP)
+                q.put(_STOP)
             except Exception:
                 pass
         for p in self._procs:

@@ -93,3 +93,21 @@ def check_grad(fn, input_arrays, rtol=1e-2, atol=1e-3, delta=1e-3, out_grad=None
             err_msg=f"gradient mismatch for input {i} of {getattr(fn, '__name__', fn)}",
         )
     return out
+
+
+def forward_shapes(net, *in_shape):
+    """Output shapes of ``net`` on a float32 input of ``in_shape``, from the
+    trace alone (``jax.eval_shape``): every layer's forward runs and every
+    channel count has to agree, but nothing is compiled. Eagerly a zoo CNN
+    costs one XLA:CPU executable per (op, shape), ~60 s for densenet121, to
+    assert the same shapes."""
+    import jax
+
+    def fwd(a):
+        out = net(Tensor(a))
+        outs = out if isinstance(out, (tuple, list)) else (out,)
+        return tuple(o._value for o in outs)
+
+    with paddle.no_grad():
+        outs = jax.eval_shape(fwd, jax.ShapeDtypeStruct(in_shape, np.float32))
+    return [list(o.shape) for o in outs]

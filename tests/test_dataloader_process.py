@@ -2,6 +2,7 @@
 shared-memory transport, persistent workers, error/crash propagation.
 Reference: ``fluid/dataloader/dataloader_iter.py:342``
 (_DataLoaderIterMultiProcess) + ``memory/allocation/mmap_allocator.cc``."""
+import multiprocessing as mp
 import os
 import time
 
@@ -82,14 +83,25 @@ class ArrayDataset(Dataset):
 
 class PyHeavyDataset(ArrayDataset):
     """Pure-Python per-sample transform: the GIL-bound case processes exist
-    for."""
+    for. Every sample comes back beside the pid that made it, and the first
+    sample of each of the first ``parties`` batches waits until ``parties``
+    workers are inside the transform at once (a worker that waits can take
+    no second batch, so each of them holds one)."""
+
+    def __init__(self, n, batch_size, parties):
+        super().__init__(n)
+        self._batch_size, self._parties = batch_size, parties
+        self._together = mp.get_context("fork").Barrier(parties)
 
     def __getitem__(self, i):
+        batch, first = divmod(i, self._batch_size)
+        if first == 0 and batch < self._parties:
+            self._together.wait(timeout=60)
         acc = 0.0
         for j in range(20000):
             acc += (i * j) % 7
         x, y = super().__getitem__(i)
-        return x + (acc % 3), y
+        return x + (acc % 3), y, np.int64(os.getpid())
 
 
 class BoomDataset(ArrayDataset):
@@ -188,9 +200,17 @@ def test_early_break_then_reiterate():
     assert _collect(loader) == list(range(64))  # stale epoch fully discarded
 
 
-def test_iterable_dataset_process_sharding():
+def _worker_2_starts_late(wid):
+    if wid == 2:
+        time.sleep(0.5)
+
+
+@pytest.mark.parametrize("worker_init_fn", [None, _worker_2_starts_late])
+def test_iterable_dataset_process_sharding(worker_init_fn):
+    # every worker streams its own shard once, however late it starts: its
+    # siblings, done with theirs, must not take the epoch's start meant for it
     loader = DataLoader(ShardedIterable(48), batch_size=4, num_workers=3,
-                        use_process=True)
+                        use_process=True, worker_init_fn=worker_init_fn)
     got = []
     for batch in loader:
         got.extend(np.asarray(batch).astype(int).tolist())
@@ -217,22 +237,29 @@ def test_worker_init_fn_runs_and_failure_propagates():
         list(loader)
 
 
-@pytest.mark.skipif((os.cpu_count() or 1) < 2,
-                    reason="parallel speedup needs >1 core")
-def test_python_heavy_transform_speedup():
+def test_python_heavy_transform_runs_in_every_worker_process():
     """The reason process workers exist: a pure-Python transform is GIL-bound
-    under threads but parallel under processes."""
-    ds = PyHeavyDataset(n=32)
+    under threads but parallel under processes. Counted, not timed: the
+    transform ran in ``num_workers`` processes at once, none of them this
+    one, and the batches are the threaded loader's."""
+    ds = PyHeavyDataset(n=32, batch_size=4, parties=4)
 
-    t0 = time.perf_counter()
-    _collect(DataLoader(ds, batch_size=4, num_workers=4))
-    threaded = time.perf_counter() - t0
+    def collect(loader):
+        xs, ys, pids = zip(*((np.asarray(x), np.asarray(y), np.asarray(p))
+                             for x, y, p in loader))
+        return (np.concatenate(xs), np.concatenate(ys),
+                set(np.concatenate(pids).tolist()))
 
-    t0 = time.perf_counter()
-    _collect(DataLoader(ds, batch_size=4, num_workers=4, use_process=True))
-    proc_time = time.perf_counter() - t0
+    x_thr, y_thr, pids_thr = collect(
+        DataLoader(ds, batch_size=4, num_workers=4))
+    x_proc, y_proc, pids_proc = collect(
+        DataLoader(ds, batch_size=4, num_workers=4, use_process=True))
 
-    assert proc_time < threaded, (proc_time, threaded)
+    assert pids_thr == {os.getpid()}
+    assert len(pids_proc) == 4 and os.getpid() not in pids_proc
+    np.testing.assert_array_equal(x_proc, x_thr)
+    np.testing.assert_array_equal(y_proc, y_thr)
+    assert y_proc.tolist() == list(range(32))
 
 
 def test_concurrent_iterators_on_persistent_loader():

@@ -4,7 +4,12 @@ runs in bfloat16 AND float16 — the framework's actual training dtypes —
 with the analytic low-precision gradient compared against the fp32
 analytic gradient at representable input points (reference discipline:
 ``unittests/op_test.py:1851`` per-dtype check_grad). Skips/deviations are
-declared in the table's LOWP map, with reasons."""
+declared in the table's LOWP map, with reasons.
+
+This file holds the check and the cases of the tensor-op surface
+(``ops.*``); those of ``nn.functional`` (``F.*``) are
+``tests/test_op_grad_sweep_lowp_functional.py``. Under ``--dist loadfile`` a
+file is one worker's, and the whole table was 123 s of one."""
 import numpy as np
 import pytest
 
@@ -15,13 +20,11 @@ from tests.op_test import check_grad_lowp
 from tests.test_op_grad_sweep import _ADAPTERS, _draw, _ids, _resolve  # noqa: F401
 
 
-def _cases():
-    ids = _ids()
-    out = []
-    for e, eid in zip(OPS, ids):
-        for dtype in ("bfloat16", "float16"):
-            out.append(pytest.param(e, dtype, id=f"{eid}-{dtype}"))
-    return out
+def _cases(surface):
+    """The table's entries of one surface ("ops." or "F."), in both dtypes."""
+    return [pytest.param(e, dtype, id=f"{eid}-{dtype}")
+            for e, eid in zip(OPS, _ids()) if e["api"].startswith(surface)
+            for dtype in ("bfloat16", "float16")]
 
 
 def test_lowp_axis_covers_table():
@@ -30,8 +33,12 @@ def test_lowp_axis_covers_table():
     assert len(active) >= 150, len(active)
 
 
-@pytest.mark.parametrize("entry,dtype", _cases())
+@pytest.mark.parametrize("entry,dtype", _cases("ops."))
 def test_op_gradient_lowp(entry, dtype):
+    _check_lowp(entry, dtype)
+
+
+def _check_lowp(entry, dtype):
     spec = LOWP.get(entry["api"])
     if spec is False:
         pytest.skip(f"{entry['api']}: low-precision skipped (see LOWP map)")

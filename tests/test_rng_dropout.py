@@ -41,16 +41,19 @@ def test_pipelined_dropout_trains_and_varies():
 
     ids = Tensor(np.random.RandomState(0).randint(0, 64, (4, 16)).astype(np.int64))
 
-    piped.train()
-    l1 = float(np.asarray(piped.loss(ids, ids)._value))
-    l2 = float(np.asarray(piped.loss(ids, ids)._value))
-    assert np.isfinite(l1) and np.isfinite(l2)
-    assert l1 != l2, "train-mode dropout produced identical losses across steps"
+    # forward only: nothing here calls backward, and with grad recording on
+    # each eager loss also lowers the schedule's vjp
+    with paddle.no_grad():
+        piped.train()
+        l1 = float(np.asarray(piped.loss(ids, ids)._value))
+        l2 = float(np.asarray(piped.loss(ids, ids)._value))
+        assert np.isfinite(l1) and np.isfinite(l2)
+        assert l1 != l2, "train-mode dropout produced identical losses across steps"
 
-    piped.eval()
-    e1 = float(np.asarray(piped.loss(ids, ids)._value))
-    e2 = float(np.asarray(piped.loss(ids, ids)._value))
-    assert e1 == e2, "eval mode must be deterministic"
+        piped.eval()
+        e1 = float(np.asarray(piped.loss(ids, ids)._value))
+        e2 = float(np.asarray(piped.loss(ids, ids)._value))
+        assert e1 == e2, "eval mode must be deterministic"
 
 
 def test_pipelined_dropout_masks_differ_across_microbatches():

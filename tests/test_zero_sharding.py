@@ -193,10 +193,13 @@ def test_int8_error_feedback_unbiased_over_steps():
     true_step = np.asarray(x).sum(0)
     T = 30
 
+    # one program for the T steps of the stream: called eagerly, shard_map
+    # lowers every primitive of the collective anew on every call
+    ef_step = jax.jit(lambda xs, rs: int8_all_reduce(xs, group=g, residual=rs))
     acc_ef = np.zeros_like(true_step)
-    r = None
+    r = jnp.zeros(x.shape, jnp.float32)      # what residual=None starts from
     for _ in range(T):
-        out, r = int8_all_reduce(x, group=g, residual=r)
+        out, r = ef_step(x, r)
         acc_ef += np.asarray(out)
     # naive: same collective, residual thrown away every step
     out0, _ = int8_all_reduce(x, group=g)
