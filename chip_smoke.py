@@ -42,7 +42,7 @@ import numpy as np
 
 Size = collections.namedtuple(
     "Size", "vocab hidden layers heads max_len batch seq")
-#: bench.py's GPT-2 124M cell
+#: the training cell's shapes (``gpt2_124m.train_b24_s1024``)
 FULL = Size(vocab=50304, hidden=768, layers=12, heads=12, max_len=1024,
             batch=24, seq=1024)
 #: --rehearse: same sequence lengths (so the attention router takes the same
@@ -70,7 +70,7 @@ FWD_TOL = 1e-2
 GRAD_TOL = 3e-2
 #: dp4 loss against the one-chip leg on the same data, per step: same math,
 #: a different reduction order over bf16 (fp32 wire); int8 also rounds the
-#: gathered weights (bench.py's INT8_PARITY_RTOL)
+#: gathered weights
 DP_FP32_RTOL = 1e-2
 DP_INT8_RTOL = 2e-2
 
@@ -151,8 +151,9 @@ def build_model(size):
 
 
 def build_trainer(size, mesh=None, quantize=None):
-    """bench.py's train step. With ``mesh``: parameters replicated over it
-    and the update sharded over ``dp`` by ``ShardedOptimizer`` (ZeRO)."""
+    """The GPT train step (AdamW with fp32 master weights). With ``mesh``:
+    parameters replicated over it and the update sharded over ``dp`` by
+    ``ShardedOptimizer`` (ZeRO)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -338,7 +339,8 @@ def leg_kernels(size):
             (rnd((b, s, size.hidden), bf), 1.0 + 0.1 * rnd((size.hidden,), f32),
              0.1 * rnd((size.hidden,), f32)), 3)
     compare(f"flash_sdpa causal [{b},{s},{h},{d}] bf16",
-            lambda q, k, v: _sdpa_flash.raw(q, k, v, causal=True),
+            lambda q, k, v: _sdpa_flash.raw(q, k, v, causal=True,
+                                            packed=True),
             lambda q, k, v: _sdpa_raw.raw(q, k, v, causal=True),
             tuple(rnd((b, s, h, d), bf) for _ in range(3)), 3)
     # the cached kernel as serving reaches it: one request's queries over

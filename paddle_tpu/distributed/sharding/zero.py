@@ -41,8 +41,8 @@ This module builds the full 2004.13336 update structure on that policy:
 Loss parity contract: exact (bitwise on the CI harness) for ZeRO alone —
 sharding constraints move data, never values; rtol-gated curve parity for
 ``quantize="int8"`` (the broadcast weights are quantized; error feedback
-bounds the drift). Both are gated in ``tools/run_tests.sh`` via
-``bench.py --dp 2 --zero --parity``.
+bounds the drift). Both are held by ``tests/test_zero_sharding.py`` on the
+virtual CPU mesh and, on four chips, by ``chip_smoke.py``'s dp4 leg.
 """
 from __future__ import annotations
 

@@ -86,15 +86,16 @@ def get_flags(flags):
 
 
 # ---------------------------------------------------------------------------
-# built-in flags (the subset of platform/flags.cc with a TPU meaning, plus
-# TPU-native knobs)
+# built-in flags: the subset of platform/flags.cc with a TPU meaning, the
+# ones accepted for reference compatibility, and two knobs of this framework
+# (matmul_precision, profiler_host_only). None picks a kernel: attention's
+# routes and block sizes are constants beside their measurements
+# (nn/functional/attention.py, ops/pallas/).
 # ---------------------------------------------------------------------------
 
 register_flag("check_nan_inf", False,
               help="scan op outputs for NaN/Inf in eager mode "
                    "(reference FLAGS_check_nan_inf, nan_inf_utils_detail.cc)")
-register_flag("disable_flash_attention", False,
-              help="route scaled_dot_product_attention to the XLA einsum path")
 register_flag("matmul_precision", "default", typ=str,
               jax_config="jax_default_matmul_precision",
               help="default/high/highest — TPU matmul precision "
@@ -115,31 +116,3 @@ register_flag("max_inplace_grad_add", 0,
               help="accepted for reference compat")
 register_flag("profiler_host_only", False,
               help="paddle.profiler: skip the XPlane device capture")
-register_flag("flash_attention_block_q", 0,
-              help="override Pallas flash attention q block (0 = auto)")
-register_flag("flash_attention_block_k", 0,
-              help="override Pallas flash attention k block (0 = auto)")
-register_flag("flash_attention_bwd_block", 0,
-              help="override packed flash attention backward block (0 = auto)")
-register_flag("enable_flash_ce", False,
-              help="route fused_linear_cross_entropy through the Pallas "
-                   "flash-CE kernels on TPU (default: XLA scan — measured "
-                   "faster fwd+bwd on v5e; see ops/fused.py _use_pallas)")
-register_flag("flash_attention_min_seq_prod", 1024 * 1024,
-              help="route sdpa to XLA einsum below this sq*sk; at 1024^2 and "
-                   "above the Pallas kernel with 1024-blocks measures faster "
-                   "than the einsum path on v5e")
-register_flag("disable_blockwise_attention", False,
-              help="route length-masked/long-causal sdpa to the dense "
-                   "einsum path (debugging / parity bisection)")
-register_flag("blockwise_attention_min_kv", 1024,
-              help="KV length at/above which sdpa takes the blockwise "
-                   "online-softmax scan (cached serving paths and causal "
-                   "training without Pallas); below it the fused einsum "
-                   "wins and its score matrix is small anyway")
-register_flag("blockwise_attention_block_q", 512,
-              help="query block for the blockwise-attention backward scan "
-                   "(largest divisor of seq_q <= this is used)")
-register_flag("blockwise_attention_block_k", 512,
-              help="KV block for the blockwise-attention scan (largest "
-                   "divisor of seq_k <= this is used)")

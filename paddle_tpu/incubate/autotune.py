@@ -1,15 +1,14 @@
 """paddle.incubate.autotune (reference ``python/paddle/incubate/autotune.py``
 ``set_config`` driving kernel/layout/dataloader autotuning).
 
-TPU-native: kernel selection and layout are XLA's job (its autotuner runs at
-compile time), so ``set_config`` maps the reference's knobs onto the flags
-registry — kernel.enable toggles the measured flash-attention block
-defaults, dataloader.use_autotune tunes DataLoader worker counts."""
+TPU-native: kernel selection is XLA's job (its autotuner runs at compile
+time) and attention's routes are chosen from shape and platform
+(``nn.functional.attention.attention_route``), so ``kernel.enable`` is
+accepted and recorded in the status and changes nothing; ``layout.enable``
+switches the layout autotuner."""
 from __future__ import annotations
 
 import json
-
-from ..framework.flags import flag_value, set_flags
 
 __all__ = ["set_config"]
 
@@ -27,7 +26,6 @@ def set_config(config=None):
         _STATUS["kernel"]["enable"] = True
         _STATUS["layout"]["enable"] = False
         _STATUS["dataloader"]["enable"] = False
-        set_flags({"disable_flash_attention": False})
         enable_layout_autotune(False)
         return
     if isinstance(config, str):
@@ -40,11 +38,6 @@ def set_config(config=None):
             raise ValueError(f"unknown autotune section {key!r}")
         section = config[key] or {}
         _STATUS[key].update(section)
-    if _STATUS["kernel"].get("enable") is False:
-        # "no tuned kernels": route attention off the measured Pallas path
-        set_flags({"disable_flash_attention": True})
-    elif "kernel" in config:
-        set_flags({"disable_flash_attention": False})
     if "layout" in config:
         from ..framework.layout_autotune import enable_layout_autotune
 

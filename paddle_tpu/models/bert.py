@@ -2,7 +2,7 @@
 
 Reference scale target: the BERT configs the reference's fleet/AMP stack
 trains (``python/paddle/fluid/tests/unittests/test_bert*`` and the
-BERT-large tokens/sec/chip metric in BASELINE.md). Encoder built from the
+BERT-large tokens/sec/chip metric of its benchmarks). Encoder built from the
 framework's TransformerEncoder; the MLM head reuses the fused
 linear+cross-entropy op so the ``[tokens, vocab]`` logits never materialize
 (ops/fused.py), same as the GPT flagship.

@@ -14,7 +14,7 @@ TPU-native design:
   * PP: ``build_pipelined_gpt`` (meta_parallel.pipeline_schedule) runs the
     decoder stack as a jitted SPMD 1F1B pipeline over the ``pp`` axis.
   * Long context: causal sdpa uses the Pallas flash-attention kernel when
-    available; past ``blockwise_attention_min_kv`` keys the fallback is
+    available; from ``BLOCKWISE_MIN_KV`` keys up the fallback is
     the blockwise online-softmax KV scan (``functional.attention``,
     ISSUE 15) — O(s·d) live bytes, never the O(s²) einsum score matrix —
     and short sequences keep the fused-einsum XLA path. The serving tier

@@ -1,24 +1,27 @@
 """Attention functionals.
 
 Reference fused kernels: ``paddle/fluid/operators/fused/fused_attention_op.cu``
-and ``fmha_ref.h``. TPU-native path: the Pallas flash-attention kernel
-(``paddle_tpu.ops.pallas.flash_attention``) whenever shapes tile onto the MXU
-and no attention dropout is requested; an XLA einsum path otherwise.
+and ``fmha_ref.h``. Seven routes, chosen in ONE place, :func:`attention_route`,
+from what the call can observe (shapes, the mask, dropout, the platform);
+nothing a user sets picks a kernel:
 
-Long context adds a third path: a blockwise online-softmax ``lax.scan`` over
-KV blocks (``_sdpa_blockwise``) that keeps the live logits at
-O(seq·block) instead of O(seq²) on every backend, selected for causal
-training above ``blockwise_attention_min_kv`` keys and for cached
-(:class:`LengthMask`) serving calls — prefill, chunked prefill, decode and
-speculative verify never materialize ``[b, h, q, max_len]`` scores. On the
-TPU the cached calls go to Pallas instead (``_route_length_masked``): the
-decode-shaped kernel for decode and verify, the length-masked flash kernel
-for 128-aligned query blocks; the scan is what XLA:CPU runs, and what takes
-a query shape neither kernel does.
+* ``flash_packed`` / ``flash``: the Pallas flash-attention kernels
+  (``paddle_tpu.ops.pallas.flash_attention_packed`` seq-major with no layout
+  change, ``flash_attention`` layout-swapping) whenever shapes tile onto the
+  MXU, at ``FLASH_MIN_SEQ_PROD`` and above;
+* ``flash_cached`` / ``flash_decode``: cached (:class:`LengthMask`) serving
+  calls on the TPU, the length-masked flash kernel for 128-aligned query
+  blocks and the decode-shaped kernel for decode and verify;
+* ``einsum_grouped``: a few query rows over a cache with grouped K/V heads;
+* ``blockwise``: an online-softmax ``lax.scan`` over KV blocks
+  (``_sdpa_blockwise``) that keeps the live logits at O(seq·block) instead of
+  O(seq²) on every backend, from ``BLOCKWISE_MIN_KV`` keys up, for causal
+  training and cached calls. It is what XLA:CPU runs, and what takes a cached
+  query shape neither kernel does;
+* ``einsum``: the XLA einsum path for everything else.
 
-Routing is an EXPLICIT capability check (``_flash_ok`` /
-``_blockwise_ok``), never a silent ``except`` fallback: if a kernel is
-selected and fails, the error propagates.
+Routing is an EXPLICIT capability check, never a silent ``except`` fallback:
+if a kernel is selected and fails, the error propagates.
 """
 from __future__ import annotations
 
@@ -237,27 +240,6 @@ def _sdpa_blockwise(q, k, v, q_pos, kv_len=None, scale=None, block_q=0,
     return _blockwise(q, k, v, q_pos, kv_len, s, block_q, block_k)
 
 
-def _blockwise_ok(q_shape, k_shape, dropout_p, training):
-    """Blockwise path: no attention dropout (the scan has no in-kernel PRNG)
-    and at least ``blockwise_attention_min_kv`` key slots — below that the
-    fused einsum is faster and its score matrix is small anyway."""
-    from ...framework.flags import flag_value
-
-    if flag_value("disable_blockwise_attention"):
-        return False
-    if dropout_p > 0.0 and training:
-        return False
-    return k_shape[1] >= flag_value("blockwise_attention_min_kv")
-
-
-def _blockwise_blocks(sq, sk):
-    from ...framework.flags import flag_value
-
-    bq = _pick_block(sq, flag_value("blockwise_attention_block_q") or 512)
-    bk = _pick_block(sk, flag_value("blockwise_attention_block_k") or 512)
-    return bq, bk
-
-
 #: query rows of a decode-shaped call (decode 1; verify spec_k + 1): the
 #: calls whose route ``attn.decode_route`` counts
 DECODE_ROWS = 8
@@ -302,117 +284,115 @@ def _sdpa_grouped_decode(q, k, v, q_pos, kv_len=None, scale=None):
     return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
-def _route_length_masked(query, key, value, lm, dropout_p, training, scale):
-    """Cached-attention routing. With Pallas: the decode-shaped kernel for a
-    few query rows (decode, verify), the length-masked flash kernel for
-    128-aligned query blocks (long prefill buckets, prefill chunks). The
-    blockwise scan is what is left: every backend without Pallas (XLA:CPU,
-    tier-1) and query shapes neither kernel takes. Below the min-kv
-    threshold (or under attention dropout): dense on-the-fly mask."""
-    b, sq, h, d = query.shape
-    sk = key.shape[1]
-    active_p = dropout_p if training else 0.0
-    if key.shape[2] != h:
-        if sq <= DECODE_ROWS and active_p == 0.0:
-            _count_decode_route("einsum_grouped")
-            return _sdpa_grouped_decode(query, key, value, lm.q_pos,
-                                        lm.kv_len, scale=scale)
-        # a prefill bucket holds its own keys and values only: repeating
-        # them per query head is a few MB, and every route below takes it
-        key, value = _repeat_kv_heads(key, value, h)
-    if _blockwise_ok(query.shape, key.shape, dropout_p, training):
-        from ...ops import pallas
-
-        s = scale if scale is not None else 1.0 / math.sqrt(d)
-        if pallas.is_available():
-            from ...ops.pallas.flash_attention import supports_cached
-            from ...ops.pallas.flash_decode import supports_decode
-
-            if supports_decode(sq, sk, h, d, key.dtype.itemsize):
-                _count_decode_route("flash_decode")
-                return _sdpa_flash_decode(query, key, value, lm.q_pos,
-                                          lm.kv_len, scale=s)
-            if supports_cached(sq, sk, d):
-                return _sdpa_flash_cached(query, key, value, lm.q_pos,
-                                          lm.kv_len, scale=s)
-        if sq <= DECODE_ROWS:
-            _count_decode_route("blockwise")
-        bq, bk = _blockwise_blocks(sq, sk)
-        return _sdpa_blockwise(query, key, value, lm.q_pos, lm.kv_len,
-                               scale=s, block_q=bq, block_k=bk)
-    if sq <= DECODE_ROWS:
-        _count_decode_route("einsum")
-    mask = lm.additive(sk, query.dtype)
-    dropout_mask = None
-    if active_p > 0.0:
-        dropout_mask = jax.random.bernoulli(
-            rnd.next_key(), 1.0 - active_p, (b, h, sq, sk))
-    return _sdpa_raw(query, key, value, mask, dropout_mask, causal=False,
-                     scale=scale, dropout_p=active_p)
+# Thresholds the routes turn on, each beside where it was measured. Constants,
+# not options: a threshold that moves, moves a cell (ROADMAP W7 asks for a
+# cell on the other side of each).
+#
+# Measured crossover on the v5e (GPT-2 124M, d=64): below sq*sk = 1024^2 XLA's
+# fused einsum attention wins; at 1024^2+ the Pallas kernel with 1024-wide
+# blocks is faster (s=1024 end-to-end: 102.6k vs 88.0k tok/s) and keeps memory
+# flat at long context.
+FLASH_MIN_SEQ_PROD = 1024 * 1024
+# Key slots from which a call takes the blockwise scan (or, on the TPU, the
+# cached kernels that stand before it): below it the fused einsum is faster
+# and its score matrix is small anyway. Set with the scan (PR 15), on XLA:CPU;
+# never fitted on the chip.
+BLOCKWISE_MIN_KV = 1024
+# Blocks of the blockwise scan (the largest divisor of the sequence at or
+# under each is used); PR 15's long-context runs, XLA:CPU.
+BLOCKWISE_BLOCK_Q = 512
+BLOCKWISE_BLOCK_K = 512
 
 
-def _flash_ok(q_shape, k_shape, mask, dropout_p, training, mask_trainable=False):
-    """Pallas flash path: TPU (or interpret-mode) backend, MXU-tileable
-    sequence lengths, and — when a mask is given — a mask the kernel streams
-    exactly: trailing dims ``(sq, sk)`` with broadcastable batch/head dims.
-    Trainable biases are supported: the fused backward computes the real
-    dS-sum bias gradient (XLA-DCE'd when unused). Attention dropout runs
-    in-kernel via the TPU hardware PRNG — compiled-TPU only (no interpret
-    lowering) and incompatible with a trainable bias (the XLA dbias
-    recompute cannot regenerate the in-kernel mask)."""
-    from ...framework.flags import flag_value
-    from ...ops import pallas
+def attention_route(*, batch, sq, sk, heads, kv_heads, head_dim, kv_itemsize,
+                    cached, causal, mask_shape, mask_trainable, dropout,
+                    pallas, interpret):
+    """The one place a route is chosen. Shape facts and the two things the
+    platform tells (``pallas.is_available()``, ``pallas.interpret_requested()``)
+    come in as arguments and no global state is read, so a test can ask what
+    the chip compiles. ``cached`` says the mask is a :class:`LengthMask` (and
+    ``mask_shape`` is None); ``dropout`` that attention dropout is active.
 
-    if flag_value("disable_flash_attention"):
-        return False
-    if dropout_p > 0.0 and training:
-        if pallas.interpret_requested() or mask_trainable:
-            return False
-    sq, sk = q_shape[1], k_shape[1]
-    # Routing by measured crossover (v5e): below sq*sk = 1024^2 XLA's fused
-    # einsum attention wins; at 1024^2+ the Pallas kernel with 1024-wide
-    # blocks is faster (GPT-2 s=1024 end-to-end: 102.6k vs 88.0k tok/s) and
-    # keeps memory flat at long context.
-    if sq * sk < flag_value("flash_attention_min_seq_prod") and not pallas.interpret_requested():
-        return False
-    if mask is not None:
-        ms = tuple(mask.shape)
+    Returns one of ``flash_packed``, ``flash``, ``flash_cached``,
+    ``flash_decode``, ``einsum_grouped``, ``blockwise``, ``einsum``."""
+    from ...ops.pallas import flash_attention_packed as packed
+    from ...ops.pallas.flash_attention import supports, supports_cached
+    from ...ops.pallas.flash_decode import supports_decode
+
+    # neither the scan nor the cached kernels have an in-kernel PRNG
+    long_kv = not dropout and sk >= BLOCKWISE_MIN_KV
+    if cached:
+        # With Pallas: the decode-shaped kernel for a few query rows (decode,
+        # verify), the length-masked flash kernel for 128-aligned query
+        # blocks (long prefill buckets, prefill chunks). The blockwise scan
+        # is what is left: every backend without Pallas (XLA:CPU, tier-1) and
+        # query shapes neither kernel takes. Below the threshold (or under
+        # attention dropout): dense on-the-fly mask.
+        if kv_heads != heads and sq <= DECODE_ROWS and not dropout:
+            return "einsum_grouped"
+        # every route below takes grouped K/V heads repeated per query head
+        if not long_kv:
+            return "einsum"
+        if pallas and supports_decode(sq, sk, heads, head_dim, kv_itemsize):
+            return "flash_decode"
+        if pallas and supports_cached(sq, sk, head_dim):
+            return "flash_cached"
+        return "blockwise"
+    # The flash kernels: TPU (or interpret-mode) backend, MXU-tileable
+    # sequence lengths and, when a mask is given, a mask the kernel streams
+    # exactly: trailing dims (sq, sk) with broadcastable batch/head dims.
+    # Trainable biases are supported: the fused backward computes the real
+    # dS-sum bias gradient (XLA-DCE'd when unused). Attention dropout runs
+    # in-kernel via the TPU hardware PRNG: compiled-TPU only (no interpret
+    # lowering) and incompatible with a trainable bias (the XLA dbias
+    # recompute cannot regenerate the in-kernel mask).
+    flash = not (dropout and (interpret or mask_trainable))
+    if sq * sk < FLASH_MIN_SEQ_PROD and not interpret:
+        flash = False
+    if mask_shape is not None:
+        ms = tuple(mask_shape)
         if len(ms) == 4:
-            if ms[2:] != (sq, sk):
-                return False
-            if ms[0] not in (1, q_shape[0]) or ms[1] not in (1, q_shape[2]):
-                return False
+            if (ms[2:] != (sq, sk) or ms[0] not in (1, batch)
+                    or ms[1] not in (1, heads)):
+                flash = False
         elif ms != (sq, sk):
-            return False
-    if not pallas.is_available():
-        return False
-    from ...ops.pallas.flash_attention import supports
-
-    return supports(sq, sk, q_shape[3])
+            flash = False
+    if flash and pallas and supports(sq, sk, head_dim):
+        # the seq-major packed kernel (zero layout transposes: the
+        # (b,s,h,d)->(b,s,h*d) reshape is free) whenever the head dim packs
+        # into 128-lane groups and the mask is shared-2-D/absent;
+        # per-batch/per-head or trainable biases take the layout-swapping one
+        shared_mask = mask_shape is None or (len(ms) == 2
+                                             and not mask_trainable)
+        if shared_mask and packed.supports(sq, sk, heads, heads * head_dim):
+            return "flash_packed"
+        return "flash"
+    if mask_shape is None and causal and long_kv:
+        # long causal training without Pallas (e.g. XLA:CPU): blockwise scan
+        # instead of the O(seq²) einsum score matrix
+        return "blockwise"
+    return "einsum"
 
 
 @op("flash_sdpa")
 def _sdpa_flash(q, k, v, mask=None, dropout_seed=None, causal=False,
-                scale=None, mask_trainable=False, dropout_p=0.0):
-    """q,k,v: (batch, seq, heads, head_dim) — paddle layout.
+                scale=None, mask_trainable=False, dropout_p=0.0,
+                packed=False):
+    """q,k,v: (batch, seq, heads, head_dim) — paddle layout. ``packed``: the
+    seq-major packed kernel (route ``flash_packed``) in place of the
+    layout-swapping one (route ``flash``)."""
+    if packed:
+        from ...ops.pallas.flash_attention_packed import flash_attention_packed
 
-    Prefers the seq-major packed kernel (zero layout transposes — the
-    (b,s,h,d)->(b,s,h*d) reshape is free) whenever the head dim packs into
-    128-lane groups and the mask is shared-2-D/absent; per-batch/per-head
-    or trainable biases take the layout-swapping kernel."""
-    from ...ops.pallas import flash_attention_packed as packed
-    from ...ops.pallas.flash_attention import flash_attention as fa
-
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    mask_2d = mask is not None and mask.ndim == 2
-    if ((mask is None or (mask_2d and not mask_trainable))
-            and packed.supports(sq, sk, h, h * d)):
-        out = packed.flash_attention_packed(
+        b, sq, h, d = q.shape
+        sk = k.shape[1]
+        out = flash_attention_packed(
             q.reshape(b, sq, h * d), k.reshape(b, sk, h * d),
             v.reshape(b, sk, h * d), h, bias=mask, causal=causal,
             scale=scale, dropout_p=dropout_p, dropout_seed=dropout_seed)
         return out.reshape(b, sq, h, d)
+    from ...ops.pallas.flash_attention import flash_attention as fa
+
     return fa(q, k, v, bias=mask, causal=causal, scale=scale,
               bias_grad=mask_trainable,
               dropout_p=dropout_p, dropout_seed=dropout_seed)
@@ -471,16 +451,29 @@ def _sdpa_raw(q, k, v, mask=None, dropout_mask=None, causal=False, scale=None,
 
 def _sdpa(query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
           training=True, scale=None):
-    if isinstance(attn_mask, LengthMask):
-        return _route_length_masked(query, key, value, attn_mask, dropout_p,
-                                    training, scale)
-    if key.shape[2] != query.shape[2]:  # grouped K/V heads, no cache
-        key, value = _repeat_kv_heads(key, value, query.shape[2])
-    trainable = (attn_mask is not None
+    from ...ops import pallas
+
+    b, sq, h, d = query.shape
+    sk = key.shape[1]
+    cached = isinstance(attn_mask, LengthMask)
+    trainable = (not cached and attn_mask is not None
                  and getattr(attn_mask, "stop_gradient", True) is False)
-    if _flash_ok(query.shape, key.shape, attn_mask, dropout_p, training,
-                 trainable):
-        active_p = dropout_p if training else 0.0
+    active_p = dropout_p if training else 0.0
+    route = attention_route(
+        batch=b, sq=sq, sk=sk, heads=h, kv_heads=key.shape[2], head_dim=d,
+        kv_itemsize=key.dtype.itemsize, cached=cached, causal=is_causal,
+        mask_shape=(None if cached or attn_mask is None
+                    else tuple(attn_mask.shape)),
+        mask_trainable=trainable, dropout=active_p > 0.0,
+        pallas=pallas.is_available(), interpret=pallas.interpret_requested())
+    if cached and sq <= DECODE_ROWS:
+        _count_decode_route(route)
+    if route == "einsum_grouped":
+        return _sdpa_grouped_decode(query, key, value, attn_mask.q_pos,
+                                    attn_mask.kv_len, scale=scale)
+    if key.shape[2] != h:
+        key, value = _repeat_kv_heads(key, value, h)
+    if route in ("flash_packed", "flash"):
         seed = None
         if active_p > 0.0:
             # two 32-bit words of a fresh key seed the in-kernel PRNG
@@ -489,28 +482,33 @@ def _sdpa(query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
             )
         return _sdpa_flash(query, key, value, attn_mask, seed,
                            causal=is_causal, scale=scale,
-                           mask_trainable=trainable, dropout_p=active_p)
-    if (attn_mask is None and is_causal
-            and _blockwise_ok(query.shape, key.shape, dropout_p, training)):
-        # long causal training without Pallas (e.g. XLA:CPU): blockwise scan
-        # instead of the O(seq²) einsum score matrix
-        b, sq, _, d = query.shape
-        sk = key.shape[1]
-        s = scale if scale is not None else 1.0 / math.sqrt(d)
+                           mask_trainable=trainable, dropout_p=active_p,
+                           packed=route == "flash_packed")
+    if route == "einsum":
+        mask = attn_mask.additive(sk, query.dtype) if cached else attn_mask
+        dropout_mask = None
+        if active_p > 0.0:
+            dropout_mask = jax.random.bernoulli(
+                rnd.next_key(), 1.0 - active_p, (b, h, sq, sk))
+        return _sdpa_raw(query, key, value, mask, dropout_mask,
+                         causal=is_causal and not cached, scale=scale,
+                         dropout_p=active_p)
+    s = scale if scale is not None else 1.0 / math.sqrt(d)
+    if cached:
+        q_pos, kv_len = attn_mask.q_pos, attn_mask.kv_len
+    else:  # causal, bottom-right aligned
         q_pos = jnp.broadcast_to(
             jnp.arange(sk - sq, sk, dtype=jnp.int32)[None, :], (b, sq))
-        bq, bk = _blockwise_blocks(sq, sk)
-        return _sdpa_blockwise(query, key, value, q_pos, None, scale=s,
-                               block_q=bq, block_k=bk)
-    dropout_mask = None
-    if dropout_p > 0.0 and training:
-        b, sq, h, _ = query.shape
-        sk = key.shape[1]
-        dropout_mask = jax.random.bernoulli(
-            rnd.next_key(), 1.0 - dropout_p, (b, h, sq, sk)
-        )
-    return _sdpa_raw(query, key, value, attn_mask, dropout_mask,
-                     causal=is_causal, scale=scale, dropout_p=dropout_p)
+        kv_len = None
+    if route == "flash_decode":
+        return _sdpa_flash_decode(query, key, value, q_pos, kv_len, scale=s)
+    if route == "flash_cached":
+        return _sdpa_flash_cached(query, key, value, q_pos, kv_len, scale=s)
+    if route == "blockwise":
+        return _sdpa_blockwise(query, key, value, q_pos, kv_len, scale=s,
+                               block_q=_pick_block(sq, BLOCKWISE_BLOCK_Q),
+                               block_k=_pick_block(sk, BLOCKWISE_BLOCK_K))
+    raise ValueError(f"attention_route returned {route!r}")
 
 
 def scaled_dot_product_attention(

@@ -10,6 +10,12 @@ temporary; the MXU does the matmuls, fp32 statistics ride in registers.
 
 For GPT-2 124M at b16xs1024 the un-fused path writes+reads a 3.3 GB fp32
 logits tensor twice per step; this op removes all of that traffic.
+
+Measured on v5e (GPT-2 124M, b16 s1024, V=50304): the op is VPU-EXP-BOUND:
+~824M f32 exps/step set a ~8-9 ms floor that no implementation can dodge. A
+Pallas version edged this scan forward (14.5 vs 15.7 ms, blocks 1024x1024) and
+lost forward + backward (41 vs 37 ms): its backward recomputed the logits in
+BOTH the dx and the dW kernel. It is gone; a better one needs a new backward.
 """
 from __future__ import annotations
 
@@ -24,37 +30,6 @@ from .dispatch import op
 __all__ = ["fused_linear_cross_entropy"]
 
 
-# test/bench override for chunk-size sweeps (None = auto)
-_FORCE_CHUNK = None
-# test override: None = auto (Pallas on TPU/interpret), False = XLA scan
-_FORCE_PALLAS = None
-
-
-def _use_pallas(tokens, vocab, hidden):
-    """Pallas flash-CE path gate.
-
-    Measured on v5e (GPT-2 124M, b16 s1024, V=50304): fused CE is
-    VPU-EXP-BOUND — ~824M f32 exps/step set a ~8-9 ms floor that neither
-    implementation can dodge. The Pallas forward edges the XLA scan (14.5
-    vs 15.7 ms, blocks 1024x1024) but its backward recomputes the logits
-    in BOTH the dx and dW kernels, losing fwd+bwd overall (41 vs 37 ms) —
-    so the scan stays the default on hardware and the kernel is opt-in
-    via FLAGS_enable_flash_ce (and the default under interpret mode,
-    which keeps it correctness-tested)."""
-    if _FORCE_PALLAS is not None:
-        return _FORCE_PALLAS
-    from . import pallas
-    from .pallas import fused_ce
-
-    if not pallas.is_available() or not fused_ce.supports(hidden):
-        return False
-    if pallas.interpret_requested():
-        return True
-    from ..framework.flags import flag_value
-
-    return bool(flag_value("enable_flash_ce"))
-
-
 def _pick_chunk(tokens: int) -> int:
     # largest power-of-two chunk <= 2048 dividing the padded token count.
     # Swept on v5e (GPT-2 124M, V=50304, 16k tokens): ISOLATED fwd+bwd
@@ -62,8 +37,6 @@ def _pick_chunk(tokens: int) -> int:
     # trips), but END-TO-END the larger transient logits block loses
     # ~4.5k tok/s to HBM pressure against the resident model state —
     # 2048 (~400 MB transient) is the full-step optimum.
-    if _FORCE_CHUNK:
-        return min(_FORCE_CHUNK, tokens)
     for c in (2048, 1024, 512, 256, 128):
         if tokens >= c:
             return c
@@ -89,17 +62,6 @@ def _flce_fwd(h, w, b, labels, ignore_index, chunk):
     chunk = chunk or _pick_chunk(tokens)
     y = labels.astype(jnp.int32)
     safe = jnp.where(y == ignore_index, 0, y)
-    vocab = w.shape[0]
-
-    if _use_pallas(tokens, vocab, h.shape[-1]):
-        from .pallas import fused_ce, interpret_requested
-
-        losses, lse = fused_ce.ce_forward(
-            h, w, None if b.ndim == 0 else b, safe,
-            interpret=interpret_requested())
-        losses = jnp.where(y == ignore_index, 0.0, losses)
-        return losses, (h, w, b, safe, y == ignore_index, lse)
-
     h_b = _chunked(h, chunk)
 
     def body(_, h_c):
@@ -129,19 +91,6 @@ def _flce_bwd(ignore_index, chunk, res, g):
     tokens = h.shape[0]
     chunk = chunk or _pick_chunk(tokens)
     g = jnp.where(ignored, 0.0, g.astype(jnp.float32))
-
-    # branch on the residual itself: the Pallas forward saves a flat
-    # (tokens,) lse, the scan forward a chunked 2-D one — intrinsic to the
-    # residuals, immune to any gate flip between fwd and bwd tracing
-    if lse_b.ndim == 1:
-        from .pallas import fused_ce, interpret_requested
-
-        dh, dw, db = fused_ce.ce_backward(
-            h, w, None if b.ndim == 0 else b, safe, g, lse_b,
-            interpret=interpret_requested())
-        db_out = (jnp.zeros((), jnp.float32) if b.ndim == 0
-                  else db.astype(b.dtype))
-        return dh, dw.astype(w.dtype), db_out, None
 
     h_b = _chunked(h, chunk)
     y_b = _chunked(safe, chunk)

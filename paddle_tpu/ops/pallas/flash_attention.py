@@ -49,8 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 # Measured on v5e (GPT-2 shapes, d=64): 1024x1024 tiles are ~2x faster than
 # 512x512 and ~9x faster than 256x256 at s=4096 (fwd+bwd), and beat XLA's
 # fused einsum attention at s=1024 (102.6k vs 88.0k tok/s end-to-end GPT
-# training). Bigger tiles exceed VMEM. Override via
-# FLAGS_flash_attention_block_{q,k}.
+# training). Bigger tiles exceed VMEM. The entry points take other block
+# sizes as arguments; nothing global overrides them.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 LANES = 128
@@ -686,14 +686,11 @@ def flash_attention_cached(q, k, v, q_pos, kv_len=None, *, scale=None,
     Forward-only: serving's prefill / chunked-prefill / speculative-verify
     steps. Returns ``(batch, seq_q, heads, head_dim)``.
     """
-    from ...framework.flags import flag_value
     from ..partition import batch_sharded
     from . import interpret_requested
 
     if interpret is None:
         interpret = interpret_requested()
-    block_q = flag_value("flash_attention_block_q") or block_q
-    block_k = flag_value("flash_attention_block_k") or block_k
     b, sq, h, d = q.shape
     sk = k.shape[1]
     block_q = _pick_block(sq, block_q)
@@ -748,7 +745,6 @@ def flash_attention(q, k, v, bias=None, *, causal=False, scale=None,
 
     Returns ``(batch, seq_q, heads, head_dim)``.
     """
-    from ...framework.flags import flag_value
     from ..partition import batch_sharded
     from . import interpret_requested
 
@@ -778,8 +774,6 @@ def flash_attention(q, k, v, bias=None, *, causal=False, scale=None,
         seed = jnp.asarray(dropout_seed, jnp.int32).reshape(2)
     else:
         seed = None
-    block_q = flag_value("flash_attention_block_q") or block_q
-    block_k = flag_value("flash_attention_block_k") or block_k
     b, sq, h, d = q.shape
     sk = k.shape[1]
     block_q = _pick_block(sq, block_q)

@@ -556,7 +556,6 @@ def flash_attention_packed(q, k, v, num_heads, bias=None, *, causal=False,
     (constant — no bias gradient path); use :func:`flash_attention` for
     per-batch/per-head biases.
     """
-    from ...framework.flags import flag_value
     from ..partition import batch_sharded
     from . import interpret_requested
 
@@ -583,8 +582,6 @@ def flash_attention_packed(q, k, v, num_heads, bias=None, *, causal=False,
     else:
         seed = None
     if (block_q == DEFAULT_BLOCK_Q and block_k == DEFAULT_BLOCK_K
-            and not flag_value("flash_attention_block_q")
-            and not flag_value("flash_attention_block_k")
             and sq == sk and sq > 1024):
         if sq <= 4096:
             # measured v5e routing (GPT-2 cfg): at mid sequence lengths the
@@ -600,9 +597,6 @@ def flash_attention_packed(q, k, v, num_heads, bias=None, *, causal=False,
             # online-softmax rescale rounds per q row than 1024x1024
             # (s=8192 b4: 61.4k vs 60.1k tok/s, 51.3% vs 50.3% MFU)
             block_q, block_k = 512, 2048
-    block_q = flag_value("flash_attention_block_q") or block_q
-    block_k = flag_value("flash_attention_block_k") or block_k
-    bwd_block = flag_value("flash_attention_bwd_block") or bwd_block
     block_q = _pick_block(sq, block_q)
     block_k = _pick_block(sk, block_k)
     bwd_block = _pick_block(sq, bwd_block) or block_q

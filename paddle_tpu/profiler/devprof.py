@@ -13,8 +13,8 @@ Pieces:
 
 * :func:`normalize_cost_analysis` — one shared shim over jax's unstable
   ``cost_analysis()`` return shape (newer jax: a list of per-computation
-  dicts; older: a dict; unavailable: ``None``) used by ``cost_model``,
-  ``tools/bench_common`` and this module.
+  dicts; older: a dict; unavailable: ``None``) used by ``cost_model``
+  and this module.
 * :class:`MemoryBreakdown` — the HBM peak decomposition from
   ``memory_analysis()`` (``peak = argument + output + temp +
   generated_code − alias``; the alias term is the donated input bytes the
@@ -88,7 +88,7 @@ OOM_DUMP_ENV = "PADDLE_TPU_OOM_DUMP"
 
 
 # ---------------------------------------------------------------------------
-# cost_analysis normalization (shared with cost_model / tools/bench_common)
+# cost_analysis normalization (shared with cost_model)
 # ---------------------------------------------------------------------------
 
 def normalize_cost_analysis(ca):

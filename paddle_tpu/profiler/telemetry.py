@@ -63,8 +63,7 @@ through a ``utils.log_writer.LogWriter`` (rendered by
 the :class:`~paddle_tpu.profiler.profiler.Profiler` merges into its
 ``ProfilerResult`` chrome trace, and :func:`report` prints the summary
 table. ``hapi.callbacks.TelemetryLogger`` wires all of this into
-``Model.fit``; ``tools/bench_common.telemetry_block`` embeds the summary
-into the BENCH json.
+``Model.fit``.
 """
 from __future__ import annotations
 

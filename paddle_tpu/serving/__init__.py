@@ -7,7 +7,7 @@ plus a slot-based continuous-batching scheduler with a resilience layer
 every request ends with exactly one terminal ``finish_reason`` from
 ``FINISH_REASONS``). See ``kv_cache.py`` for the cache/compiler contract,
 ``engine.py`` for the prefill/decode split, ``scheduler.py`` for request
-scheduling and the failure story, ``tools/bench_serve.py`` for the
+scheduling and the failure story, ``benchmark/drivers/serve.py`` for the
 throughput/latency benchmark and ``tools/chaos_serve.py`` for the
 deterministic chaos harness.
 """

@@ -76,8 +76,7 @@ counters, the resilience counters ``serve.shed`` / ``serve.timeouts`` /
 ``serve.spec_ticks`` / ``serve.spec_proposed`` / ``serve.spec_accepted``
 / ``serve.spec_fallback_ticks`` plus the ``serve.spec_acceptance_rate``
 gauge (running accepted/proposed), and per-request ``serve.ttft_s`` /
-``serve.tpot_s`` / ``serve.latency_s`` histograms —
-``tools/bench_serve.py`` summarizes them into the SERVE json.
+``serve.tpot_s`` / ``serve.latency_s`` histograms.
 
 Where a tick's time goes (telemetry on): every ``step()`` leaves one tick
 record in telemetry's ring (``kind "serve.tick"``, ``index`` = this

@@ -22,23 +22,31 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
-from paddle_tpu.framework.flags import get_flags, set_flags
 from paddle_tpu.framework.tensor import Tensor
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.nn.functional import LengthMask
+from paddle_tpu.nn.functional import attention as A
 from paddle_tpu.profiler import telemetry
 from paddle_tpu.serving import GenerationEngine
 from paddle_tpu.utils import unique_name
 
-_FLAG_NAMES = ["disable_blockwise_attention", "blockwise_attention_min_kv",
-               "blockwise_attention_block_q", "blockwise_attention_block_k"]
+_CONSTANTS = ["BLOCKWISE_MIN_KV", "BLOCKWISE_BLOCK_Q", "BLOCKWISE_BLOCK_K"]
+
+
+def _set(**constants):
+    """Move the route's thresholds for a toy size: the module constants are
+    what ``attention_route`` and ``_sdpa`` read, and nothing else sets
+    them."""
+    for name, value in constants.items():
+        assert name in _CONSTANTS, name
+        setattr(A, name, value)
 
 
 @pytest.fixture(autouse=True)
-def _restore_flags():
-    saved = get_flags(_FLAG_NAMES)
+def _restore_constants():
+    saved = {name: getattr(A, name) for name in _CONSTANTS}
     yield
-    set_flags(saved)
+    _set(**saved)
 
 
 @pytest.fixture
@@ -69,9 +77,9 @@ def _sdpa_lm(q, k, v, lm):
 
 def _both_paths(q, k, v, lm):
     """(dense einsum fallback, forced blockwise scan) on the same mask."""
-    set_flags({"blockwise_attention_min_kv": 10 ** 9})
+    _set(BLOCKWISE_MIN_KV=10 ** 9)
     dense = _sdpa_lm(q, k, v, lm)
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     block = _sdpa_lm(q, k, v, lm)
     return dense, block
 
@@ -147,8 +155,7 @@ def test_parity_odd_lengths_pick_divisor_blocks():
     # sk = 24 with preferred block 512 -> block 24; with block_k=7 -> 6
     q, k, v = _qkv(1, 5, 24, seed=4)
     lm = LengthMask(np.full((1, 5), 23, np.int32), np.array([17], np.int32))
-    set_flags({"blockwise_attention_block_q": 7,
-               "blockwise_attention_block_k": 7})
+    _set(BLOCKWISE_BLOCK_Q=7, BLOCKWISE_BLOCK_K=7)
     dense, block = _both_paths(q, k, v, lm)
     np.testing.assert_allclose(block, dense, rtol=1e-5, atol=1e-5)
 
@@ -166,10 +173,9 @@ def test_blockwise_grads_match_einsum_causal_training():
         return (np.asarray(out._value),
                 [np.asarray(t.grad._value) for t in (tq, tk, tv)])
 
-    set_flags({"disable_blockwise_attention": True})
+    _set(BLOCKWISE_MIN_KV=10 ** 9)  # the einsum route
     ref_out, ref_g = run()
-    set_flags({"disable_blockwise_attention": False,
-               "blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     got_out, got_g = run()
     np.testing.assert_allclose(got_out, ref_out, rtol=1e-5, atol=1e-5)
     for g, r in zip(got_g, ref_g):
@@ -181,7 +187,7 @@ def test_fully_masked_rows_are_zero_not_nan():
     # slot 1 has an empty cache: every key invalid for every query row
     lm = LengthMask(np.tile(np.arange(4, dtype=np.int32), (2, 1)),
                     np.array([16, 0], np.int32))
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     out = _sdpa_lm(q, k, v, lm)
     assert np.isfinite(out).all()
     np.testing.assert_array_equal(out[1], np.zeros_like(out[1]))
@@ -212,7 +218,7 @@ def test_greedy_serving_byte_identical_with_blockwise_forced(
         return eng.generate(prompt, max_new_tokens=16)
 
     base = gen()
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     forced = gen()
     assert len(set(base)) > 2, "degenerate model; parity check is vacuous"
     assert forced == base
@@ -230,13 +236,13 @@ def test_chunked_prefill_byte_identical_with_blockwise_forced(
         return eng.generate(prompt, max_new_tokens=12)
 
     base = gen()
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     forced = gen()
     assert forced == base
 
 
 def test_decode_still_compiles_once_with_blockwise_forced():
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     model = _serve_model()
     telemetry.reset()
     telemetry.enable()
@@ -281,7 +287,7 @@ def test_length_masked_routing_by_platform_and_shape(interpret, sq, sk,
 
     from paddle_tpu.ops import pallas
 
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     q, k, v = _qkv(2, sq, sk, h=2, d=64)
     lm = LengthMask(np.tile(sk - sq + np.arange(sq, dtype=np.int32), (2, 1)))
     with pallas.interpret_mode() if interpret else contextlib.nullcontext():
@@ -291,8 +297,8 @@ def test_length_masked_routing_by_platform_and_shape(interpret, sq, sk,
 
 
 def test_short_caches_keep_the_dense_route_under_interpret():
-    """Below ``blockwise_attention_min_kv`` nothing changes route: the same
-    ``_blockwise_ok`` gate stands before both kernels."""
+    """Below ``BLOCKWISE_MIN_KV`` nothing changes route: the same threshold
+    stands before both kernels."""
     from paddle_tpu.ops import pallas
 
     q, k, v = _qkv(2, 1, 128, h=2, d=64)
@@ -323,7 +329,7 @@ def test_greedy_serving_byte_identical_scan_and_decode_kernel(
     op is the one its decode (or verify) step traced."""
     from paddle_tpu.ops import pallas
 
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     model = _kernel_model()
     rng = np.random.RandomState(11)
     # a periodic prompt: the n-gram proposer drafts from the first tick
@@ -351,7 +357,7 @@ def test_greedy_serving_byte_identical_scan_and_decode_kernel(
 def test_decode_still_compiles_once_through_the_decode_kernel():
     from paddle_tpu.ops import pallas
 
-    set_flags({"blockwise_attention_min_kv": 1})
+    _set(BLOCKWISE_MIN_KV=1)
     model = _kernel_model()
     telemetry.reset()
     telemetry.enable()

@@ -71,7 +71,7 @@ def _clean_registry():
 
 
 # ---------------------------------------------------------------------------
-# normalize_cost_analysis (shared shim: cost_model / bench_common / devprof)
+# normalize_cost_analysis (shared shim: cost_model / devprof)
 # ---------------------------------------------------------------------------
 
 def test_normalize_cost_analysis_shapes():
@@ -612,34 +612,8 @@ def test_export_scalars_includes_percentiles_and_device_gauges(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench + tools integration
+# tools integration
 # ---------------------------------------------------------------------------
-
-def test_telemetry_block_reports_device_keys():
-    from bench_common import measure_steps, telemetry_block
-
-    step, _, _ = _mlp_step()
-    rng = np.random.RandomState(0)
-    batches = [(rng.rand(8, 16).astype(np.float32),
-                rng.randint(0, 4, (8, 1)).astype(np.int64))
-               for _ in range(7)]
-    total, _ = measure_steps(step, batches, iters=4, warmup=2)
-    blk = telemetry_block(total, 4)
-    assert blk["hbm_peak_bytes"] > 0
-    assert blk["comm_fraction"] == 0.0  # single device
-    assert blk["comm_bytes_by_axis"] == {}
-    assert blk["compile_count"] >= 1
-
-
-def test_compiled_flops_prefers_harvested_report():
-    from bench_common import compiled_flops
-
-    telemetry.enable()
-    step, x, y = _mlp_step()
-    step(x, y)
-    rep = devprof.get_report("train_step")
-    assert compiled_flops(step, [(x, y)]) == rep.flops
-
 
 def test_mem_report_tool_renders_harvest(tmp_path, capsys):
     import mem_report

@@ -60,15 +60,15 @@ def test_check_nan_inf_skips_traced_values():
         paddle.set_flags({"check_nan_inf": False})
 
 
-def test_disable_flash_flag_routes_to_einsum():
+def test_sdpa_agrees_with_the_einsum_op():
+    """No flag routes attention (it once took a kill switch to reach the
+    einsum): whatever route the call takes agrees with the einsum op called
+    directly."""
     import paddle_tpu.nn.functional as F
+    from paddle_tpu.nn.functional.attention import _sdpa_raw
 
     q = Tensor(np.random.RandomState(0).randn(2, 128, 4, 64).astype(np.float32))
     out1 = F.scaled_dot_product_attention(q, q, q, is_causal=True)
-    paddle.set_flags({"disable_flash_attention": True})
-    try:
-        out2 = F.scaled_dot_product_attention(q, q, q, is_causal=True)
-    finally:
-        paddle.set_flags({"disable_flash_attention": False})
+    out2 = _sdpa_raw(q, q, q, causal=True)
     np.testing.assert_allclose(np.asarray(out1._value), np.asarray(out2._value),
                                atol=2e-2)

@@ -125,7 +125,7 @@ def test_router_prefers_packed(monkeypatch):
     monkeypatch.setattr(packed_mod, "flash_attention_packed", spy)
     q = jnp.ones((1, 256, 4, 64), jnp.float32)
     with __import__("paddle_tpu").ops.pallas.interpret_mode():
-        A._sdpa_flash(q, q, q, causal=True)
+        A._sdpa(q, q, q, is_causal=True)
     assert called.get("hit")
 
 

@@ -558,19 +558,3 @@ def test_cli_sarif(cli, capsys):
     driver = doc["runs"][0]["tool"]["driver"]
     assert driver["name"] == "paddle-tpu-mem-lint"
     assert doc["runs"][0]["results"]
-
-
-def test_bench_sentinel_tracks_hbm_peak():
-    """Satellite: BENCH/SERVE history rounds carrying hbm_peak_bytes are
-    tracked as lower-better metrics."""
-    path = os.path.join(os.path.dirname(__file__), os.pardir, "tools",
-                        "bench_sentinel.py")
-    spec = importlib.util.spec_from_file_location("bench_sentinel_cli", path)
-    sentinel = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sentinel)
-    bench = sentinel.extract_bench(
-        {"parsed": {"value": 10.0}, "telemetry": {"hbm_peak_bytes": 4096}})
-    assert bench["hbm_peak_bytes"] == (4096.0, "lower")
-    serve = sentinel.extract_serve(
-        {"value": 5.0, "telemetry": {"hbm_peak_bytes": 2048}})
-    assert serve["hbm_peak_bytes"] == (2048.0, "lower")

@@ -1,5 +1,5 @@
 """BERT model family + new vision models (DenseNet/AlexNet/SqueezeNet).
-References: BASELINE.md BERT metric; python/paddle/vision/models/."""
+References: python/paddle/vision/models/."""
 import numpy as np
 import pytest
 

@@ -398,7 +398,7 @@ def test_retired_gauge_readers_are_absent_safe():
         table = telemetry_report.build_table(
             {}, {}, {"serve.shed": 2.0, "serve.decode_steps": 5.0}, {}, {})
         assert "serve.shed" in table
-        # bench_serve's reader idiom: absent gauge reads as the default
+        # a reader's idiom: absent gauge reads as the default
         assert tm.gauges().get("serve.requests_in_flight", 0.0) == 0.0
     finally:
         telemetry.disable()

@@ -500,27 +500,3 @@ def test_profiler_merges_telemetry_spans():
            if e.name == "telemetry::dispatch"]
     assert tel[0].event_type == "Telemetry"
     assert tel[0].end_ns - tel[0].start_ns >= 1_000_000  # the 1ms sleep
-
-
-def test_bench_telemetry_block():
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
-    from bench_common import measure_steps, telemetry_block
-
-    step = _compiled_linear_step(in_dim=4)
-    batches = [(np.random.randn(4, 4).astype(np.float32),)
-               for _ in range(8)]
-    total, vals = measure_steps(step, batches, iters=5, warmup=3)
-    assert len(vals) == 5
-    blk = telemetry_block(total, 5)
-    assert blk["steps_per_sec"] > 0
-    assert 0.0 <= blk["data_wait_frac"] <= 1.0
-    assert blk["compile_count"] >= 1
-    assert "dispatch" in blk["phase_s"] or "compile" in blk["phase_s"]
-    assert blk["prefetch"]["bytes_staged"] > 0
-    # measure_steps turned telemetry back off but kept the data readable
-    assert not telemetry.enabled()
-    assert telemetry.summary()["steps_recorded"] >= 5

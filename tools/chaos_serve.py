@@ -35,10 +35,6 @@ Usage::
 
     python tools/chaos_serve.py --smoke       # CI gate (tiny CPU config)
     python tools/chaos_serve.py --json        # machine-readable result
-
-``tools/bench_serve.py --chaos`` embeds this harness's verdict as the
-``chaos_ok`` contract metric in the SERVE_r*.json artifact (direction
-``equal`` in ``tools/bench_sentinel.py``).
 """
 from __future__ import annotations
 

@@ -1,10 +1,9 @@
 #!/usr/bin/env python
-"""Graph-lint the bench model zoo (static analysis only — nothing executes
+"""Graph-lint a small model zoo (static analysis only — nothing executes
 on a device unless ``--run-steps`` is given).
 
-For each model this builds the same train step the benchmarks measure
-(``bench_resnet.py`` / ``bench_bert.py`` recipes at CPU smoke scale),
-abstractly traces it with ``paddle_tpu.analysis.lint_step`` against two
+For each model this builds a train step at CPU smoke scale (ResNet-50 with
+SGD+momentum, BERT MLM with AdamW, the serving steps), abstractly traces it with ``paddle_tpu.analysis.lint_step`` against two
 example batches, prints the findings table, and (with ``--jsonl``) emits one
 JSON object per finding — ``Finding.as_dict()`` plus a ``model`` key;
 ``Finding.from_dict`` round-trips the lines.
@@ -90,8 +89,7 @@ def build_mlp(fixture=None):
 
 
 def build_resnet(fixture=None):
-    """ResNet-50 at the bench script's CPU smoke scale (32x32, 10 classes,
-    SGD+momentum — bench_resnet.py recipe)."""
+    """ResNet-50 at CPU smoke scale (32x32, 10 classes, SGD+momentum)."""
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as F
     from paddle_tpu.vision.models import resnet50
@@ -117,8 +115,7 @@ def build_resnet(fixture=None):
 
 
 def build_bert(fixture=None):
-    """BERT MLM at the bench script's CPU smoke config (bench_bert.py),
-    AdamW — the optimizer whose lazy double-trace this lint regression-
+    """BERT MLM at a CPU smoke config, AdamW — the optimizer whose lazy double-trace this lint regression-
     tests."""
     import paddle_tpu as paddle
     from paddle_tpu.models import BertConfig, BertForPretraining

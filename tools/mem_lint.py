@@ -266,9 +266,9 @@ def build_longctx(fixture=None):
     threshold, so causal training attention runs the KV-block scan (ISSUE
     15) instead of the O(seq²) einsum score matrix. Measurable on
     XLA:CPU: the predicted peak must agree with ``memory_analysis`` and
-    never under-predict. ``--disable-blockwise`` forces the einsum path
-    on the SAME shapes — the run_tests.sh gate lints both under one
-    ``--capacity`` that only the blockwise timeline fits."""
+    never under-predict. ``--smoke`` lints the SAME shapes once more with
+    the threshold out of reach (the einsum path), under one ``--capacity``
+    that only the blockwise timeline fits."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.tensor import Tensor
     from paddle_tpu.jit.functionalize import CompiledStep
@@ -464,11 +464,6 @@ def run(argv=None):
                     choices=["error", "warning", "never"],
                     help="exit 1 when findings at/above this severity "
                          "exist")
-    ap.add_argument("--disable-blockwise", action="store_true",
-                    help="force the einsum attention path (sets the "
-                         "disable_blockwise_attention flag) — the "
-                         "run_tests.sh long-context gate lints the SAME "
-                         "config both ways under one --capacity")
     ap.add_argument("--no-fusion", action="store_true",
                     help="run the fusion-blind legacy timeline (looser "
                          "upper bound, crosschecked at MEM_RTOL_UNFUSED "
@@ -488,8 +483,16 @@ def run(argv=None):
         # the blockwise timeline fits and the einsum score matrix blows
         bw = run(["--models", "longctx", "--capacity",
                   str(LONGCTX_CAPACITY)])
-        es = run(["--models", "longctx", "--capacity",
-                  str(LONGCTX_CAPACITY), "--disable-blockwise"])
+        from paddle_tpu.nn.functional import attention
+
+        # the control: the same shapes with the scan's threshold out of
+        # reach, so causal training takes the einsum score matrix
+        min_kv, attention.BLOCKWISE_MIN_KV = attention.BLOCKWISE_MIN_KV, 1 << 62
+        try:
+            es = run(["--models", "longctx", "--capacity",
+                      str(LONGCTX_CAPACITY)])
+        finally:
+            attention.BLOCKWISE_MIN_KV = min_kv
         remat = run(["--fixture", "remat-plan"])
         ab = run(["--fixture", "fusion-ab"])
         ok = (clean == 0 and fixture == 1 and bw == 0 and es == 1
@@ -500,11 +503,6 @@ def run(argv=None):
               f"rc={remat} (want 0), fusion-ab rc={ab} (want 0) -> "
               f"{'OK' if ok else 'FAIL'}")
         return 0 if ok else 1
-
-    if args.disable_blockwise:
-        from paddle_tpu.framework.flags import set_flags
-
-        set_flags({"disable_blockwise_attention": True})
 
     capacity = args.capacity
     if args.fixture == "remat-plan":
