@@ -297,7 +297,7 @@ def leg_kernels(size):
     bf, f32 = jnp.bfloat16, jnp.float32
     b, s, h = size.batch, size.seq, size.heads
     d = size.hidden // h
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 24))
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 32))
 
     def rnd(shape, dtype):
         return jax.random.normal(next(keys), shape, f32).astype(dtype)
@@ -378,6 +378,28 @@ def leg_kernels(size):
         compare(f"flash_sdpa_decode sq{sq} sk{sk} b{db} bf16", decode, dense,
                 (rnd((db, sq, h, d), bf), rnd((db, sk, h, d), bf),
                  rnd((db, sk, h, d), bf)), 0)
+    # the row write as the serving cells' decode step reaches it (32 slots of
+    # 1024 positions, GPT-2 large's 20 heads of 64; the rehearsal keeps its
+    # own heads), each slot at a position of its own: the kernel and the
+    # vmapped dynamic_update_slice must agree in every element of K and V
+    from paddle_tpu.ops.pallas.kv_row_write import kv_row_write
+    from paddle_tpu.serving.kv_cache import _row_update
+
+    wh = 20 if size is FULL else h
+    starts = lens.at[3:5].set(jnp.asarray([127, 128]))
+    for rows in (1, 5):
+        kv = [rnd((db, sk, wh, d), bf) for _ in range(2)]
+        new = [rnd((db, rows, wh, d), bf) for _ in range(2)]
+        want = jax.jit(lambda kv, new: [
+            _row_update(x, n, starts) for x, n in zip(kv, new)])(kv, new)
+        # donated, as the decode step hands its cache over: the kernel's
+        # outputs are pinned to HBM and alias these very buffers
+        got = jax.jit(lambda kv, new: kv_row_write(
+            tuple(kv), tuple(new), starts), donate_argnums=0)(kv, new)
+        same = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
+        say(f"kernels: kv_row_write rows{rows} sk{sk} b{db} "
+            f"{wh}x{d} bf16: equal to the dynamic_update_slice: {same}")
+        check(same, f"kv_row_write rows{rows}: differs from _row_update")
 
 
 def _periodic_prompt(rng, vocab, n):
@@ -472,11 +494,17 @@ def leg_serve(size, label, engine_kw, prompt_lens, new_tokens):
           and not seen["layer_norm_op"],
           f"{label}: a serving route did not run, or the blockwise scan "
           f"did: {dict(seen)}")
+    routes = {k.rsplit(".", 1)[1] for k, v in counters.items()
+              if k.startswith("kv.row_write_route.") and v}
+    check(routes == {"column_kernel"},
+          f"{label}: the decode step's rows were written by {routes}, not "
+          f"by the column kernel alone")
     say(f"serve[{label}]: {len(first)} requests x2 (prompts {prompt_lens}), "
         f"all eos|length, replay identical; compiles {counts}; ops traced "
         f"flash_sdpa_cached x{seen['flash_sdpa_cached']} flash_sdpa_decode "
         f"x{seen['flash_sdpa_decode']} blockwise_sdpa "
-        f"x{seen['blockwise_sdpa']} sdpa x{seen['sdpa']}")
+        f"x{seen['blockwise_sdpa']} sdpa x{seen['sdpa']}; row write "
+        f"{sorted(routes)}")
     say(f"serve[{label}]: seconds in calls that compiled, per step "
         f"(trace, lower, backend, first run) "
         f"{ {k: [round(x, 1) for x in v.values()] for k, v in tm.compile_seconds().items()} }")

@@ -17,7 +17,12 @@ and in the layout the TPU keeps it in, and only up to the live length:
   The kernel takes exactly that view, so the ``transpose`` in
   :func:`flash_attention_decode` is a bitcast and no cache byte moves before
   the kernel's own DMA. For a shape the TPU lays out otherwise the result is
-  the same and XLA pays one copy;
+  the same and XLA pays one copy. Two kernels share this view: the decode
+  step's row write (``kv_row_write.py``) hands the buffers over in it,
+  aliased onto the donated cache, and this kernel reads them as written,
+  with nothing but bitcasts between parameter, write, attention and result
+  (``tools/tpu_aot_preflight.py`` compiles that program and
+  ``tests/test_pallas_tpu_compile.py`` holds it to no copy of a buffer);
 * grid ``(batch, kv_block)``; the per-row positions are scalar-prefetched,
   the K/V index map is clamped to the last live block (a repeated block
   index is not fetched again) and dead blocks skip their compute;

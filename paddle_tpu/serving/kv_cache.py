@@ -9,12 +9,24 @@ O(n²) per sequence.
 
 This module is the compiler-first formulation (PAPERS.md arxiv 2603.09555):
 per-layer buffers are preallocated at ``[batch, max_len, heads, head_dim]``
-and every step writes the new K/V rows with ``lax.dynamic_update_slice`` at
-a *traced* position index — the shapes entering the compiled step never
-change, so prefill compiles once per length bucket and decode compiles
-exactly once, and with the buffers passed through ``CompiledStep``'s
-``donate_inputs`` the update aliases in place in HBM (arxiv 2301.13062:
-a fused in-place dynamic-update-slice, not a gather/concat chain).
+and every step writes the new K/V rows at a *traced* position index — the
+shapes entering the compiled step never change, so prefill compiles once per
+length bucket and decode compiles exactly once, and with the buffers passed
+through ``CompiledStep``'s ``donate_inputs`` the write happens in place in
+HBM.
+
+How the decode step's row is written is chosen by :func:`row_write_route`
+from the layout the backend keeps the buffer in. XLA:CPU, and XLA:TPU when
+``head_dim`` fills the 128 lanes, keep a row contiguous: there a vmapped
+``lax.dynamic_update_slice`` (:func:`_row_update`) is one small in-place
+update (arxiv 2301.13062). With ``head_dim`` under 128 XLA:TPU keeps the
+buffer as ``[batch, heads, head_dim, max_len]``, ``max_len`` on the lanes
+(what the decode attention kernel wants, ``ops/pallas/flash_decode.py``): a
+row is then ``heads * head_dim`` elements in as many lane rows, the same
+``dynamic_update_slice`` compiles to a ``while`` of one-row updates a slot
+(68% of a GPT-2 large decode step on the v5e), and the Pallas kernel
+``ops/pallas/kv_row_write.py`` rewrites the one 128-lane column of each
+slot that holds its position instead, aliased onto the donated buffer.
 
 Masking carries the variable part: attention always runs over the full
 ``max_len`` keys and the per-slot lengths mask out the not-yet-written
@@ -39,7 +51,7 @@ from ..framework.tensor import Tensor
 
 __all__ = ["KVCache", "DecodeView", "PrefillView", "ChunkView",
            "StateDecodeView", "StatePrefillView", "CountsView",
-           "pick_bucket", "default_buckets"]
+           "pick_bucket", "default_buckets", "row_write_route"]
 
 #: additive-mask floor: large enough to zero a softmax lane in fp32/bf16
 #: without producing inf-inf NaNs when a whole row is masked
@@ -195,10 +207,13 @@ class KVCache:
 # per-layer views (the duck-typed `cache=` object GPTDecoderLayer consumes)
 # ---------------------------------------------------------------------------
 def _row_update(buf, new, starts):
-    """Batched in-place row write: ``buf[i, starts[i]:starts[i]+s] = new[i]``
-    via a vmapped ``dynamic_update_slice`` (per-slot scalar start index,
-    static shapes — XLA lowers this to one fused in-place update when the
-    buffer is donated)."""
+    """Batched row write: ``buf[i, starts[i]:starts[i]+s] = new[i]`` via a
+    vmapped ``dynamic_update_slice`` (per-slot scalar start index, static
+    shapes). Where a row is contiguous in the backend's layout (XLA:CPU;
+    XLA:TPU at ``head_dim >= 128``) this is one small in-place update of a
+    donated buffer; where XLA:TPU puts ``max_len`` on the lanes it is a
+    ``while`` of one-row updates a slot, and :func:`row_write_route` sends
+    the decode step to the column kernel instead."""
 
     def one(b, n, s):
         z = jnp.int32(0)
@@ -207,13 +222,50 @@ def _row_update(buf, new, starts):
     return jax.vmap(one)(buf, new, starts)
 
 
-class DecodeView:
-    """One layer's cache view for the batched decode step.
+def row_write_route(*, rows, max_len, heads, head_dim, itemsize, pallas):
+    """The one place the decode-shaped row write is chosen: ``column_kernel``
+    (``ops/pallas/kv_row_write.py``) or ``dus`` (:func:`_row_update`). Shape
+    facts and what the platform tells (``pallas.is_available()``: a TPU
+    backend, or a test's ``interpret_mode()``) come in as arguments and no
+    global state is read, so a test can ask what the chip compiles.
 
-    ``update(k_new, v_new)`` writes each slot's single new K/V row at that
-    slot's position index and returns the FULL buffers for attention (the
-    additive length mask hides the invalid tail). The updated buffers stay
-    on the view; the engine collects them into the next ``KVCache``.
+    The kernel takes the layouts in which XLA:TPU puts ``max_len`` on the
+    lanes (``head_dim`` under 128) in whole 128-lane columns; XLA:CPU, a
+    ``head_dim`` that fills the lanes (a row is contiguous there already), a
+    ``max_len`` that is no multiple of 128 and blocks too large for VMEM
+    keep the ``dynamic_update_slice``."""
+    from ..ops.pallas.kv_row_write import supports_row_write
+
+    if pallas and supports_row_write(rows, max_len, heads, head_dim, itemsize):
+        return "column_kernel"
+    return "dus"
+
+
+def _count_row_write_route(route):
+    """Counter ``kv.row_write_route.<route>``: bumped when a decode-shaped
+    write is TRACED (the route is a property of the compiled step, not of a
+    tick), once a layer, as ``attn.decode_route.<route>`` is."""
+    from ..profiler import telemetry
+
+    if telemetry.enabled():
+        telemetry.get_telemetry().inc(f"kv.row_write_route.{route}")
+
+
+class DecodeView:
+    """One layer's cache view for the batched decode step (and speculative
+    verify's window).
+
+    ``update(k_new, v_new)`` writes each slot's new K/V rows (decode one,
+    verify ``spec_k + 1``) from that slot's position index on and returns
+    the FULL buffers for attention (the length mask hides the invalid
+    tail). :func:`row_write_route` picks the write: the column kernel, K
+    and V in one call, where the TPU keeps ``max_len`` on the lanes, the
+    vmapped ``dynamic_update_slice`` elsewhere; the two agree element for
+    element. The kernel writes into the buffers it is handed, so on a TPU
+    the step that builds this view donates its cache (every serving step
+    does; ``kv_row_write.py`` says what XLA does otherwise). The updated
+    buffers stay on the view; the engine collects them into the next
+    ``KVCache``.
     """
 
     __slots__ = ("k", "v", "pos")
@@ -224,10 +276,23 @@ class DecodeView:
         self.pos = _leaf(pos)
 
     def update(self, k_new, v_new):
+        from ..ops import pallas
+
         kn = _leaf(k_new).astype(self.k.dtype)
         vn = _leaf(v_new).astype(self.v.dtype)
-        self.k = _row_update(self.k, kn, self.pos)
-        self.v = _row_update(self.v, vn, self.pos)
+        _, max_len, heads, head_dim = self.k.shape
+        route = row_write_route(
+            rows=kn.shape[1], max_len=max_len, heads=heads, head_dim=head_dim,
+            itemsize=self.k.dtype.itemsize, pallas=pallas.is_available())
+        _count_row_write_route(route)
+        if route == "column_kernel":
+            from ..ops.pallas.kv_row_write import kv_row_write
+
+            self.k, self.v = kv_row_write((self.k, self.v), (kn, vn),
+                                          self.pos)
+        else:
+            self.k = _row_update(self.k, kn, self.pos)
+            self.v = _row_update(self.v, vn, self.pos)
         return Tensor(self.k), Tensor(self.v), self
 
 

@@ -1,0 +1,372 @@
+"""The decode step's K/V row write (ISSUE 33): the column kernel
+(``ops/pallas/kv_row_write.py``, interpret mode here) against
+``kv_cache._row_update``, the table of ``row_write_route``, and serving
+through either route.
+
+Contracts under test:
+  * exact equality of the WHOLE buffer with the vmapped
+    ``dynamic_update_slice``: every dtype, batch and position the engine
+    builds, one row (decode) and several (speculative verify, straddling a
+    128-lane column or not), a dead slot at its clamped position, starts the
+    ``dynamic_update_slice`` would clamp;
+  * everything outside the written rows is the input's, bit for bit;
+  * ``row_write_route`` is a pure function of shape facts and of what the
+    platform tells, so tier-1 (XLA:CPU) can say what the TPU compiles;
+  * greedy serving is byte-identical between the two routes, decode and
+    verify, the counter ``kv.row_write_route.<route>`` says which one a step
+    traced, and ``serve_decode`` still compiles exactly once.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu.models import GPTConfig, GPTForCausalLM
+from paddle_tpu.ops import pallas
+from paddle_tpu.ops.pallas import kv_row_write as W
+from paddle_tpu.profiler import telemetry
+from paddle_tpu.serving import GenerationEngine
+from paddle_tpu.serving import kv_cache as C
+from paddle_tpu.utils import unique_name
+
+
+def _bits(x):
+    """The array's bytes as unsigned integers: equality that tells -0.0 from
+    0.0 and one NaN from another."""
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+def _buffers(b, max_len, h, d, s, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape, new = (b, max_len, h, d), (b, s, h, d)
+    return ([jax.random.normal(k, shape, jnp.float32).astype(dtype)
+             for k in ks[:2]],
+            [jax.random.normal(k, new, jnp.float32).astype(dtype)
+             for k in ks[2:]])
+
+
+def _write_both(bufs, news, starts):
+    got = W.kv_row_write(tuple(bufs), tuple(news), starts, interpret=True)
+    want = [C._row_update(x, n, starts) for x, n in zip(bufs, news)]
+    return got, want
+
+
+# ---------------------------------------------------------------------------
+# the kernel against _row_update
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("batch", [1, 4, 32])
+def test_one_row_a_slot_equals_row_update(dtype, batch):
+    """Decode: every slot at a position of its own, the column edges (0,
+    127, 128, 255, ``max_len - 1``) among them."""
+    max_len = 384
+    bufs, news = _buffers(batch, max_len, 2, 64, 1, dtype)
+    edges = [0, 127, 128, 255, max_len - 1]
+    rest = np.random.RandomState(batch).randint(0, max_len, batch)
+    starts = jnp.asarray((edges + list(rest))[:batch], jnp.int32)
+    got, want = _write_both(bufs, news, starts)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("pos", [0, 127, 128, 255, 383])
+def test_all_slots_at_one_position(pos):
+    bufs, news = _buffers(4, 384, 2, 64, 1, jnp.bfloat16, seed=pos)
+    got, want = _write_both(bufs, news, jnp.full((4,), pos, jnp.int32))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("rows", [2, 5, 8])
+def test_verify_rows_straddling_a_column_equal_row_update(dtype, rows):
+    """Speculative verify: ``rows`` rows from each slot's position on:
+    inside one 128-lane column, across two (126, 124, 255), at the end."""
+    max_len = 384
+    bufs, news = _buffers(6, max_len, 2, 64, rows, dtype, seed=rows)
+    starts = jnp.asarray([0, 126, 124, 255, max_len - rows, 17], jnp.int32)
+    got, want = _write_both(bufs, news, starts)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("rows,start", [(1, 255), (5, 251), (1, 10 ** 6),
+                                        (5, 254), (1, -3)])
+def test_dead_slots_and_clamped_starts_are_written_as_row_update_writes_them(
+        rows, start):
+    """A dead slot sits at the engine's clamped position (``max_len - 1``
+    decode, ``max_len - W`` verify) and is written like any other; a start
+    outside ``[0, max_len - rows]`` lands where ``dynamic_update_slice``
+    clamps it."""
+    bufs, news = _buffers(3, 256, 2, 64, rows, jnp.bfloat16, seed=7)
+    starts = jnp.asarray([5, start, 130], jnp.int32)
+    got, want = _write_both(bufs, news, starts)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("heads,head_dim", [(3, 32), (5, 64), (1, 16)])
+def test_widths_that_fill_no_whole_128_rows_chunk(heads, head_dim):
+    """``heads * head_dim`` of 96, 320 and 16: the last chunk of the block
+    is a partial one."""
+    bufs, news = _buffers(3, 256, heads, head_dim, 1, jnp.bfloat16)
+    got, want = _write_both(bufs, news, jnp.asarray([3, 127, 200], jnp.int32))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+def _random_bits(rng, shape, nans):
+    """Random bf16 bit patterns: infinities, negative zeros, subnormals;
+    NaNs of every payload when asked, else none."""
+    raw = rng.randint(0, 2 ** 16, shape).astype(np.uint16)
+    nan = (raw & 0x7F80 == 0x7F80) & (raw & 0x007F != 0)
+    return raw if nans else np.where(nan, raw & 0xBFFF, raw)
+
+
+@pytest.mark.parametrize("nans", [False, True], ids=["bits", "nans"])
+def test_cells_outside_the_written_rows_keep_their_bytes(nans):
+    """Random bits around the written rows and in them: infinities,
+    negative zeros and subnormals come back bit for bit, outside the rows
+    and inside. A NaN stays a NaN where it was (the select and the float32
+    column may not keep its payload, here under XLA:CPU's interpreter)."""
+    b, max_len, h, d, s = 2, 256, 2, 64, 3
+    rng = np.random.RandomState(3)
+    raw = _random_bits(rng, (2, b, max_len, h, d), nans)
+    new_raw = _random_bits(rng, (2, b, s, h, d), nans)
+    new_raw[0, 0, 0, 0, :3] = [0x8000, 0x7F80, 0xFF80]  # -0.0, inf, -inf
+    bufs = [jnp.asarray(r).view(jnp.bfloat16) for r in raw]
+    news = [jnp.asarray(r).view(jnp.bfloat16) for r in new_raw]
+    starts = np.asarray([126, 40], np.int32)
+    got = W.kv_row_write(tuple(bufs), tuple(news), jnp.asarray(starts),
+                         interpret=True)
+    for g, before, new in zip(got, raw, new_raw):
+        want = before.copy()
+        for i, p in enumerate(starts):
+            want[i, p:p + s] = new[i]
+        if nans:
+            g = np.asarray(g, np.float32)
+            want = np.asarray(jnp.asarray(want).view(jnp.bfloat16),
+                              np.float32)
+            np.testing.assert_array_equal(np.isnan(g), np.isnan(want))
+            np.testing.assert_array_equal(g[~np.isnan(g)],
+                                          want[~np.isnan(want)])
+        else:
+            np.testing.assert_array_equal(_bits(g), want)
+
+
+def test_one_buffer_a_call_and_two_agree():
+    bufs, news = _buffers(4, 256, 2, 64, 1, jnp.bfloat16)
+    starts = jnp.asarray([0, 127, 128, 255], jnp.int32)
+    both = W.kv_row_write(tuple(bufs), tuple(news), starts, interpret=True)
+    for x, n, w in zip(bufs, news, both):
+        (alone,) = W.kv_row_write((x,), (n,), starts, interpret=True)
+        np.testing.assert_array_equal(_bits(alone), _bits(w))
+
+
+def test_the_kernel_refuses_what_the_route_does_not_send_it():
+    bufs, news = _buffers(2, 250, 2, 64, 1, jnp.bfloat16)
+    with pytest.raises(ValueError, match="max_len"):
+        W.kv_row_write(tuple(bufs), tuple(news), jnp.zeros((2,), jnp.int32),
+                       interpret=True)
+
+
+def test_under_a_mesh_the_kernel_partitions_itself_over_the_batch():
+    """Mosaic kernels are not partitioned by GSPMD: under the ``dp`` mesh of
+    ``chip_smoke.py`` each device writes its own slots."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.partition import partition_scope
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    bufs, news = _buffers(8, 256, 2, 64, 1, jnp.bfloat16)
+    starts = jnp.asarray([0, 127, 128, 255, 1, 2, 3, 200], jnp.int32)
+    row = NamedSharding(mesh, P("dp"))
+    placed = jax.device_put((bufs, news, starts), row)
+
+    def write(bufs, news, starts):
+        with partition_scope((mesh, ("dp",))):
+            return W.kv_row_write(tuple(bufs), tuple(news), starts,
+                                  interpret=True)
+
+    got = jax.jit(write)(*placed)
+    for g, x, n in zip(got, bufs, news):
+        assert g.sharding.is_equivalent_to(row, g.ndim)
+        np.testing.assert_array_equal(
+            _bits(g), _bits(C._row_update(x, n, starts)))
+
+
+# ---------------------------------------------------------------------------
+# the route table, read without a chip
+# ---------------------------------------------------------------------------
+TPU = dict(pallas=True)
+CPU = dict(pallas=False)
+INTERPRET = dict(pallas=True)  # is_available() is true inside interpret_mode
+
+LARGE = dict(max_len=1024, heads=20, head_dim=64, itemsize=2)  # gpt2-large
+HYBRID = dict(max_len=2048, heads=2, head_dim=128, itemsize=2)  # nemotron-3
+
+
+def _row(name, platform, expect, *, rows=1, **facts):
+    return pytest.param(dict(rows=rows, **facts), platform, expect, id=name)
+
+
+ROUTE_ROWS = [
+    _row("tpu-large-decode", TPU, "column_kernel", **LARGE),
+    _row("tpu-large-verify-5", TPU, "column_kernel", rows=5, **LARGE),
+    _row("tpu-large-verify-8", TPU, "column_kernel", rows=8, **LARGE),
+    _row("tpu-large-rows-9", TPU, "dus", rows=9, **LARGE),
+    _row("tpu-large-f32", TPU, "column_kernel",
+         **dict(LARGE, itemsize=4)),
+    _row("tpu-124m-decode", TPU, "column_kernel", max_len=1024, heads=12,
+         head_dim=64, itemsize=2),
+    _row("tpu-generate-batch-1-max-len-128", TPU, "column_kernel",
+         max_len=128, heads=20, head_dim=64, itemsize=2),
+    _row("tpu-hybrid-head-dim-128", TPU, "dus", **HYBRID),
+    _row("tpu-max-len-1000", TPU, "dus", **dict(LARGE, max_len=1000)),
+    _row("tpu-max-len-64", TPU, "dus", **dict(LARGE, max_len=64)),
+    _row("tpu-head-dim-8-under-a-bf16-tile", TPU, "dus",
+         **dict(LARGE, head_dim=8)),
+    _row("tpu-head-dim-8-f32", TPU, "column_kernel",
+         **dict(LARGE, head_dim=8, itemsize=4)),
+    _row("tpu-blocks-over-vmem", TPU, "dus", **dict(LARGE, heads=160)),
+    _row("tpu-one-byte-cache", TPU, "dus", **dict(LARGE, itemsize=1)),
+    _row("cpu-large-decode", CPU, "dus", **LARGE),
+    _row("cpu-large-verify-5", CPU, "dus", rows=5, **LARGE),
+    _row("cpu-hybrid", CPU, "dus", **HYBRID),
+    _row("interpret-large-decode", INTERPRET, "column_kernel", **LARGE),
+    _row("interpret-hybrid", INTERPRET, "dus", **HYBRID),
+    _row("interpret-toy-two-heads-of-64", INTERPRET, "column_kernel",
+         max_len=128, heads=2, head_dim=64, itemsize=4),
+]
+
+
+@pytest.mark.parametrize("facts, platform, expect", ROUTE_ROWS)
+def test_route_table(facts, platform, expect):
+    assert C.row_write_route(**facts, **platform) == expect
+
+
+def test_the_route_reads_no_global_state(monkeypatch):
+    """What the platform tells comes in as an argument: the same call gives
+    the same answer inside interpret mode and outside."""
+    monkeypatch.setattr(pallas, "is_available", lambda: 1 / 0)
+    with pallas.interpret_mode():
+        inside = C.row_write_route(rows=1, pallas=False, **LARGE)
+    assert inside == C.row_write_route(rows=1, pallas=False, **LARGE) == "dus"
+
+
+# ---------------------------------------------------------------------------
+# serving through either route
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def _no_persistent_compile_cache():
+    """Parity across separately compiled executables is only bit-exact with
+    in-process compiles (as in tests/test_serving.py)."""
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+@pytest.fixture
+def _counters():
+    telemetry.reset()
+    telemetry.enable()
+    yield telemetry.get_telemetry
+    telemetry.disable()
+    telemetry.reset()
+
+
+def _kernel_model(seed=0):
+    """Two heads of 64 over a 128-slot cache: shapes the kernel takes."""
+    with unique_name.guard():
+        paddle.seed(seed)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
+            max_position_embeddings=128, hidden_dropout=0.0,
+            attention_dropout=0.0, initializer_range=0.6))
+    model.eval()
+    return model
+
+
+def _route_counts(tm):
+    return {k.rsplit(".", 1)[1]: v for k, v in tm().counters().items()
+            if k.startswith("kv.row_write_route.")}
+
+
+@pytest.mark.parametrize("engine_kw,prompt_len", [
+    ({}, 7), ({"spec_k": 4}, 9)], ids=["decode", "verify"])
+def test_greedy_serving_byte_identical_between_the_two_routes(
+        _no_persistent_compile_cache, _counters, monkeypatch, engine_kw,
+        prompt_len):
+    """The same engine, both times in interpret mode (so attention takes the
+    same kernel): once with the column kernel writing the rows, once with
+    the kernel's VMEM budget at 0, which leaves ``dus``. The same tokens,
+    and each run's counter names the route its steps traced, once a layer."""
+    model = _kernel_model()
+    rng = np.random.RandomState(11)
+    # a periodic prompt: the n-gram proposer drafts from the first tick
+    prompt = np.tile(rng.randint(0, 512, 3), 4)[:prompt_len].tolist()
+
+    def gen():
+        telemetry.reset()
+        eng = GenerationEngine(model, max_batch=2, max_len=128,
+                               prefill_buckets=(16,), **engine_kw)
+        return eng.generate(prompt, max_new_tokens=24), _route_counts(
+            _counters)
+
+    with pallas.interpret_mode():
+        kernel, kernel_routes = gen()
+        monkeypatch.setattr(W, "BLOCK_BYTES", 0)
+        dus, dus_routes = gen()
+    assert len(set(kernel)) > 2, "degenerate model; parity is vacuous"
+    assert kernel == dus
+    assert set(kernel_routes) == {"column_kernel"}, kernel_routes
+    assert set(dus_routes) == {"dus"}, dus_routes
+    # bumped once a layer whenever the step is traced (CompiledStep traces
+    # it more than once): the same number either way, the two layers' share
+    assert kernel_routes["column_kernel"] == dus_routes["dus"] > 0
+    assert kernel_routes["column_kernel"] % 2 == 0
+
+
+def test_xla_cpu_keeps_the_dynamic_update_slice(_counters):
+    """Tier-1's own platform: no Pallas, so the decode step writes its rows
+    as it always did."""
+    eng = GenerationEngine(_kernel_model(), max_batch=2, max_len=128,
+                           prefill_buckets=(16,))
+    eng.generate([5, 6, 7], max_new_tokens=4)
+    assert set(_route_counts(_counters)) == {"dus"}
+
+
+def test_decode_still_compiles_once_through_the_column_kernel(_counters):
+    with pallas.interpret_mode():
+        eng = GenerationEngine(_kernel_model(), max_batch=2, max_len=128,
+                               prefill_buckets=(8, 16))
+        out = eng.generate([5, 6, 7], max_new_tokens=40)
+    tm = _counters()
+    assert len(out) == 40
+    assert tm.compile_counts().get("serve_decode") == 1, tm.compile_counts()
+    assert tm.compile_counts().get("serve_prefill") == 1
+    assert tm.recompile_count == 0
+    assert set(_route_counts(_counters)) == {"column_kernel"}
+
+
+def test_a_head_dim_that_fills_the_lanes_counts_dus_under_interpret(
+        _counters):
+    """The hybrid configuration's attention blocks hold heads of 128: a row
+    is contiguous on the TPU, and the route says ``dus`` whatever Pallas
+    offers."""
+    k = jnp.zeros((2, 128, 2, 128), jnp.bfloat16)
+    new = jnp.ones((2, 1, 2, 128), jnp.bfloat16)
+    with pallas.interpret_mode():
+        view = C.DecodeView(k, k, jnp.asarray([3, 127], jnp.int32))
+        out_k, _, _ = view.update(new, new)
+    assert _route_counts(_counters) == {"dus": 1}
+    np.testing.assert_array_equal(
+        np.asarray(out_k._value[:, :, 0, 0], np.float32)[[0, 1], [3, 127]],
+        [1.0, 1.0])

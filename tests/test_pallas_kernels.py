@@ -583,16 +583,27 @@ def _site_layer_norm():
     return _sum_grad(lambda x, g, b: fused_layer_norm(x, g, b), 3), (x, g, g)
 
 
+def _site_kv_row_write():
+    from paddle_tpu.ops.pallas.kv_row_write import kv_row_write
+
+    k = jnp.ones((2, 128, 2, 64), jnp.float32)
+    new = jnp.ones((2, 1, 2, 64), jnp.float32)
+    starts = np.asarray([3, 127], np.int32)
+    return (lambda k, v, kn, vn: kv_row_write((k, v), (kn, vn), starts)), (
+        k, k, new, new)
+
+
 @pytest.mark.parametrize("site,expected", [
     (_site_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     (_site_flash_cached, {"flash_cached_fwd"}),
     (_site_flash_decode, {"flash_decode_fwd"}),
     (_site_flash_packed, {"flash_packed_fwd", "flash_packed_bwd"}),
     (_site_layer_norm, {"layer_norm_fwd", "layer_norm_bwd"}),
+    (_site_kv_row_write, {"kv_row_write"}),
 ], ids=["flash", "flash_cached", "flash_decode", "flash_packed",
-        "layer_norm"])
+        "layer_norm", "kv_row_write"])
 def test_every_pallas_call_site_carries_its_name(site, expected):
-    """Each of these nine ``pl.pallas_call`` sites names its kernel: the
+    """Each of these ten ``pl.pallas_call`` sites names its kernel: the
     name is the custom call's in the device trace (``%jvp_flash_packed_fwd_``
     on the v5e), which is what a reader's pattern holds on to."""
     fn, args = site()
@@ -611,4 +622,4 @@ def test_no_pallas_call_site_is_left_unnamed():
             calls += 1
             named += bool(re.match(r"\s*[\w.()=, ]+,\s*name=\"\w+\"",
                                    text[m.end():m.end() + 200]))
-    assert calls == 12 and named == calls
+    assert calls == 13 and named == calls

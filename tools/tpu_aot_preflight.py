@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import re
 import sys
 import time
 
@@ -130,6 +131,45 @@ def kernel_programs(devs):
                lambda q=q, kv=kv, sq=sq: jax.jit(flash_attention_decode).lower(
                    q, kv, kv, _sds((8, sq), jnp.int32, one)))
 
+    # the decode step's row write at GPT-2 large's heads (20 x 64): the
+    # kernel alone, one row a slot (decode) and spec_k + 1 (verify), and a
+    # decode-shaped program around it with the cache DONATED: write, then
+    # attend over what was written, both through the view the TPU keeps
+    from paddle_tpu.ops.pallas.kv_row_write import kv_row_write
+
+    wh = 20
+    kvw = _sds((8, s, wh, d), bf, one)
+    for rows in (1, 5):
+        new = _sds((8, rows, wh, d), bf, one)
+        yield (f"kv row write b8 s{rows} sk{s}",
+               lambda new=new: jax.jit(
+                   lambda k, v, kn, vn, pos: kv_row_write(
+                       (k, v), (kn, vn), pos), donate_argnums=(0, 1)).lower(
+                           kvw, kvw, new, new, _sds((8,), jnp.int32, one)))
+
+    # ... at the serving cells' batch (32 slots: 84 MB a buffer) and over
+    # eight layers, because what has to be ruled out needs both: XLA's
+    # memory-space assignment moving whole cache buffers into the v5e's
+    # 128 MiB of VMEM ahead of the kernel and copying its aliased result
+    # out again (25 of a 36-layer decode step's 72 buffers, 5 ms a step on
+    # the chip, before the kernel pinned its outputs to HBM)
+    def write_then_attend(ks, vs, x, w, pos):
+        out_k, out_v = [], []
+        for k, v in zip(ks, vs):
+            new = (x @ w).reshape(32, 1, wh, d)
+            k, v = kv_row_write((k, v), (new, new), pos)
+            x = flash_attention_decode(new, k, v, pos[:, None]).reshape(
+                32, wh * d)
+            out_k.append(k)
+            out_v.append(v)
+        return x, out_k, out_v
+
+    big = [_sds((32, s, wh, d), bf, one)] * 8
+    yield "kv row write donated decode b32 x8", lambda: jax.jit(
+        write_then_attend, donate_argnums=(0, 1)).lower(
+            big, big, _sds((32, wh * d), bf, one),
+            _sds((wh * d, wh * d), bf, one), _sds((32,), jnp.int32, one))
+
     # the expert and state-space kernels at the widths the benchmark's
     # hybrid configuration serves: hidden 2688, expert width 1856 (no
     # multiple of 128: the stacks are [experts, 1856, 2688] both ways), 64
@@ -196,6 +236,15 @@ def kernel_programs(devs):
 
     yield "dp4: flash decode b8", lambda: jax.jit(decode).lower(
         _sds((gb, 1, h, d), bf, row), kvs, kvs, _sds((gb, 1), jnp.int32, row))
+
+    def write(k, v, kn, vn, pos):
+        with partition_scope((mesh, ("dp",))):
+            return kv_row_write((k, v), (kn, vn), pos)
+
+    news = _sds((gb, 1, h, d), bf, row)
+    yield "dp4: kv row write b8", lambda: jax.jit(
+        write, donate_argnums=(0, 1)).lower(
+            kvs, kvs, news, news, _sds((gb,), jnp.int32, row))
 
 
 # ---------------------------------------------------------------------------
@@ -303,6 +352,26 @@ def main(argv=None):
             print(f"       temporaries "
                   f"{compiled.memory_analysis().temp_size_in_bytes} bytes",
                   flush=True)
+        if compiled is not None and name.startswith("kv row write"):
+            # in place: no temporary as large as a cache buffer, and under
+            # donation the kernel's outputs ARE the donated parameters (the
+            # transposes and reshapes around it are bitcasts, no copy stands
+            # between parameter, kernel and result)
+            ma = compiled.memory_analysis()
+            print(f"       temporaries {ma.temp_size_in_bytes} bytes, "
+                  f"aliased {ma.alias_size_in_bytes} of "
+                  f"{ma.argument_size_in_bytes} argument bytes", flush=True)
+            if "donated" in name:
+                text = compiled.as_text()
+                header = text[:text.index("\n")]
+                pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+                copies = len(re.findall(
+                    r"= \(*bf16\[32,(?:\d+,)*1024[,\d]*\]\S*,? [^=]*?"
+                    r"(?:copy|transpose|fusion|copy-start|slice-start)\(",
+                    text))
+                print(f"       outputs aliased to parameters: "
+                      f"{' '.join(f'{o}<-{i}' for o, i in pairs) or 'none'}; "
+                      f"copies of a cache buffer: {copies}", flush=True)
         if compiled is not None and name.startswith("moe grouped"):
             # both products contract over the stacks' minor dimension as the
             # TPU keeps them: a program that had to re-lay a stack out would
