@@ -195,6 +195,31 @@ def build_engine(size, **kw):
                             max_len=size.max_len, **kw)
 
 
+def build_delta_rule_engine(tiny, max_batch, max_len, **kw):
+    """The benchmark's Solar Open 2 cut through the normal constructor: the
+    published widths (the config's defaults), the first 4 layers ``G K K
+    K``, 40 of the 320 routed experts, 24,576 vocabulary rows; ``tiny``
+    keeps the kinds and the 128-wide heads (so the same routes are taken)
+    at a size the interpreter finishes."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import SolarOpen2Config, SolarOpen2ForCausalLM
+    from paddle_tpu.serving import GenerationEngine
+
+    cut = dict(num_hidden_layers=4, gqa_layers=(0,))
+    if tiny:
+        cfg = SolarOpen2Config(
+            vocab_size=512, hidden_size=128, num_attention_heads=2,
+            num_key_value_heads=1, kda_num_heads=8, kda_gate_rank=16,
+            n_routed_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=128, held_experts=(0, 1, 2, 3), **cut)
+    else:
+        cfg = SolarOpen2Config(vocab_size=24576,
+                               held_experts=tuple(range(40)), **cut)
+    paddle.seed(0)
+    return GenerationEngine(SolarOpen2ForCausalLM(cfg), max_batch=max_batch,
+                            max_len=max_len, **kw)
+
+
 def train_batches(size):
     """One seeded batch of random tokens with next-token labels, repeated:
     every copy is a fresh host array because the step donates its inputs.
@@ -514,6 +539,59 @@ def leg_serve(size, label, engine_kw, prompt_lens, new_tokens):
         f"peak bytes {peak_bytes(jax.devices()[0])}; ok")
 
 
+def leg_delta_rule(tiny):
+    """A decoder with delta-rule state, gated grouped-KV attention and gated
+    experts through ``Scheduler``: which routes its decode step compiled
+    to, and the state kernel against the XLA step at the served shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional import kda
+    from paddle_tpu.profiler import telemetry
+    from paddle_tpu.serving import Request, Scheduler
+
+    slots = 4 if tiny else 128
+    tm = telemetry.get_telemetry()
+    before = dict(tm.counters())
+    eng = build_delta_rule_engine(tiny, max_batch=slots, max_len=256)
+    sched = Scheduler(eng)
+    rng = np.random.default_rng(0)
+    vocab = eng.model.cfg.vocab_size
+    reqs = [sched.submit(Request(prompt=rng.integers(0, vocab, n).tolist(),
+                                 max_new_tokens=m))
+            for n, m in ((9, 6), (70, 4), (140, 5))]
+    sched.run()
+    check(all(len(r.tokens) == r.max_new_tokens for r in reqs),
+          "delta-rule engine: a request was not served in full")
+    took = {k: v - before.get(k, 0) for k, v in tm.counters().items()
+            if k.startswith(("kda.step_route", "kv.row_write_route",
+                             "attn.decode_route")) and v != before.get(k, 0)}
+    say(f"delta-rule engine: routes of the traced steps {took}")
+    traces = took.get("kv.row_write_route.dus", 0)
+    check(traces >= 1 and set(took) == {
+        "kda.step_route.kernel", "kv.row_write_route.dus",
+        "attn.decode_route.einsum_grouped"}
+        and took["kda.step_route.kernel"] == 3 * traces,
+        f"delta-rule engine: expected the state kernel in 3 layers, dus and "
+        f"the grouped einsum, got {took}")
+    del eng, sched
+    gc.collect()
+    H = 8 if tiny else 64
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q, k, v = (jax.random.normal(keys[i], (slots, H, 128)) for i in range(3))
+    args = (unit(q) * 128 ** -0.5, unit(k), v,
+            -jax.nn.softplus(jax.random.normal(keys[3], (slots, H, 128))),
+            2 * jax.nn.sigmoid(jax.random.normal(keys[4], (slots, H))))
+    S = jax.random.normal(keys[5], (slots, H, 128, 128))
+    want = jax.jit(kda._step_xla)(*args, S)
+    got = jax.jit(kda.kda_step, donate_argnums=5)(*args, S + 0.0)
+    for name, a, b in zip(("read-out", "state"), got, want):
+        err = _rel_err(a, b)
+        say(f"kda_step b{slots} h{H} 128x128 {name}: rel err {err:.2e}")
+        check(err < 1e-4, f"kda_step {name} off the XLA step by {err:.2e}")
+
+
 def leg_dp4(size, one_chip_losses):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -635,6 +713,8 @@ def main(argv=None):
         for leg in SERVE_LEGS:
             leg_serve(size, *leg)
             gc.collect()
+        leg_delta_rule(args.rehearse)
+        gc.collect()
         leg_dp4(size, losses)
     say(f"memory_stats of device 0: {dev.memory_stats()}")
     say(stats.line(cache_dir))

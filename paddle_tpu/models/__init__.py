@@ -14,6 +14,11 @@ from .nemotron_h import (  # noqa: F401
     NemotronHModel,
     NemotronHForCausalLM,
 )
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config,
+    SolarOpen2Model,
+    SolarOpen2ForCausalLM,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertModel,
@@ -44,6 +49,9 @@ __all__ = [
     "NemotronHConfig",
     "NemotronHModel",
     "NemotronHForCausalLM",
+    "SolarOpen2Config",
+    "SolarOpen2Model",
+    "SolarOpen2ForCausalLM",
     "BertConfig",
     "BertModel",
     "BertForPretraining",

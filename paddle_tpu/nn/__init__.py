@@ -14,6 +14,7 @@ from .decode import BeamSearchDecoder, Decoder, dynamic_decode  # noqa: F401
 from .layer.transformer import *  # noqa: F401,F403
 from .layer.rnn import *  # noqa: F401,F403
 from .layer.experts import DroplessExperts  # noqa: F401
+from .layer.kda import KimiDeltaAttention  # noqa: F401
 from .layer.mamba import Mamba2Mixer  # noqa: F401
 from . import quant  # noqa: F401
 

@@ -13,8 +13,10 @@ sorted by expert into tiles of ``tm`` rows, every expert's rows starting on
 a tile boundary, and ONE grouped product runs over them (the Pallas kernel
 ``moe_grouped_*`` on the TPU, a gathered batched product elsewhere); the
 results are gathered back per token and summed with their weights.
-Experts and shared expert are ``down(relu(up(x))**2)``: two matrices, no
-gate, no bias.
+``form`` is the experts' and the shared expert's alike, no bias either way:
+``relu2``, ``down(relu(up(x))**2)``, two matrices; or ``swiglu``,
+``down(silu(gate(x)) * up(x))``, three, the gate stacked on the up matrix
+(``up [held, 2 width, hidden]``) so that one gated product reads both.
 
 ``counts`` (int32 ``[4]``) is what the serving tick records: tokens routed,
 pairs that fell on a held expert, rows of the busiest held expert, held
@@ -94,8 +96,10 @@ def _grouped(xs, w, tile_expert, n_active, tm, activation, transpose_rhs):
 
     k, n = w.shape[2 if transpose_rhs else 1], w.shape[1 if transpose_rhs
                                                        else 2]
-    if pallas.is_available() and K.supports_grouped(tm, k, n,
-                                                    w.dtype.itemsize):
+    fits = K.supports_gated(tm, k, n // 2, w.dtype.itemsize) \
+        if activation == "swiglu" \
+        else K.supports_grouped(tm, k, n, w.dtype.itemsize)
+    if pallas.is_available() and fits:
         return K.grouped_matmul_pallas(xs, w, tile_expert, n_active, tm,
                                        activation, transpose_rhs)
     with jax.named_scope("moe_grouped_xla"):
@@ -105,7 +109,7 @@ def _grouped(xs, w, tile_expert, n_active, tm, activation, transpose_rhs):
 
 @op("dropless_experts")
 def _dropless_experts(x, valid, gate_w, gate_b, up, down, *, lut, top_k,
-                      scale):
+                      scale, form="relu2"):
     """``x [T, h]`` -> ``(routed part [T, h], counts [4], chosen [T, k])``."""
     T, h = x.shape
     n_held = up.shape[0]
@@ -115,7 +119,7 @@ def _dropless_experts(x, valid, gate_w, gate_b, up, down, *, lut, top_k,
     token_of_row, dest, tile_expert, n_active, counts = dispatch(
         local, valid, n_held, tm)
     xs = x[token_of_row]
-    hidden = _grouped(xs, up, tile_expert, n_active, tm, "relu2", True)
+    hidden = _grouped(xs, up, tile_expert, n_active, tm, form, True)
     ys = _grouped(hidden, down, tile_expert, n_active, tm, None, False)
     ys = jnp.concatenate([ys, jnp.zeros((1, h), ys.dtype)])
     w = jnp.where(dest < ys.shape[0] - 1, w, 0.0)
@@ -133,15 +137,33 @@ def _relu2_mlp(x, up, down):
     return jnp.matmul(hid.astype(x.dtype), down)
 
 
+@op("swiglu_mlp")
+def _swiglu_mlp(x, gate_up, down):
+    """``gate_up [hidden, 2 width]``: the gate's columns, then the up's."""
+    hid = jnp.matmul(x, gate_up, preferred_element_type=F32)
+    n = hid.shape[-1] // 2
+    hid = jax.nn.silu(hid[..., :n]) * hid[..., n:]
+    return jnp.matmul(hid.astype(x.dtype), down)
+
+
+FORMS = {"relu2": (1, _relu2_mlp), "swiglu": (2, _swiglu_mlp)}
+
+
 class DroplessExperts(Layer):
     """``num_experts`` routed experts of which ``held`` (default: all) are
     computed here, ``top_k`` a token, plus one shared expert of
-    ``shared_width`` (0: none)."""
+    ``shared_width`` (0: none); ``form`` (``relu2`` or ``swiglu``) is the
+    MLP of both."""
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k, *,
                  held=None, shared_width=0, scale=1.0, dtype=None,
-                 init_std=0.02):
+                 init_std=0.02, form="relu2"):
         super().__init__()
+        if form not in FORMS:
+            raise ValueError(f"experts of form {form!r}: one of "
+                             f"{sorted(FORMS)}")
+        self.form = form
+        first, self._shared_mlp = FORMS[form]  # matrices of the first layer
         held = list(range(num_experts)) if held is None else list(held)
         if not held or len(set(held)) != len(held) or not all(
                 0 <= e < num_experts for e in held):
@@ -165,7 +187,7 @@ class DroplessExperts(Layer):
         # (as published), so that the 128-aligned hidden size is the minor
         # dimension of both and the TPU lays neither out transposed
         self.up = self.create_parameter(
-            [n, expert_width, hidden_size], dtype=dtype,
+            [n, first * expert_width, hidden_size], dtype=dtype,
             default_initializer=init)
         self.down = self.create_parameter(
             [n, expert_width, hidden_size], dtype=dtype,
@@ -173,7 +195,7 @@ class DroplessExperts(Layer):
         self.has_shared = bool(shared_width)
         if self.has_shared:
             self.shared_up = self.create_parameter(
-                [hidden_size, shared_width], dtype=dtype,
+                [hidden_size, first * shared_width], dtype=dtype,
                 default_initializer=init)
             self.shared_down = self.create_parameter(
                 [shared_width, hidden_size], dtype=dtype,
@@ -189,8 +211,10 @@ class DroplessExperts(Layer):
             else jnp.asarray(getattr(valid, "_value", valid)).reshape(b * s)
         out, counts, chosen = _dropless_experts(
             flat, ok, self.gate_weight, self.gate_bias, self.up, self.down,
-            lut=self._lut, top_k=self.top_k, scale=self.scale)
+            lut=self._lut, top_k=self.top_k, scale=self.scale,
+            form=self.form)
         if self.has_shared:
-            out = out + _relu2_mlp(flat, self.shared_up, self.shared_down)
+            out = out + self._shared_mlp(flat, self.shared_up,
+                                         self.shared_down)
         return (out.reshape([b, s, h]), counts,
                 chosen.reshape([b, s, self.top_k]))

@@ -19,7 +19,12 @@ A decode step has a handful of rows per expert, so the kernel is bound by
 the bytes of the weights of the experts that were hit, each read once
 (twice where an expert's rows span two tiles); a prefill bucket is bound by
 the MXU. ``activation="relu2"`` squares the rectified result in the
-epilogue (the experts' ``down(relu(up(x))**2)``).
+epilogue (the experts' ``down(relu(up(x))**2)``). ``activation="swiglu"``
+(``transpose_rhs`` only) takes ``w [E, 2 N, K]``, each expert's gate matrix
+stacked on its up matrix, both out-major: one weight block holds the same
+``tn`` rows of both, two accumulators take the two products and the epilogue
+writes ``silu(gate) * up`` (device op ``moe_grouped_swiglu``), so a gated
+expert's first layer is one pass over the rows and one over its weights.
 
 The backward pass is that of the XLA formulation (a gather of the tiles'
 weights and a batched product), which is also what runs off the TPU.
@@ -62,6 +67,9 @@ def supports_grouped(tm, K, N, itemsize=2):
 def _act(a, activation):
     if activation == "relu2":
         return jnp.square(jnp.maximum(a, 0.0))
+    if activation == "swiglu":  # [..., gate | up]
+        n = a.shape[-1] // 2
+        return jax.nn.silu(a[..., :n]) * a[..., n:]
     return a
 
 
@@ -136,6 +144,81 @@ def _grouped_call(xs, w, tile_expert, n_active, tm, activation,
     )(tile_expert, n_active, xs, w)
 
 
+def _gated_kernel(te_ref, na_ref, x_ref, w_ref, o_ref, gate_ref, up_ref, *,
+                  nk):
+    mi, ki = pl.program_id(0), pl.program_id(2)
+    dims = (((1,), (1,)), ((), ()))
+
+    @pl.when(ki == 0)
+    def _zero():
+        gate_ref[:] = jnp.zeros_like(gate_ref)
+        up_ref[:] = jnp.zeros_like(up_ref)
+
+    @pl.when(mi < na_ref[0])
+    def _accumulate():
+        x = x_ref[...]
+        gate_ref[:] += jax.lax.dot_general(x, w_ref[0, 0], dims,
+                                           preferred_element_type=F32)
+        up_ref[:] += jax.lax.dot_general(x, w_ref[0, 1], dims,
+                                         preferred_element_type=F32)
+
+    @pl.when(ki == nk - 1)
+    def _store():
+        gate = gate_ref[:]
+        o_ref[...] = (gate * jax.nn.sigmoid(gate) * up_ref[:]).astype(
+            o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _gated_call(xs, w, tile_expert, n_active, tm, interpret):
+    """``silu(xs @ gate^T) * (xs @ up^T)`` a tile, ``w [E, 2 N, K]``."""
+    M, K = xs.shape
+    E, N = w.shape[0], w.shape[1] // 2
+    tk, tn = pick_blocks(K, N, 2 * w.dtype.itemsize)
+    nk, nn = K // tk, N // tn
+
+    def x_map(mi, ni, ki, te, na):
+        on = mi < na[0]
+        return (jnp.where(on, mi, jnp.maximum(na[0] - 1, 0)),
+                jnp.where(on, ki, nk - 1))
+
+    def w_map(mi, ni, ki, te, na):
+        on = mi < na[0]
+        return (te[mi], 0, jnp.where(on, ni, nn - 1),
+                jnp.where(on, ki, nk - 1))
+
+    return pl.pallas_call(
+        functools.partial(_gated_kernel, nk=nk),
+        name="moe_grouped_swiglu",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(M // tm, nn, nk),
+            in_specs=[pl.BlockSpec((tm, tk), x_map),
+                      pl.BlockSpec((1, 2, tn, tk), w_map)],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda mi, ni, ki, te, na: (mi, ni)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), F32),
+                            pltpu.VMEM((tm, tn), F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, N), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret,
+        cost_estimate=pl.CostEstimate(
+            flops=int(4 * M * K * N),
+            bytes_accessed=int(w.size * w.dtype.itemsize
+                               + (M * K + M * N) * xs.dtype.itemsize),
+            transcendentals=int(M * N)),
+    )(tile_expert, n_active, xs, w.reshape(E, 2, N, K))
+
+
+def supports_gated(tm, K, N, itemsize=2):
+    """The gated product's gate: one block holds ``tn`` rows of BOTH
+    matrices, so it prices an element at twice its size."""
+    return supports_grouped(tm, K, N, 2 * itemsize)
+
+
 def grouped_matmul_xla(xs, w, tile_expert, tm, activation=None,
                        transpose_rhs=False):
     """The same product as XLA sees it: each tile against a gathered copy
@@ -154,6 +237,12 @@ def grouped_matmul_pallas(xs, w, tile_expert, n_active, tm, activation=None,
     rows; ``n_active`` (int32 ``[1]``) tiles are live."""
     from . import interpret_requested
 
+    if activation == "swiglu":
+        if not transpose_rhs:
+            raise ValueError("the gated product takes its stack out-major "
+                             "(transpose_rhs=True)")
+        return _gated_call(xs, w, tile_expert, n_active, int(tm),
+                           bool(interpret_requested()))
     return _grouped_call(xs, w, tile_expert, n_active, int(tm), activation,
                          bool(transpose_rhs), bool(interpret_requested()))
 

@@ -96,3 +96,17 @@ def time_limit(seconds, name):
 def _time_limit(request):
     with time_limit(LIMIT, request.node.nodeid):
         yield
+
+
+@pytest.fixture
+def counting():
+    """Telemetry on and empty for one test, off and empty again after it:
+    left on, it fails whichever file the worker runs next that expects it
+    off (``test_tracing.py``)."""
+    from paddle_tpu.profiler import telemetry
+
+    telemetry.enable()
+    telemetry.reset()
+    yield telemetry.get_telemetry()
+    telemetry.disable()
+    telemetry.reset()

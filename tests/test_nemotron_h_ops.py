@@ -120,7 +120,7 @@ def test_dispatch_starts_every_expert_on_a_tile_boundary():
 
 
 @pytest.mark.parametrize("sq", [1, 3])
-def test_grouped_kv_decode_attention(sq):
+def test_grouped_kv_decode_attention(sq, counting):
     # 4 query heads over 2 K/V heads against a static-shape cache
     rng = np.random.default_rng(sq)
     b, sk, h, hk, d = 3, 32, 4, 2, 16
@@ -129,7 +129,6 @@ def test_grouped_kv_decode_attention(sq):
     v = jnp.asarray(rng.normal(size=(b, sk, hk, d)), jnp.float32)
     pos = jnp.asarray(rng.integers(sq, sk - sq, (b, 1)) + np.arange(sq),
                       jnp.int32)
-    telemetry.enable()
     before = telemetry.get_telemetry().counters().get(
         "attn.decode_route.einsum_grouped", 0)
     out = F.scaled_dot_product_attention(q, k, v, attn_mask=LengthMask(pos),

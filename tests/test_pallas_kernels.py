@@ -622,4 +622,4 @@ def test_no_pallas_call_site_is_left_unnamed():
             calls += 1
             named += bool(re.match(r"\s*[\w.()=, ]+,\s*name=\"\w+\"",
                                    text[m.end():m.end() + 200]))
-    assert calls == 13 and named == calls
+    assert calls == 15 and named == calls

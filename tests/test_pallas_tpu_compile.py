@@ -133,3 +133,31 @@ def test_expert_and_state_kernels_compile_at_the_served_widths(preflight):
         assert f"ok   {program}" in out, out
     temps = [int(t) for t in re.findall(r"re-laid-out (\d+) bytes", out)]
     assert len(temps) == 2 and max(temps) < 64 * 1856 * 2688, (temps, out)
+
+
+def test_delta_rule_and_gated_expert_kernels_compile_at_the_served_widths(
+        preflight):
+    # the gated expert product (one block holds the same rows of the gate
+    # and of the up matrix; decode- and prefill-sized tiles) and the
+    # delta-rule state kernel pass Mosaic at hidden 4096, width 1280, 128
+    # slots of [64, 128, 128]; no kernel is handed a copy of a 0.84 GB
+    # expert stack, and the donated states (0.54 GB) are written in place
+    import re
+
+    out = preflight.stdout
+    lines = out.splitlines()
+    temps = {}
+    for program in ("gated experts up+down t128 tm16",
+                    "gated experts up+down t4096 tm128",
+                    "kda step b128 h64 128x128 donated"):
+        at = next((i for i, l in enumerate(lines)
+                   if l.startswith(f"ok   {program}")), None)
+        assert at is not None, out
+        temps[program] = [int(n) for n in re.findall(
+            r"(\d+) temporary bytes; (\d+) of (\d+) argument bytes aliased",
+            lines[at + 1])[0]]
+    stack = 40 * 2560 * 4096 * 2
+    assert all(t[0] < stack // 4 for t in temps.values()), temps
+    state = 128 * 64 * 128 * 128 * 4
+    temp, aliased, _ = temps["kda step b128 h64 128x128 donated"]
+    assert aliased == state and temp < state // 8, temps

@@ -1,15 +1,13 @@
-"""Nemotron-H through ``serving.GenerationEngine``: the cache the model
-declares (K/V beside recurrent state beside nothing), prefill in a padded
-bucket then decoding through it, slots decoded together and reused, what
-the tick records, what the engine refuses; and GPT-2 through the same
-declared cache."""
+"""Solar Open 2 through ``serving.GenerationEngine``: the cache the model
+declares (one entry a sublayer: K/V or KDA state, then the counts), prefill in
+a padded bucket then decoding through it, slots decoded together and reused,
+what the tick records, what the engine refuses."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.nn.functional import LengthMask
 from paddle_tpu.profiler import telemetry
 from paddle_tpu.serving import (GenerationEngine, RecurrentStateError,
@@ -18,7 +16,8 @@ from paddle_tpu.serving.kv_cache import (CountsView, DecodeView, KVCache,
                                          PrefillView, StateDecodeView,
                                          StatePrefillView)
 
-import nemotron_h_tiny as tiny
+import solar_open2_tiny as tiny
+from solar_open2_tiny import chunks_of_8  # noqa: F401 (autouse)
 
 MAX_LEN = 128
 
@@ -49,15 +48,15 @@ def _served_gap(named, cfg, prompt, tokens):
 
 def test_cache_is_what_the_model_declares(served, engine):
     kinds = [layer["kind"] for layer in served[1].cache_spec()]
-    assert kinds == ["state", "counts", "state", "kv", "counts"]
+    assert kinds == ["kv", "counts"] + ["state", "counts"] * 3
     cache = engine.cache
     assert [k is not None for k in cache.ks] == [k == "kv" for k in kinds]
     assert [s is not None for s in cache.states] \
         == [k == "state" for k in kinds]
-    assert cache.ks[3].shape == (3, MAX_LEN, 2, 16)  # K/V heads, not query
-    assert cache.states[0]["conv"].shape == (3, 3, 64 + 2 * 2 * 16)
-    assert cache.states[0]["ssm"].shape == (3, 8, 8, 16)
-    assert cache.states[0]["ssm"].dtype == jnp.float32
+    assert cache.ks[0].shape == (3, MAX_LEN, 2, 16)  # K/V heads, not query
+    assert cache.states[2]["conv"].shape == (3, 3, 3 * 64)
+    assert cache.states[2]["kda"].shape == (3, 4, 16, 16)
+    assert cache.states[2]["kda"].dtype == jnp.float32
     assert engine.has_state and len(engine.count_names) == 4
 
 
@@ -145,7 +144,7 @@ def test_slots_of_different_lengths_and_a_reused_slot(served, engine,
     fresh = GenerationEngine(model, max_batch=1, max_len=MAX_LEN)
     for r in later:
         assert fresh.generate(r.prompt, max_new_tokens=12) == r.tokens
-    # what the ticks recorded: 2 expert layers count every routed token
+    # what the ticks recorded: 4 expert sublayers count every routed token
     ticks = telemetry.get_telemetry().steps(kind="serve.tick",
                                             owner=sched.sched_id)
     both = lambda c, name: c.get(name, 0) + c.get(name + ".prefill", 0)
@@ -157,7 +156,7 @@ def test_slots_of_different_lengths_and_a_reused_slot(served, engine,
     routed = sum(c["moe.tokens_routed"] for c in counts)
     served_positions = sum(len(r.prompt) + len(r.tokens) - 1
                            for r in reqs + later)
-    assert routed == 2 * served_positions
+    assert routed == 4 * served_positions
     on_held = sum(c["moe.pairs_on_held"] for c in counts)
     assert 0 < on_held < 2 * routed  # top-2, half the experts held
     assert all(c["moe.busiest_expert_rows"] <= c["moe.pairs_on_held"]
@@ -165,6 +164,11 @@ def test_slots_of_different_lengths_and_a_reused_slot(served, engine,
                for c in counts)
     assert max(c["serve.state_live_slots"] for c in counts) == 3
     assert not engine._live.any()  # every slot released
+    # the routes the traced steps took, off the TPU: XLA's step, the
+    # grouped einsum over the cache, the vmapped dynamic_update_slice
+    counters = telemetry.get_telemetry().counters()
+    assert counters.get("kda.step_route.xla", 0) >= 3
+    assert "kda.step_route.kernel" not in counters
 
 
 @pytest.mark.parametrize("kw", [{"spec_k": 2}, {"prefill_chunk": 16}],
@@ -174,43 +178,12 @@ def test_recurrent_state_refuses_what_would_rewind_it(served, kw):
         GenerationEngine(served[1], max_batch=2, max_len=64, **kw)
 
 
-@pytest.fixture(scope="module")
-def gpt():
-    paddle.seed(0)
-    cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
-                    max_position_embeddings=64, hidden_dropout=0.0,
-                    attention_dropout=0.0)
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    return model
-
-
-def test_gpt_declares_its_kv_and_nothing_else(gpt):
-    assert gpt.cache_spec() == [{"kind": "kv", "heads": 4, "head_dim": 8,
-                                 "dtype": jnp.dtype("float32")}] * 2
-    eng = GenerationEngine(gpt, max_batch=2, max_len=32)
-    # the cache's leaves are what they were: the layers' K, their V, lengths
-    leaves = jax.tree_util.tree_leaves(eng.cache)
-    assert [a.shape for a in leaves] == [(2, 32, 4, 8)] * 4 + [(2,)]
-    assert eng.cache.states == (None, None)
-    assert not eng.has_state and eng.count_names == ()
-    # no counting layer: the step takes no live mask and returns bare tokens
-    args = eng.example_decode_args([3])
-    assert len(args) == 6
-    tok, _, cache = eng.decode_step(*args)
-    assert list(tok.shape) == [2] and isinstance(cache, KVCache)
-
-
-def test_gpt_through_the_declared_cache_serves_the_uncached_tokens(gpt):
-    eng = GenerationEngine(gpt, max_batch=2, max_len=32)
-    prompt = _prompt(3, 9)
-    prompt = [t % 64 for t in prompt]
-    served_tokens = eng.generate(prompt, max_new_tokens=12)
-    seq = list(prompt)
-    with paddle.no_grad():
-        full = jax.jit(lambda t: gpt(paddle.Tensor(t))._value)
-    for _ in range(12):  # the full forward pass, no cache, one token a time
-        ids = np.zeros((1, 32), np.int32)  # one shape: causal, so padding
-        ids[0, :len(seq)] = seq            # after a position cannot reach it
-        seq.append(int(np.argmax(full(ids)[0, len(seq) - 1])))
-    assert served_tokens == seq[len(prompt):]
+def test_decode_step_counts_its_routes_once_a_layer(served, counting):
+    # a fresh engine traces its decode step here: three KDA layers, one
+    # attention layer with grouped K/V heads, one row write
+    eng = GenerationEngine(served[1], max_batch=2, max_len=64)
+    eng.generate(_prompt(4, 9), max_new_tokens=3)
+    c = telemetry.get_telemetry().counters()
+    traces = c["kv.row_write_route.dus"]  # a step is traced more than once
+    assert traces >= 1 and c["attn.decode_route.einsum_grouped"] == traces
+    assert c["kda.step_route.xla"] == 3 * traces

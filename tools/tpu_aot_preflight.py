@@ -205,6 +205,34 @@ def kernel_programs(devs):
             _sds((1, 8, heads, hdim, state), f32, one),
             _sds((1, heads, hdim, state), f32, one))
 
+    # the delta-rule state kernel and the gated expert product at the
+    # widths the benchmark's Solar Open 2 cut serves: hidden 4096, gated
+    # experts of width 1280 (40 held, 8 a token), 128 slots of state [64,
+    # 128, 128]; the state donated, as every serving step donates it
+    from paddle_tpu.ops.pallas import kda_step
+
+    E, hid, wid = 40, 4096, 1280
+    for tokens, tm in ((128, 16), (4096, 128)):
+        tiles = -(-tokens * 8 // tm) + E
+        yield (f"gated experts up+down t{tokens} tm{tm}",
+               lambda tiles=tiles, tm=tm: jax.jit(
+                   lambda x, up, down, te, na: moe_grouped._grouped_call(
+                       moe_grouped._gated_call(x, up, te, na, tm, False),
+                       down, te, na, tm, None, False, False)).lower(
+                           _sds((tiles * tm, hid), bf, one),
+                           _sds((E, 2 * wid, hid), bf, one),
+                           _sds((E, wid, hid), bf, one),
+                           _sds((tiles,), jnp.int32, one),
+                           _sds((1,), jnp.int32, one)))
+    slots, heads, hdim = 128, 64, 128
+    nb = heads // kda_step.head_block(heads)
+    yield "kda step b128 h64 128x128 donated", lambda: jax.jit(
+        lambda c, v, S: kda_step._step_call(c, v, S, False),
+        donate_argnums=2).lower(
+            _sds((slots, nb, hdim, 4 * heads // nb), f32, one),
+            _sds((slots, nb, heads // nb, hdim), f32, one),
+            _sds((slots, heads, hdim, hdim), f32, one))
+
     # LN + flash inside one jit sharded over the 4-device mesh
     mesh = Mesh(np.array(devs), ("dp",))
     row = NamedSharding(mesh, P("dp"))
@@ -321,6 +349,18 @@ def step_programs(devs):
         if eng.chunk_step is not None:
             yield f"serve_prefill_chunk ({label})", lambda eng=eng: lower_step(
                 eng.chunk_step, eng.example_chunk_args([0]), place, place)
+    # the benchmark's Solar Open 2 cut as its cell serves it: 128 slots of
+    # 5,120 positions, the decode step and the largest bucket its traffic
+    # uses (6.6 GB of parameters and 4.4 GB of cache are built on this host)
+    eng = chip_smoke.build_delta_rule_engine(False, max_batch=128,
+                                             max_len=5120,
+                                             freeze_weights=False)
+    yield "serve_decode (delta-rule cut b128 x 5120)", lambda: lower_step(
+        eng.decode_step, eng.example_decode_args([1]), place, place)
+    yield "serve_prefill bucket 4096 (delta-rule cut)", lambda: lower_step(
+        eng.prefill_step, (np.zeros((1, 4096), np.int32), np.int32(1),
+                           np.int32(0), eng._example_cache([0])), place,
+        place)
 
 
 def main(argv=None):
@@ -372,6 +412,14 @@ def main(argv=None):
                 print(f"       outputs aliased to parameters: "
                       f"{' '.join(f'{o}<-{i}' for o, i in pairs) or 'none'}; "
                       f"copies of a cache buffer: {copies}", flush=True)
+        if compiled is not None and name.startswith(("gated experts",
+                                                     "kda step")):
+            # no copy of an expert stack (0.84 GB) or of the states (0.54
+            # GB): the stacks are read where they lie, the state in place
+            ma = compiled.memory_analysis()
+            print(f"       {ma.temp_size_in_bytes} temporary bytes; "
+                  f"{ma.alias_size_in_bytes} of {ma.argument_size_in_bytes} "
+                  f"argument bytes aliased", flush=True)
         if compiled is not None and name.startswith("moe grouped"):
             # both products contract over the stacks' minor dimension as the
             # TPU keeps them: a program that had to re-lay a stack out would
@@ -391,6 +439,16 @@ def main(argv=None):
                 print(f"       temp {ma.temp_size_in_bytes / 2**30:.2f} GiB, "
                       f"arguments {ma.argument_size_in_bytes / 2**30:.2f} GiB",
                       flush=True)
+            if compiled is not None and "delta-rule" in name:
+                # XLA ops (not the kernels, not bitcasts) whose result is a
+                # whole expert stack or every slot's state: a copy of one
+                copies = re.findall(
+                    r"= (?:bf16\[40,(?:2560|1280),4096\]|"
+                    r"f32\[128,64,128,128\])\S* "
+                    r"(?:copy|transpose|fusion|copy-start)\(",
+                    compiled.as_text())
+                print(f"       copies of an expert stack or of the states: "
+                      f"{len(copies)}", flush=True)
     if rep.failed:
         print(f"{len(rep.failed)} program(s) failed: {rep.failed}")
         return 1
