@@ -34,6 +34,7 @@ import contextlib
 import gc
 import json
 import math
+import os
 import re
 import sys
 import time
@@ -332,7 +333,7 @@ def leg_kernels(size):
 
     def compare(name, kernel, reference, args, n_grad):
         """Forward always; gradients w.r.t. the first ``n_grad`` args under a
-        fixed random cotangent."""
+        fixed random cotangent. Returns the kernel's output."""
         out = jax.jit(kernel)(*args)
         ct = rnd(out.shape, f32)
         with jax.default_matmul_precision("highest"):
@@ -356,6 +357,7 @@ def leg_kernels(size):
         for k, v in errs.items():
             tol = FWD_TOL if k == "fwd" else GRAD_TOL
             check(v <= tol, f"{name}: {k} error {v:.3e} over tolerance {tol}")
+        return out
 
     compare(f"fused_layer_norm [{b},{s},{size.hidden}] bf16",
             lambda x, g, be: _layer_norm_pallas.raw(x, g, be),
@@ -385,24 +387,55 @@ def leg_kernels(size):
         compare(f"flash_sdpa_cached sq{sq} sk{sk} b1 bf16", cached, dense,
                 (rnd((1, sq, h, d), bf), rnd((1, sk, h, d), bf),
                  rnd((1, sk, h, d), bf)), 0)
-    # the decode kernel as serving reaches it: every slot's one query row
-    # (verify: spec_k + 1 rows) over its own cache row, each slot at a length
-    # of its own: the first slot, a block boundary, the last slot, the rest
+    # the decode kernel as the serving cells' decode step reaches it (32
+    # slots of 1024 positions, GPT-2 large's 20 heads of 64; the rehearsal
+    # keeps its own heads): every slot's one query row (verify: spec_k + 1
+    # rows) over its own cache row, 10 slots live, each at a length of its
+    # own (the first position, a block boundary, the last positions, the
+    # rest), and 22 that hold no request (no valid key: q_pos -1), which the
+    # kernel neither fetches nor visits. Live rows: the dense reference's
+    # within a rounding, and BIT FOR BIT those of the kernel that visited
+    # every slot (tests/flash_decode_slot_grid.py); dead rows: zeros
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "tests"))
+    from flash_decode_slot_grid import flash_attention_decode_slot_grid
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.pallas.flash_decode import _decode_block
+
     db = 32
+    wh = 20 if size is FULL else h
     lens = jnp.asarray(np.random.RandomState(0).randint(16, sk // 2, (db,)),
                        jnp.int32).at[:3].set(jnp.asarray([0, 255, sk - 5]))
+    live = np.zeros((db,), bool)
+    live[[0, 1, 2, 5, 6, 9, 13, 14, 20, 31]] = True
     for sq in (1, 5):
-        mask = LengthMask(lens[:, None] + jnp.arange(sq, dtype=jnp.int32))
+        mask = LengthMask(jnp.where(
+            live[:, None], lens[:, None] + jnp.arange(sq, dtype=jnp.int32),
+            -1))
+        args = (rnd((db, sq, wh, d), bf), rnd((db, sk, wh, d), bf),
+                rnd((db, sk, wh, d), bf))
 
         def decode(q, k, v, mask=mask):
             return _sdpa_flash_decode.raw(q, k, v, mask.q_pos)
 
         def dense(q, k, v, mask=mask, sk=sk):
-            return _sdpa_raw.raw(q, k, v, mask.additive(sk, q.dtype))
+            out = _sdpa_raw.raw(q, k, v, mask.additive(sk, q.dtype))
+            return jnp.where(live[:, None, None, None], out, 0.0)
 
-        compare(f"flash_sdpa_decode sq{sq} sk{sk} b{db} bf16", decode, dense,
-                (rnd((db, sq, h, d), bf), rnd((db, sk, h, d), bf),
-                 rnd((db, sk, h, d), bf)), 0)
+        name = f"flash_sdpa_decode sq{sq} sk{sk} b{db} {wh}x{d} bf16"
+        got = np.asarray(compare(f"{name}, 22 slots dead", decode, dense,
+                                 args, 0), np.float32)
+        want = np.asarray(jax.jit(
+            lambda q, k, v, mask=mask: flash_attention_decode_slot_grid(
+                q, k, v, mask.q_pos,
+                block_k=_decode_block(sk, wh * d, 2),
+                interpret=pallas.interpret_requested()))(*args), np.float32)
+        same = bool(np.array_equal(got[live], want[live]))
+        zeros = not got[~live].any()
+        say(f"kernels: {name}: live rows equal to the slot-grid kernel's: "
+            f"{same}; dead rows zero: {zeros}")
+        check(same, f"{name}: a live row differs from the slot-grid kernel")
+        check(zeros, f"{name}: a dead slot's rows are not zero")
     # the row write as the serving cells' decode step reaches it (32 slots of
     # 1024 positions, GPT-2 large's 20 heads of 64; the rehearsal keeps its
     # own heads), each slot at a position of its own: the kernel and the
@@ -410,7 +443,6 @@ def leg_kernels(size):
     from paddle_tpu.ops.pallas.kv_row_write import kv_row_write
     from paddle_tpu.serving.kv_cache import _row_update
 
-    wh = 20 if size is FULL else h
     starts = lens.at[3:5].set(jnp.asarray([127, 128]))
     for rows in (1, 5):
         kv = [rnd((db, sk, wh, d), bf) for _ in range(2)]

@@ -7,7 +7,11 @@ query, and the blockwise XLA scan that took it instead copies every layer's
 K and V to float32, re-lays them out three times and visits all ``max_len``
 positions whatever the lengths say (168 of the 195 ms of a GPT-2 large
 decode step on the v5e). This kernel reads the cache ONCE, in its own dtype
-and in the layout the TPU keeps it in, and only up to the live length:
+and in the layout the TPU keeps it in, and only the blocks that hold a valid
+key: of a live slot those up to its position, of a dead slot none. A slot is
+dead when none of its query rows has a valid key (``q_pos < 0``): that is
+how the serving engine marks a slot that holds no request, whose length is
+stale and means nothing (``KVCache``), and its output rows are zeros:
 
 * **layout.** XLA:TPU stores a ``(batch, max_len, heads, head_dim)`` array
   whose ``head_dim`` is under the 128 lanes with ``max_len`` minor:
@@ -23,9 +27,15 @@ and in the layout the TPU keeps it in, and only up to the live length:
   with nothing but bitcasts between parameter, write, attention and result
   (``tools/tpu_aot_preflight.py`` compiles that program and
   ``tests/test_pallas_tpu_compile.py`` holds it to no copy of a buffer);
-* grid ``(batch, kv_block)``; the per-row positions are scalar-prefetched,
-  the K/V index map is clamped to the last live block (a repeated block
-  index is not fetched again) and dead blocks skip their compute;
+* the grid walks a list of the live (slot, block) pairs, slot by slot and
+  each slot's blocks in order (:func:`live_pairs`, built in XLA from the
+  positions alone, once a step; scalar-prefetched with the positions). The
+  grid keeps its static length ``batch x blocks``: the steps past the list
+  repeat the last pair, so no block index changes, nothing is fetched or
+  written back, and the body is skipped. On the v5e such a step costs 0.11
+  us and a fetched block 2.0 us (PERF.md, PR 35); a grid that ends with the
+  list (a dynamic bound) was measured too and costs each call ~40 us more
+  than the empty steps it saves;
 * all heads of a batch entry in one grid step, as one batched product
   ``[h, rows, d] x [h, d, block_k]`` for the scores and one
   ``[h, rows, block_k] x [h, d, block_k]^T`` for ``P.V``. The kernel is
@@ -80,23 +90,54 @@ def _live_bound(qpos_ref, bb, sq):
         jnp.maximum, [qpos_ref[bb * sq + i] for i in range(sq)])
 
 
-def _decode_fwd_kernel(qpos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                       l_ref, *, scale, block_k, sq):
-    """Online-softmax sweep of one batch entry's live KV blocks, every head
-    at once. ``q_ref`` is ``(1, h, ROWS, d)``, ``k_ref``/``v_ref`` are
-    ``(1, h, d, block_k)``. Key slot ``c`` attends to query row ``i`` iff
-    ``c <= q_pos[batch, i]``; the padding rows ``i >= sq`` attend to
+def live_pairs(qpos, block_k, n_blocks):
+    """The (slot, block) pairs the kernel visits, slot by slot and each
+    slot's blocks in order, built in XLA from ``qpos`` ``(b, sq)`` alone (the
+    same for every layer of a step: XLA keeps one copy). A slot whose largest
+    position is ``p >= 0`` owns blocks ``0 .. p // block_k``; a slot with no
+    valid key owns none. Returns ``(slot, block, count)``: two int32 lists of
+    the static length ``b * n_blocks`` and the number of pairs; entries past
+    ``count`` repeat the last pair (the last slot's block 0 when there is
+    none), so a grid step there changes no block index and fetches
     nothing."""
-    f32 = jnp.float32
-    bb, ki = pl.program_id(0), pl.program_id(1)
+    b = qpos.shape[0]
+    bound = jnp.max(qpos, axis=1)
+    owned = jnp.where(bound >= 0, bound // block_k + 1, 0)
+    ends = jnp.cumsum(owned)
+    count = ends[-1]
+    step = jnp.minimum(jnp.arange(b * n_blocks, dtype=jnp.int32),
+                       jnp.maximum(count - 1, 0))
+    # the slots that end at or before a step are those before its own
+    before = ends[None, :] <= step[:, None]
+    slot = jnp.minimum(jnp.sum(before, axis=1), b - 1)
+    block = step - jnp.sum(jnp.where(before, owned[None, :], 0), axis=1)
+    return (slot.astype(jnp.int32), block.astype(jnp.int32),
+            count.astype(jnp.int32).reshape(1))
 
-    @pl.when(ki == 0)
+
+def _decode_fwd_kernel(qpos_ref, slot_ref, block_ref, count_ref, q_ref,
+                       k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale,
+                       block_k, sq):
+    """Online-softmax sweep over the live (slot, block) pairs, every head at
+    once: grid step ``t`` holds block ``block_ref[t]`` of slot
+    ``slot_ref[t]``. ``q_ref`` is ``(1, h, ROWS, d)``, ``k_ref``/``v_ref``
+    are ``(1, h, d, block_k)``. Key slot ``c`` attends to query row ``i``
+    iff ``c <= q_pos[batch, i]``; the padding rows ``i >= sq`` attend to
+    nothing. A slot's output block is written with its last pair; a slot
+    that owns no pair is never visited and its rows are the caller's to
+    zero."""
+    f32 = jnp.float32
+    t = pl.program_id(0)
+    bb, ki = slot_ref[t], block_ref[t]
+    listed = t < count_ref[0]
+
+    @pl.when(listed & (ki == 0))
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ki * block_k <= _live_bound(qpos_ref, bb, sq))
+    @pl.when(listed)
     def _body():
         s = jax.lax.dot_general(
             q_ref[0], k_ref[0], (((2,), (1,)), ((0,), (0,))),
@@ -118,7 +159,7 @@ def _decode_fwd_kernel(qpos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(ki == pl.num_programs(1) - 1)
+    @pl.when(listed & (ki == _live_bound(qpos_ref, bb, sq) // block_k))
     def _finish():
         l = l_ref[:, :, 0:1]
         o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
@@ -128,27 +169,28 @@ def _decode_fwd_kernel(qpos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_decode(q, k, v, qpos, scale, block_k, interpret):
     """``q`` ``(b, h, ROWS, d)``, ``k``/``v`` ``(b, h, d, sk)``, ``qpos``
-    ``(b, sq)``; returns ``(b, h, ROWS, d)``."""
+    ``(b, sq)``; returns ``(b, h, ROWS, d)``, zeros for a slot with no valid
+    key."""
     b, h, _, d = q.shape
     sk = k.shape[3]
     sq = qpos.shape[1]
+    slot, block, count = live_pairs(qpos, block_k, sk // block_k)
 
-    # index maps take the scalar-prefetch ref as a trailing argument
-    def qmap(bb, ki, qpos_ref):
-        return (bb, 0, 0, 0)
+    # index maps take the scalar-prefetch refs as trailing arguments
+    def qmap(t, qpos_ref, slot_ref, block_ref, count_ref):
+        return (slot_ref[t], 0, 0, 0)
 
-    def kvmap(bb, ki, qpos_ref):
-        last = jnp.maximum(_live_bound(qpos_ref, bb, sq), 0) // block_k
-        return (bb, 0, 0, jnp.minimum(ki, last))
+    def kvmap(t, qpos_ref, slot_ref, block_ref, count_ref):
+        return (slot_ref[t], 0, 0, block_ref[t])
 
     kernel = functools.partial(_decode_fwd_kernel, scale=scale,
                                block_k=block_k, sq=sq)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         name="flash_decode_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, sk // block_k),
+            num_scalar_prefetch=4,
+            grid=(slot.shape[0],),
             in_specs=[
                 pl.BlockSpec((1, h, ROWS, d), qmap),
                 pl.BlockSpec((1, h, d, block_k), kvmap),
@@ -163,7 +205,7 @@ def _flash_decode(q, k, v, qpos, scale, block_k, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=int(4 * b * h * ROWS * sk * d),
@@ -171,7 +213,10 @@ def _flash_decode(q, k, v, qpos, scale, block_k, interpret):
                                + 2 * q.size * q.dtype.itemsize),
             transcendentals=int(b * h * ROWS * sk),
         ),
-    )(qpos.reshape(b * sq), q, k, v)
+    )(qpos.reshape(b * sq), slot, block, count, q, k, v)
+    # the rows of a slot the grid never visited are whatever the buffer held
+    dead = jnp.max(qpos, axis=1) < 0
+    return jnp.where(dead[:, None, None, None], jnp.zeros((), out.dtype), out)
 
 
 def _flash_decode_vjp_fwd(q, k, v, qpos, scale, block_k, interpret):

@@ -229,8 +229,10 @@ class GenerationEngine:
         self.cache_dtype = None if cache_dtype is None \
             else jnp.dtype(cache_dtype)
         self.cache = self._alloc_cache()
-        #: slots that hold a request (prefilled, not yet released): whose
-        #: tokens a counting layer counts, whose state is live
+        #: slots that hold a request (prefilled, not yet released): the
+        #: decode and verify steps are handed this mask, a slot outside it
+        #: has no valid key for their attention and keeps its length, and
+        #: its tokens do not count as routed nor its state as live
         self._live = np.zeros((self.max_batch,), bool)
         if freeze_weights == "auto":
             freeze_weights = jax.default_backend() == "cpu"
@@ -300,24 +302,28 @@ class GenerationEngine:
             return Tensor(tokens)
         return Tensor(jnp.concatenate([tokens.reshape(-1), counts]))
 
-    def _unpack(self, packed, n_tokens, state_live=None):
+    def _unpack(self, packed, n_tokens, live_slots=None):
         """Host side of :meth:`_pack`: the tokens; the counts are filed in
         the open serving tick's record (``Telemetry.add_count``), a decode
         step's under the layers' names, a prefill's under ``<name>.prefill``
-        (``state_live`` is given by decode alone)."""
+        (``live_slots`` is given by decode alone)."""
         out = np.asarray(_leaf(packed)).reshape(-1)
         if _telemetry.enabled():
             tm = _telemetry.get_telemetry()
-            suffix = ".prefill" if state_live is None else ""
+            suffix = ".prefill" if live_slots is None else ""
             for name, n in zip(self.count_names, out[n_tokens:]):
                 tm.add_count(name + suffix, int(n))
-            if state_live is not None and self.has_state:
-                tm.add_count("serve.state_live_slots", int(state_live))
+            if live_slots is not None:
+                # of max_batch slots, those the step's attention visited
+                tm.add_count("serve.decode_live_slots", int(live_slots))
+                if self.has_state:
+                    tm.add_count("serve.state_live_slots", int(live_slots))
         return out[:n_tokens]
 
     def release_slot(self, slot):
         """The scheduler's word that ``slot`` holds no request any more:
-        its tokens stop counting as routed, its state as live."""
+        the decode step's attention stops reading its rows, its length stops
+        advancing, its tokens stop counting as routed, its state as live."""
         self._live[int(slot)] = False
 
     # -- traced step bodies --------------------------------------------------
@@ -401,20 +407,21 @@ class GenerationEngine:
         model = self.model
         max_len = self.max_len
 
-        def serve_decode(tokens, cache, keys, temps, top_ks, top_ps,
-                         live=None):
+        def serve_decode(tokens, cache, keys, temps, top_ks, top_ps, live):
             # tokens [max_batch, 1] int32 — each slot's last token, fed at
             # that slot's own position; shapes NEVER vary step to step.
-            # ``live [max_batch]`` (only a model whose layers count is
-            # handed it): the slots that hold a request
+            # ``live [max_batch]`` bool: the slots that hold a request
             ln = _leaf(cache.lengths).astype(jnp.int32)
+            lv = _leaf(live)
             pos = jnp.minimum(ln, max_len - 1)  # [b]
             # each slot's single query row sits at its own position; keys
-            # j <= pos[b] are valid — no [b, 1, 1, max_len] mask tensor
-            lmask = LengthMask(pos[:, None])
+            # j <= pos[b] are valid — no [b, 1, 1, max_len] mask tensor. A
+            # slot without a request has no valid key: its attention reads
+            # nothing of the cache and gives zeros
+            lmask = LengthMask(jnp.where(lv, pos, -1)[:, None])
             views = self._views(
                 cache, lambda k, v: DecodeView(k, v, pos), StateDecodeView,
-                None if live is None else _leaf(live)[:, None])
+                lv[:, None])
             logits, views = model(
                 tokens, position_ids=Tensor(pos[:, None]),
                 attn_mask=lmask, cache=views)
@@ -424,7 +431,10 @@ class GenerationEngine:
             next_tok, new_keys = _sample_next(
                 last, _leaf(keys), _leaf(temps),
                 _leaf(top_ks), _leaf(top_ps))
-            new_cache, counts = self._collect(views, Tensor(ln + 1))
+            # a slot without a request keeps its length (and so the row
+            # its write lands on, which the next prefill starts over)
+            new_cache, counts = self._collect(
+                views, Tensor(ln + lv.astype(jnp.int32)))
             return self._pack(next_tok, counts), Tensor(new_keys), new_cache
 
         return serve_decode
@@ -434,7 +444,8 @@ class GenerationEngine:
         max_len = self.max_len
         W = self.spec_k + 1
 
-        def serve_verify(tokens, cache, keys, temps, top_ks, top_ps):
+        def serve_verify(tokens, cache, keys, temps, top_ks, top_ps,
+                         live):
             # tokens [max_batch, W] int32 — window = [last committed
             # token, k drafts]; each slot's window sits at its OWN
             # positions ln..ln+W-1. K/V for all W positions are written
@@ -450,8 +461,9 @@ class GenerationEngine:
             offs = jnp.arange(W, dtype=jnp.int32)
             pos = pos0[:, None] + offs[None, :]  # [b, W]
             # window row i of slot b queries position pos[b, i]; keys
-            # j <= pos[b, i] are valid — no [b, 1, W, max_len] mask tensor
-            lmask = LengthMask(pos)
+            # j <= pos[b, i] are valid — no [b, 1, W, max_len] mask tensor;
+            # a slot without a request has no valid key, as in serve_decode
+            lmask = LengthMask(jnp.where(_leaf(live)[:, None], pos, -1))
             views = self._views(
                 cache, lambda k, v: DecodeView(k, v, pos0), None, None)
             logits, views = model(
@@ -631,10 +643,9 @@ class GenerationEngine:
                 self.max_batch, 1)
             self._declare_variants()
             _inject.check("serve.decode")  # pre-donation: retry-safe
-            live = (self._live.copy(),) if self.count_names else ()
             tok, keys, cache = self._decode_step(
                 feed, self.cache, self._keys, self._temps,
-                self._top_ks, self._top_ps, *live)
+                self._top_ks, self._top_ps, self._live.copy())
             self.cache = cache
             self._keys = _leaf(keys)
         # the one blocking wait of a tick: the host sits here while the
@@ -666,7 +677,7 @@ class GenerationEngine:
                                    attrs={"window": w}):
             greedy, tok0, keys, cache = self._verify_step(
                 feed, self.cache, self._keys, self._temps,
-                self._top_ks, self._top_ps)
+                self._top_ks, self._top_ps, self._live.copy())
         self.cache = cache
         self._keys = _leaf(keys)
         with _telemetry.phase_span("serve.verify_readback"):
@@ -701,7 +712,9 @@ class GenerationEngine:
         return out
 
     def lengths(self):
-        """Per-slot cached-token counts (host numpy)."""
+        """Per-slot cached-token counts (host numpy). A slot without a
+        request keeps the count it was released with until the next prefill
+        into it starts over."""
         return np.asarray(_leaf(self.cache.lengths))
 
     def predicted_footprints(self, refresh=False):
@@ -790,15 +803,14 @@ class GenerationEngine:
 
     def example_decode_args(self, lengths):
         """A shape-faithful ``(tokens, cache, keys, temps, top_ks,
-        top_ps)`` example batch for static lint: fresh (non-donated)
+        top_ps, live)`` example batch for static lint: fresh (non-donated)
         cache buffers with the given per-slot lengths. Two consecutive
         positions lint identically — that IS the O(1) contract the
         ``kv-cache-concat`` rule checks."""
         tokens = np.zeros((self.max_batch, 1), np.int32)
-        live = (np.ones((self.max_batch,), bool),) if self.count_names \
-            else ()
         return (tokens, self._example_cache(lengths),
-                *self._example_sampling_args(), *live)
+                *self._example_sampling_args(),
+                np.ones((self.max_batch,), bool))
 
     def example_verify_args(self, lengths):
         """Shape-faithful example batch for linting the speculative
@@ -808,7 +820,8 @@ class GenerationEngine:
             raise RuntimeError("engine was built with spec_k=0")
         tokens = np.zeros((self.max_batch, self.spec_k + 1), np.int32)
         return (tokens, self._example_cache(lengths),
-                *self._example_sampling_args())
+                *self._example_sampling_args(),
+                np.ones((self.max_batch,), bool))
 
     def example_chunk_args(self, lengths, off=0):
         """Shape-faithful ``(tokens, chunk_len, off, slot, cache)``

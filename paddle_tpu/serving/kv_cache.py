@@ -107,6 +107,11 @@ class KVCache:
 
     ``lengths[i]`` is the number of valid cached tokens in batch slot ``i``.
     K/V rows beyond it are garbage by contract (masked until overwritten).
+    A dead slot (one that holds no request: never prefilled, or released)
+    keeps the length it was left with, and that length means nothing: the
+    engine hands the decode and verify steps its mask of live slots, a dead
+    slot's query has no valid key (``LengthMask.q_pos`` −1) whatever its
+    length says, and the next prefill into the slot sets the length anew.
     A recurrent state has no such mask — a stale one would be USED — so a
     prefill always starts a slot's state from zeros
     (:class:`StatePrefillView`) and overwrites what the last request left.

@@ -194,9 +194,10 @@ def test_gpt_declares_its_kv_and_nothing_else(gpt):
     assert [a.shape for a in leaves] == [(2, 32, 4, 8)] * 4 + [(2,)]
     assert eng.cache.states == (None, None)
     assert not eng.has_state and eng.count_names == ()
-    # no counting layer: the step takes no live mask and returns bare tokens
+    # no counting layer: the step returns bare tokens; the live mask is the
+    # attention's, handed to every model's step
     args = eng.example_decode_args([3])
-    assert len(args) == 6
+    assert len(args) == 7 and args[-1].dtype == bool
     tok, _, cache = eng.decode_step(*args)
     assert list(tok.shape) == [2] and isinstance(cache, KVCache)
 

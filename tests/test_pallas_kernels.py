@@ -420,6 +420,61 @@ def test_flash_decode_verify_window_and_kv_len(sq, with_kv_len):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
+_DEAD = {"first": range(0, 9), "middle": range(11, 23),
+         "last": range(20, 32), "all": range(32)}
+
+
+@pytest.mark.parametrize("with_kv_len", [False, True],
+                         ids=["no_kv_len", "kv_len"])
+@pytest.mark.parametrize("sq", [1, 5, 8])
+@pytest.mark.parametrize("dead", list(_DEAD))
+def test_flash_decode_dead_slots(dead, sq, with_kv_len):
+    """The serving engine's 32 slots, some without a request (``q_pos`` −1
+    in every row): the grid visits the live (slot, block) pairs alone,
+    wherever the dead slots lie. A live slot's rows are BIT FOR BIT what the
+    kernel that visited every slot gave (``flash_decode_slot_grid``); a dead
+    slot's rows are exactly zero, never what the output buffer held."""
+    from flash_decode_slot_grid import flash_attention_decode_slot_grid
+    from paddle_tpu.ops.pallas.flash_decode import (flash_attention_decode,
+                                                    live_pairs)
+
+    b, sk, block_k = 32, 384, 128
+    q, _, _ = _rand_qkv(b=b, s=sq, seed=7)
+    _, k, v = _rand_qkv(b=b, s=sk, seed=8)
+    rng = np.random.RandomState(9)
+    pos0 = rng.randint(0, sk - sq + 1, b).astype(np.int32)
+    pos0[:4] = [0, block_k - 1, block_k, sk - sq]  # on the blocks' edges
+    q_pos = pos0[:, None] + np.arange(sq, dtype=np.int32)[None, :]
+    is_dead = np.zeros(b, bool)
+    is_dead[list(_DEAD[dead])] = True
+    q_pos[is_dead] = -1
+    kv_len = None
+    if with_kv_len:  # some bounds cut below the positions, none kills a slot
+        kv_len = np.maximum(1, pos0 + sq - rng.randint(0, 3, b) * 100
+                            ).astype(np.int32)
+    with pallas.interpret_mode():
+        out = np.asarray(flash_attention_decode(q, k, v, q_pos, kv_len,
+                                                block_k=block_k))
+    want = np.asarray(flash_attention_decode_slot_grid(
+        q, k, v, q_pos, kv_len, block_k=block_k))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[~is_dead], want[~is_dead])
+    np.testing.assert_array_equal(out[is_dead], np.zeros_like(out[is_dead]))
+    # the list the grid walks: one pair a live block, slot by slot, and past
+    # its end the last pair again (no block index changes: nothing fetched)
+    bound = q_pos.max(axis=1) if kv_len is None else np.minimum(
+        q_pos.max(axis=1), kv_len - 1)
+    pairs = [(i, j) for i in range(b) if not is_dead[i]
+             for j in range(bound[i] // block_k + 1)]
+    slot, block, count = live_pairs(
+        jnp.asarray(np.where(is_dead[:, None], -1, bound[:, None])), block_k,
+        sk // block_k)
+    assert int(count[0]) == len(pairs) and slot.shape == (b * 3,)
+    got = list(zip(np.asarray(slot).tolist(), np.asarray(block).tolist()))
+    assert got[:len(pairs)] == pairs
+    assert set(got[len(pairs):]) <= {pairs[-1] if pairs else (b - 1, 0)}
+
+
 def test_flash_decode_refuses_what_it_cannot_tile():
     from paddle_tpu.ops.pallas.flash_decode import (flash_attention_decode,
                                                     supports_decode)

@@ -319,6 +319,134 @@ def test_scheduler_matches_single_request_generate(
         assert r.ttft_s is not None and r.latency_s is not None
 
 
+def test_dead_slots_are_masked_out_and_cost_a_live_request_nothing(
+        _no_persistent_compile_cache):
+    """A request served beside a freed slot and two never-used ones gives
+    the tokens it gives alone, before and after its neighbours have sat
+    through more than 2 x max_len decode steps without a request. In every
+    decode step the attention mask says "no valid key" (``q_pos`` −1) for
+    exactly the slots that hold no request, and such a slot's length stays
+    where its request left it."""
+    import jax
+    from mask_spy import MaskSpy
+
+    model = _gpt(seed=4, max_pos=32)
+    seen = []
+    eng = GenerationEngine(MaskSpy(model, seen), max_batch=4, max_len=32,
+                           prefill_buckets=(8,))
+    rng = np.random.RandomState(2)
+    prompt = rng.randint(0, 97, size=6).tolist()
+
+    def serve(slot, p, new):
+        """Serve ``p`` in ``slot`` through the engine's own steps; the mask
+        of every decode step is held against the live slots."""
+        out = [eng.prefill(slot, p)]
+        feed = np.zeros((4,), np.int32)
+        for _ in range(new - 1):
+            feed[slot] = out[-1]
+            live, before, n = eng._live.copy(), eng.lengths(), len(seen)
+            out.append(int(eng.decode_once(feed)[slot]))
+            jax.effects_barrier()
+            (q_pos,) = seen[n:]
+            assert ((q_pos[:, 0] == -1) == ~live).all(), (q_pos, live)
+            assert (q_pos[live, 0] == before[live]).all()
+            assert (eng.lengths() == before + live).all()
+        return out
+
+    serve(1, rng.randint(0, 97, size=5).tolist(), 4)
+    eng.release_slot(1)               # slot 1 freed; 2 and 3 never used
+    first = serve(0, prompt, 12)
+    assert first == _greedy_uncached(model, prompt, first)
+    eng.release_slot(0)
+    idle = 0
+    while idle <= 2 * eng.max_len:    # other requests come and go in slot 0
+        serve(0, rng.randint(0, 97, size=7).tolist(), 20)
+        eng.release_slot(0)
+        idle += 19
+    assert eng.lengths()[1:].tolist() == [5 + 3, 0, 0]
+    assert serve(0, prompt, 12) == first
+
+
+def test_decode_kernel_serves_a_request_among_dead_slots_as_alone(
+        _no_persistent_compile_cache, monkeypatch):
+    """The same through the decode attention KERNEL (interpreted; heads of
+    64 over a 256-slot cache, the threshold of the long-cache routes moved
+    down to it): a request in slot 2, between a never-used slot, a freed
+    one and another never-used one, is served token for token as an engine
+    of one slot serves it."""
+    from paddle_tpu.nn.functional import attention
+    from paddle_tpu.ops import pallas
+
+    monkeypatch.setattr(attention, "BLOCKWISE_MIN_KV", 256)
+
+    with unique_name.guard():
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
+            max_position_embeddings=256, hidden_dropout=0.0,
+            attention_dropout=0.0, initializer_range=0.6))
+    model.eval()
+    rng = np.random.RandomState(5)
+    prompt = rng.randint(0, 512, size=9).tolist()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        with pallas.interpret_mode():
+            eng = GenerationEngine(model, max_batch=4, max_len=256,
+                                   prefill_buckets=(16,))
+            feed = np.zeros((4,), np.int32)
+            feed[1] = eng.prefill(1, rng.randint(0, 512, size=5).tolist())
+            for _ in range(3):
+                feed[1] = eng.decode_once(feed)[1]
+            eng.release_slot(1)
+            got = [eng.prefill(2, prompt)]
+            for _ in range(15):
+                feed[:] = 0
+                feed[2] = got[-1]
+                got.append(int(eng.decode_once(feed)[2]))
+            alone = GenerationEngine(model, max_batch=1, max_len=256,
+                                     prefill_buckets=(16,)).generate(
+                                         prompt, max_new_tokens=16)
+        routes = telemetry.get_telemetry().counters()
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert routes.get("attn.decode_route.flash_decode", 0) > 0, routes
+    assert len(set(got)) > 2, "degenerate model; parity is vacuous"
+    assert got == alone
+    assert eng.lengths().tolist() == [0, 5 + 3, 9 + 15, 0]
+
+
+def test_tick_record_counts_the_slots_the_decode_step_attended():
+    """``serve.decode_live_slots`` in a tick's record: of ``max_batch``
+    slots, those that held a request in the tick's decode step (the others
+    the attention neither fetched nor visited)."""
+    model = _gpt(max_pos=64)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        eng = GenerationEngine(model, max_batch=3, max_len=64,
+                               prefill_buckets=(8, 16))
+        sched = Scheduler(eng)
+        sched.submit(Request(prompt=[5, 6, 7], max_new_tokens=6))
+        sched.submit(Request(prompt=[8, 9], max_new_tokens=3))
+        live = []
+        while sched.queue or sched.active:
+            sched.step()
+            live.append(len(sched.active))
+        ticks = telemetry.get_telemetry().steps(kind="serve.tick",
+                                                owner=sched.sched_id)
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    counted = [t.counts.get("serve.decode_live_slots") for t in ticks]
+    assert len(counted) == len(live) and None not in counted
+    # a tick's decode step runs before the tick evicts what finished in it
+    assert max(counted) == 2 and min(counted) == 1
+    assert all(c >= n for c, n in zip(counted, live))
+    assert "serve.state_live_slots" not in ticks[0].counts  # no state here
+
+
 def test_scheduler_rejects_oversized_requests():
     model = _gpt(max_pos=64)
     eng = GenerationEngine(model, max_batch=2, max_len=32,

@@ -154,6 +154,42 @@ def test_spec_with_chunked_prefill_matches_plain(model):
     assert both == plain
 
 
+def test_spec_request_beside_dead_slots_is_served_as_alone(model):
+    """A short request frees slot 0 while a long one speculates on in slot
+    1 and slots 2 and 3 are never used: the long one's tokens are those a
+    plain engine serves it alone, and every verify and decode step's mask
+    says "no valid key" (``q_pos`` −1 in every window row) for exactly the
+    slots that held no request when the step was handed over."""
+    import jax
+    from mask_spy import MaskSpy
+
+    rng = np.random.RandomState(21)
+    # periodic prompts: the n-gram proposer drafts from the first tick
+    short = np.tile(rng.randint(0, 97, 3), 3)[:7].tolist()
+    long_ = np.tile(rng.randint(0, 97, 4), 3)[:10].tolist()
+    (alone,) = _serve(_engine(model, max_batch=1), _reqs([long_]))
+    seen, lives = [], []
+    eng = _engine(MaskSpy(model, seen), spec_k=4)
+    for name in ("decode_once", "verify_once"):
+        step = getattr(eng, name)
+        setattr(eng, name, lambda feed, step=step: (
+            lives.append(eng._live.copy()), step(feed))[1])
+    reqs = _reqs([short], max_new=4) + _reqs([long_])
+    got = _serve(eng, reqs)
+    jax.effects_barrier()
+    assert got[1] == alone and len(got[0]) == 4
+    masks = [m for m in seen if m.shape[0] == eng.max_batch]
+    assert len(masks) == len(lives) > 10
+    widths = {m.shape[1] for m in masks}  # verify windows; decode if any
+    assert 5 in widths and widths <= {1, 5}
+    for q_pos, live in zip(masks, lives):
+        assert ((q_pos == -1).all(axis=1) == ~live).all(), (q_pos, live)
+        assert (q_pos[live] >= 0).all()
+    assert sum(1 for live in lives if live.tolist() == [
+        False, True, False, False]) > 10  # the long one, alone among dead
+    assert not eng._live.any()
+
+
 def test_scheduler_speculative_false_forces_plain_path(model):
     rng = np.random.RandomState(13)
     prompts = [rng.randint(0, 97, 7).tolist() for _ in range(2)]

@@ -52,6 +52,33 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
+def cache_buffer_copies(text, batch):
+    """XLA ops (not the kernels, not bitcasts) of a compiled program whose
+    result is a whole bf16 K or V buffer of ``batch`` slots x 1024: a
+    re-layout, or the staging through VMEM that XLA's memory-space
+    assignment puts around an unpinned kernel."""
+    return len(re.findall(
+        rf"= \(*bf16\[{batch},(?:\d+,)*1024[,\d]*\]\S*,? [^=]*?"
+        r"(?:copy|transpose|fusion|copy-start|slice-start)\(", text))
+
+
+def pair_lists_built(text):
+    """How often a compiled program builds the decode attention's list of
+    live (slot, block) pairs (``flash_decode.live_pairs``: its cumulative
+    sum is the one ``reduce-window`` the kernel's wrapper holds). The list
+    depends on the step's positions alone, so once, however many layers."""
+    return len(re.findall(
+        r"reduce-window\([^\n]*op_name=\"[^\"]*jit\(_flash_decode\)", text))
+
+
+def decode_program_facts(text):
+    """The two things a compiled decode-shaped program at the serving cells'
+    batch is held to, as one line of the report."""
+    return (f"copies of a cache buffer: {cache_buffer_copies(text, 32)}; "
+            f"lists of live (slot, block) pairs built: "
+            f"{pair_lists_built(text)}")
+
+
 class Report:
     def __init__(self):
         self.failed = []
@@ -349,6 +376,19 @@ def step_programs(devs):
         if eng.chunk_step is not None:
             yield f"serve_prefill_chunk ({label})", lambda eng=eng: lower_step(
                 eng.chunk_step, eng.example_chunk_args([0]), place, place)
+    # the benchmark's gpt2-large as its two cells serve it: 36 layers, 32
+    # slots of 1,024 positions, the decode step (row write and attention
+    # kernels on all 72 donated buffers; the 8-layer program above is too
+    # small to show what XLA stages through VMEM at this depth)
+    large = chip_smoke.Size(vocab=50304, hidden=1280, layers=36, heads=20,
+                            max_len=1024, batch=0, seq=0)
+    from paddle_tpu.serving import GenerationEngine
+
+    eng = GenerationEngine(chip_smoke.build_model(large), max_batch=32,
+                           max_len=1024, freeze_weights=False)
+    yield "serve_decode (gpt2-large b32 x 1024)", lambda eng=eng: lower_step(
+        eng.decode_step, eng.example_decode_args([1]), place, place)
+    del eng
     # the benchmark's Solar Open 2 cut as its cell serves it: 128 slots of
     # 5,120 positions, the decode step and the largest bucket its traffic
     # uses (6.6 GB of parameters and 4.4 GB of cache are built on this host)
@@ -405,13 +445,9 @@ def main(argv=None):
                 text = compiled.as_text()
                 header = text[:text.index("\n")]
                 pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
-                copies = len(re.findall(
-                    r"= \(*bf16\[32,(?:\d+,)*1024[,\d]*\]\S*,? [^=]*?"
-                    r"(?:copy|transpose|fusion|copy-start|slice-start)\(",
-                    text))
                 print(f"       outputs aliased to parameters: "
                       f"{' '.join(f'{o}<-{i}' for o, i in pairs) or 'none'}; "
-                      f"copies of a cache buffer: {copies}", flush=True)
+                      f"{decode_program_facts(text)}", flush=True)
         if compiled is not None and name.startswith(("gated experts",
                                                      "kda step")):
             # no copy of an expert stack (0.84 GB) or of the states (0.54
@@ -438,6 +474,9 @@ def main(argv=None):
                 ma = compiled.memory_analysis()
                 print(f"       temp {ma.temp_size_in_bytes / 2**30:.2f} GiB, "
                       f"arguments {ma.argument_size_in_bytes / 2**30:.2f} GiB",
+                      flush=True)
+            if compiled is not None and "gpt2-large" in name:
+                print(f"       {decode_program_facts(compiled.as_text())}",
                       flush=True)
             if compiled is not None and "delta-rule" in name:
                 # XLA ops (not the kernels, not bitcasts) whose result is a
