@@ -14,6 +14,10 @@ bf16 + fp32 LayerNorm, AdamW with fp32 masters), weights random from a seed:
   (``spec_k=4, prefill_chunk=128``) — a dozen seeded requests in all, each
   engine's request set replayed through the same executables (tokens must
   repeat exactly), every fault / fallback / recompile counter at 0;
+* expert decoders: the benchmark's Solar Open 2 and Laguna cuts through
+  ``Scheduler`` at their published widths (delta-rule state; rotary
+  positions with rings of 512 K/V rows beside full-length rows), the routes
+  their traced steps took, their kernels against the XLA formulations;
 * dp4: when four devices are visible, the same trainer under
   ``build_mesh({"dp": 4})`` + ``ShardedOptimizer`` (fp32 wire, then int8),
   with per-device shard evidence and loss parity against the one-chip leg.
@@ -218,6 +222,33 @@ def build_delta_rule_engine(tiny, max_batch, max_len, **kw):
                                held_experts=tuple(range(40)), **cut)
     paddle.seed(0)
     return GenerationEngine(SolarOpen2ForCausalLM(cfg), max_batch=max_batch,
+                            max_len=max_len, **kw)
+
+
+def build_ring_engine(tiny, max_batch, max_len, **kw):
+    """The benchmark's Laguna cut through the normal constructor: the
+    published widths (the config's defaults), the first 13 layers ``F S S S
+    F S S S F S S S F`` (4 full, 9 window of 512), 32 of the 256 routed
+    experts, 12,544 vocabulary rows; ``tiny`` keeps the kinds, the window
+    and the 128-wide heads (so the same routes are taken) at a size the
+    interpreter finishes."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LagunaConfig, LagunaForCausalLM
+    from paddle_tpu.serving import GenerationEngine
+
+    if tiny:
+        cfg = LagunaConfig(
+            vocab_size=512, hidden_size=128, intermediate_size=256,
+            num_hidden_layers=13,
+            num_attention_heads_per_layer=(2, 4, 4, 4) * 10,
+            num_key_value_heads=1, num_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=128, shared_expert_intermediate_size=128,
+            held_experts=(0, 1, 2, 3))
+    else:
+        cfg = LagunaConfig(vocab_size=12544, num_hidden_layers=13,
+                           held_experts=tuple(range(32)))
+    paddle.seed(0)
+    return GenerationEngine(LagunaForCausalLM(cfg), max_batch=max_batch,
                             max_len=max_len, **kw)
 
 
@@ -624,6 +655,70 @@ def leg_delta_rule(tiny):
         check(err < 1e-4, f"kda_step {name} off the XLA step by {err:.2e}")
 
 
+def leg_ring(tiny):
+    """A decoder with rotary positions whose window layers keep a ring,
+    through ``Scheduler``: a prompt past the window in a bucket that runs
+    the banded kernel, decoding that wraps the ring; which routes the
+    traced steps took; and the banded kernel against the scan at a served
+    shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional import attention as A
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_cached
+    from paddle_tpu.profiler import telemetry
+    from paddle_tpu.serving import Request, Scheduler
+
+    slots = 4 if tiny else 48
+    tm = telemetry.get_telemetry()
+    before = dict(tm.counters())
+    eng = build_ring_engine(tiny, max_batch=slots, max_len=2048)
+    rings = [tuple(k.shape) for k in eng.cache.ks
+             if k is not None and k.shape[1] == 512]
+    check(len(rings) == 9 and eng.ring_windows == [512],
+          f"ring engine: expected 9 rings of 512 rows, got {rings}")
+    sched = Scheduler(eng)
+    rng = np.random.default_rng(0)
+    vocab = eng.model.cfg.vocab_size
+    reqs = [sched.submit(Request(prompt=rng.integers(0, vocab, n).tolist(),
+                                 max_new_tokens=m))
+            for n, m in ((9, 6), (500, 20), (1100, 5))]
+    sched.run()
+    check(all(len(r.tokens) == r.max_new_tokens for r in reqs),
+          "ring engine: a request was not served in full")
+    took = {k: v - before.get(k, 0) for k, v in tm.counters().items()
+            if k.startswith(("attn.cache_route", "kv.row_write_route",
+                             "attn.decode_route", "attn.prefill_band"))
+            and v != before.get(k, 0)}
+    say(f"ring engine: routes of the traced steps {took}")
+    traces = took.get("attn.cache_route.full", 0) // 4
+    check(traces >= 1 and took.get("attn.cache_route.ring") == 9 * traces
+          and took.get("kv.row_write_route.dus") == 13 * traces
+          and took.get("attn.decode_route.einsum_grouped") == 13 * traces
+          and took.get("attn.prefill_band.banded", 0) >= 9
+          and not any(k.startswith(("kv.row_write_route.column",
+                                    "attn.decode_route.flash"))
+                      for k in took),
+          f"ring engine: expected 4 full and 9 ring layers a decode trace, "
+          f"dus and the grouped einsum in all 13, the banded prefill in the "
+          f"window layers of the long bucket, got {took}")
+    del eng, sched
+    gc.collect()
+    h, d, n = (4, 128, 1024) if tiny else (64, 128, 2048)
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    q, k, v = (jax.random.normal(keys[i], (1, n, h, d), jnp.bfloat16)
+               for i in range(3))
+    pos = jnp.arange(n, dtype=jnp.int32)[None]
+    klen = jnp.asarray([n - 100], jnp.int32)
+    got = jax.jit(lambda q, k, v: flash_attention_cached(
+        q, k, v, pos, klen, window=512))(q, k, v)
+    want = jax.jit(lambda q, k, v: A._bw_fwd_banded(
+        q, k, v, pos, klen, 512, d ** -0.5, 256, 256))(q, k, v)
+    err = _rel_err(got[:, :n - 100], want[:, :n - 100])
+    say(f"flash_banded_fwd s{n} h{h} window 512: rel err {err:.2e}")
+    check(err < FWD_TOL, f"banded kernel off the scan by {err:.2e}")
+
+
 def leg_dp4(size, one_chip_losses):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -746,6 +841,8 @@ def main(argv=None):
             leg_serve(size, *leg)
             gc.collect()
         leg_delta_rule(args.rehearse)
+        gc.collect()
+        leg_ring(args.rehearse)
         gc.collect()
         leg_dp4(size, losses)
     say(f"memory_stats of device 0: {dev.memory_stats()}")

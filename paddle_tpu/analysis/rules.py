@@ -889,7 +889,10 @@ def _hbm_kv_bucket_waste(graph):
     [batch, max_len, heads, head_dim] buffers + an int32 [batch] lengths
     vector) whose example lengths round up to prefill buckets so much that
     ≥ ``kv_waste_fraction`` of the reserved rows are padding — shrink the
-    bucket ladder or max_len."""
+    bucket ladder or max_len. Of several groups (a cache that also keeps
+    rings of a window's rows, or 4-D recurrent state) the one with the most
+    rows a slot is the full-length K/V: a ring's rows stop at its window
+    whatever the bucket, and only that group's bytes are priced."""
     threshold = graph.config.get("kv_waste_fraction", 0.25)
     groups = {}
     for path, leaf, donated in graph.dyn_args:
@@ -909,10 +912,11 @@ def _hbm_kv_bucket_waste(graph):
                 lengths = (path, leaf)
         if lengths is None or not bufs:
             continue
-        (shape, dtype), paths = max(bufs.items(),
-                                    key=lambda kv: len(kv[1]))
-        if len(paths) < 2:
+        bufs = {key: paths for key, paths in bufs.items() if len(paths) > 1}
+        if not bufs:
             continue
+        (shape, dtype), paths = max(bufs.items(),
+                                    key=lambda kv: (kv[0][0][1], len(kv[1])))
         batch, max_len = int(shape[0]), int(shape[1])
         lpath, lleaf = lengths
         if tuple(getattr(lleaf, "shape", ())) != (batch,):
@@ -938,7 +942,7 @@ def _hbm_kv_bucket_waste(graph):
         waste = (reserved - sum(active)) / reserved if reserved else 0.0
         if waste < threshold:
             continue
-        group_bytes = sum(_nbytes(l) for _, l in leaves)
+        group_bytes = sum(_nbytes(l) for path, l in leaves if path in paths)
         per_row = group_bytes / float(batch * max_len) if batch * max_len \
             else 0.0
         wasted_bytes = (reserved - sum(active)) * per_row

@@ -19,6 +19,11 @@ from .solar_open2 import (  # noqa: F401
     SolarOpen2Model,
     SolarOpen2ForCausalLM,
 )
+from .laguna import (  # noqa: F401
+    LagunaConfig,
+    LagunaModel,
+    LagunaForCausalLM,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertModel,
@@ -52,6 +57,9 @@ __all__ = [
     "SolarOpen2Config",
     "SolarOpen2Model",
     "SolarOpen2ForCausalLM",
+    "LagunaConfig",
+    "LagunaModel",
+    "LagunaForCausalLM",
     "BertConfig",
     "BertModel",
     "BertForPretraining",

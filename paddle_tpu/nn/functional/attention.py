@@ -50,14 +50,24 @@ class LengthMask:
     ``scaled_dot_product_attention`` instead of a dense ``[b, 1, q, max_len]``
     additive mask: the blockwise/Pallas paths consume the lengths directly and
     the einsum fallback expands the mask on the fly in the compute dtype.
+
+    ``window`` (a static int; None: no lower bound, the meaning above) makes
+    it a band: additionally ``j > q_pos[b, i] - window``, the query's own
+    position and the ``window - 1`` before it. The blockwise scan and the
+    cached flash kernel then visit only the key blocks that meet the band;
+    the kernel sizes its sweep for query blocks whose rows stand at
+    consecutive positions (a prompt, a chunk), which is what it is handed.
     """
 
-    __slots__ = ("q_pos", "kv_len")
+    __slots__ = ("q_pos", "kv_len", "window")
 
-    def __init__(self, q_pos, kv_len=None):
+    def __init__(self, q_pos, kv_len=None, window=None):
         self.q_pos = jnp.asarray(q_pos, jnp.int32)
         self.kv_len = None if kv_len is None else jnp.asarray(kv_len,
                                                               jnp.int32)
+        if window is not None and int(window) < 1:
+            raise ValueError(f"window must be at least 1, got {window}")
+        self.window = None if window is None else int(window)
 
     def valid(self, sk):
         """Boolean ``[b, 1, q, sk]`` validity (broadcasts over heads)."""
@@ -65,6 +75,8 @@ class LengthMask:
         ok = col <= self.q_pos[:, None, :, None]
         if self.kv_len is not None:
             ok = ok & (col < self.kv_len[:, None, None, None])
+        if self.window is not None:
+            ok = ok & (col > self.q_pos[:, None, :, None] - self.window)
         return ok
 
     def additive(self, sk, dtype, mask_min=-1e9):
@@ -231,12 +243,76 @@ def _blockwise_vjp_bwd(scale, block_q, block_k, res, g):
 _blockwise.defvjp(_blockwise_vjp_fwd, _blockwise_vjp_bwd)
 
 
+def _bw_fwd_banded(q, k, v, q_pos, kv_len, window, scale, block_q, block_k):
+    """The scan under a band (``LengthMask.window``): query blocks outside,
+    key blocks inside, and a key block that no row of the query block can
+    see is passed over (``lax.cond`` on the block's positions, so any
+    ``q_pos`` is served right and a prompt's blocks visit the band alone).
+    Forward only; serving holds no gradients."""
+    f32 = jnp.float32
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    nq, nk = sq // block_q, sk // block_k
+
+    def blocks(x, n, size):  # [b, s, h, d] -> [n, b, h, size, d], float32
+        return jnp.moveaxis(
+            jnp.swapaxes(x, 1, 2).astype(f32).reshape(b, h, n, size, d), 2, 0)
+
+    qs = blocks(q, nq, block_q) * scale
+    ks, vs = blocks(k, nk, block_k), blocks(v, nk, block_k)
+    pos = jnp.moveaxis(q_pos.reshape(b, nq, block_q), 1, 0)  # [nq, b, bq]
+    base = jnp.arange(nk, dtype=jnp.int32) * block_k
+    klen_e = None if kv_len is None else kv_len[:, None, None, None]
+
+    def q_block(xs):
+        qb, pb = xs
+        hi = jnp.max(pb)  # the last key a row of this block sees
+        lo = jnp.min(jnp.where(pb >= 0, pb, hi)) - window + 1  # the first
+        pe = pb[:, None, :, None]
+
+        def visit(carry, kb, vb, b0):
+            m, l, acc = carry
+            s_ = jnp.einsum("bhqd,bhkd->bhqk", qb, kb)
+            col = b0 + jax.lax.broadcasted_iota(jnp.int32,
+                                                (1, 1, 1, block_k), 3)
+            ok = (col <= pe) & (col > pe - window)
+            if klen_e is not None:
+                ok = ok & (col < klen_e)
+            s_ = jnp.where(ok, s_, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s_, axis=-1))
+            p = jnp.where(ok, jnp.exp(s_ - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            return (m_new, l * alpha + jnp.sum(p, axis=-1),
+                    acc * alpha[..., None]
+                    + jnp.einsum("bhqk,bhkd->bhqd", p, vb))
+
+        def body(carry, ys):
+            kb, vb, b0 = ys
+            meets = (b0 <= hi) & (b0 + block_k > lo)
+            return jax.lax.cond(meets, lambda c: visit(c, kb, vb, b0),
+                                lambda c: c, carry), None
+
+        init = (jnp.full((b, h, block_q), NEG_INF, f32),
+                jnp.zeros((b, h, block_q), f32),
+                jnp.zeros((b, h, block_q, d), f32))
+        (_, l, acc), _ = jax.lax.scan(body, init, (ks, vs, base))
+        return acc / jnp.maximum(l, 1e-30)[..., None]
+
+    out = jax.lax.map(q_block, (qs, pos))                    # [nq,b,h,bq,d]
+    out = jnp.moveaxis(out, 0, 2).reshape(b, h, sq, d)
+    return jnp.swapaxes(out, 1, 2).astype(q.dtype)
+
+
 @op("blockwise_sdpa")
 def _sdpa_blockwise(q, k, v, q_pos, kv_len=None, scale=None, block_q=0,
-                    block_k=0):
+                    block_k=0, window=None):
     """Blockwise online-softmax attention (q,k,v in paddle (b,s,h,d)
-    layout). ``q_pos``/``kv_len`` follow :class:`LengthMask` semantics."""
+    layout). ``q_pos``/``kv_len``/``window`` follow :class:`LengthMask`
+    semantics."""
     s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if window is not None:
+        return _bw_fwd_banded(q, k, v, q_pos, kv_len, window, s, block_q,
+                              block_k)
     return _blockwise(q, k, v, q_pos, kv_len, s, block_q, block_k)
 
 
@@ -255,6 +331,26 @@ def _count_decode_route(route):
         telemetry.get_telemetry().inc(f"attn.decode_route.{route}")
 
 
+def prefill_band(route):
+    """What a windowed cached call of more than :data:`DECODE_ROWS` query
+    rows (a prefill bucket's window layer) got: ``banded`` where the route
+    passes over key blocks outside the band (the cached flash kernel, the
+    blockwise scan), ``dense`` where every key is scored and the band is a
+    mask (the einsum routes: buckets under ``BLOCKWISE_MIN_KV``, no
+    Pallas and few keys). Pure; table in ``tests/test_attention_window.py``."""
+    return "banded" if route in ("flash_cached", "blockwise") else "dense"
+
+
+def _count_prefill_band(route):
+    """Counter ``attn.prefill_band.<banded|dense>``, bumped when such a call
+    is TRACED, once a layer."""
+    from ...profiler import telemetry
+
+    if telemetry.enabled():
+        telemetry.get_telemetry().inc(
+            f"attn.prefill_band.{prefill_band(route)}")
+
+
 def _repeat_kv_heads(key, value, heads):
     """Each K/V head once per query head of its group (uncached calls and
     prefill buckets: a few MB; never the cache)."""
@@ -266,7 +362,8 @@ def _repeat_kv_heads(key, value, heads):
 
 
 @op("sdpa_grouped_decode")
-def _sdpa_grouped_decode(q, k, v, q_pos, kv_len=None, scale=None):
+def _sdpa_grouped_decode(q, k, v, q_pos, kv_len=None, scale=None,
+                         window=None):
     """A few query rows over a cache whose K/V heads each serve a GROUP of
     query heads: one einsum per product with the group as a batch
     dimension, the cache read in its own dtype and never repeated per
@@ -277,7 +374,8 @@ def _sdpa_grouped_decode(q, k, v, q_pos, kv_len=None, scale=None):
     qg = q.reshape(b, sq, hk, h // hk, d)
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32) * s
-    ok = LengthMask(q_pos, kv_len).valid(sk)[:, :, None]   # [b,1,1,q,sk]
+    # [b, 1, 1, q, sk]
+    ok = LengthMask(q_pos, kv_len, window).valid(sk)[:, :, None]
     probs = jax.nn.softmax(jnp.where(ok, logits, NEG_INF), axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
@@ -306,12 +404,14 @@ BLOCKWISE_BLOCK_K = 512
 
 def attention_route(*, batch, sq, sk, heads, kv_heads, head_dim, kv_itemsize,
                     cached, causal, mask_shape, mask_trainable, dropout,
-                    pallas, interpret):
+                    pallas, interpret, window=None):
     """The one place a route is chosen. Shape facts and the two things the
     platform tells (``pallas.is_available()``, ``pallas.interpret_requested()``)
     come in as arguments and no global state is read, so a test can ask what
     the chip compiles. ``cached`` says the mask is a :class:`LengthMask` (and
-    ``mask_shape`` is None); ``dropout`` that attention dropout is active.
+    ``mask_shape`` is None); ``dropout`` that attention dropout is active;
+    ``window`` is the mask's (``LengthMask.window``): every cached route
+    honours it but the decode-shaped kernel, which is then not chosen.
 
     Returns one of ``flash_packed``, ``flash``, ``flash_cached``,
     ``flash_decode``, ``einsum_grouped``, ``blockwise``, ``einsum``."""
@@ -333,7 +433,8 @@ def attention_route(*, batch, sq, sk, heads, kv_heads, head_dim, kv_itemsize,
         # every route below takes grouped K/V heads repeated per query head
         if not long_kv:
             return "einsum"
-        if pallas and supports_decode(sq, sk, heads, head_dim, kv_itemsize):
+        if pallas and window is None and supports_decode(
+                sq, sk, heads, head_dim, kv_itemsize):
             return "flash_decode"
         if pallas and supports_cached(sq, sk, head_dim):
             return "flash_cached"
@@ -399,13 +500,15 @@ def _sdpa_flash(q, k, v, mask=None, dropout_seed=None, causal=False,
 
 
 @op("flash_sdpa_cached")
-def _sdpa_flash_cached(q, k, v, q_pos, kv_len=None, scale=None):
+def _sdpa_flash_cached(q, k, v, q_pos, kv_len=None, scale=None, window=None):
     """Pallas length-masked (cached-attention) kernel — inference path; the
     per-tile validity comes from the streamed positions, never a dense
-    bias."""
+    bias. Under a ``window`` its banded form, which sweeps the key blocks
+    that meet each query block's band and no others."""
     from ...ops.pallas.flash_attention import flash_attention_cached
 
-    return flash_attention_cached(q, k, v, q_pos, kv_len, scale=scale)
+    return flash_attention_cached(q, k, v, q_pos, kv_len, scale=scale,
+                                  window=window)
 
 
 @op("flash_sdpa_decode")
@@ -456,6 +559,7 @@ def _sdpa(query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
     b, sq, h, d = query.shape
     sk = key.shape[1]
     cached = isinstance(attn_mask, LengthMask)
+    window = attn_mask.window if cached else None
     trainable = (not cached and attn_mask is not None
                  and getattr(attn_mask, "stop_gradient", True) is False)
     active_p = dropout_p if training else 0.0
@@ -465,12 +569,16 @@ def _sdpa(query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
         mask_shape=(None if cached or attn_mask is None
                     else tuple(attn_mask.shape)),
         mask_trainable=trainable, dropout=active_p > 0.0,
-        pallas=pallas.is_available(), interpret=pallas.interpret_requested())
+        pallas=pallas.is_available(), interpret=pallas.interpret_requested(),
+        window=window)
     if cached and sq <= DECODE_ROWS:
         _count_decode_route(route)
+    elif window is not None:
+        _count_prefill_band(route)
     if route == "einsum_grouped":
         return _sdpa_grouped_decode(query, key, value, attn_mask.q_pos,
-                                    attn_mask.kv_len, scale=scale)
+                                    attn_mask.kv_len, scale=scale,
+                                    window=window)
     if key.shape[2] != h:
         key, value = _repeat_kv_heads(key, value, h)
     if route in ("flash_packed", "flash"):
@@ -503,11 +611,13 @@ def _sdpa(query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
     if route == "flash_decode":
         return _sdpa_flash_decode(query, key, value, q_pos, kv_len, scale=s)
     if route == "flash_cached":
-        return _sdpa_flash_cached(query, key, value, q_pos, kv_len, scale=s)
+        return _sdpa_flash_cached(query, key, value, q_pos, kv_len, scale=s,
+                                  window=window)
     if route == "blockwise":
         return _sdpa_blockwise(query, key, value, q_pos, kv_len, scale=s,
                                block_q=_pick_block(sq, BLOCKWISE_BLOCK_Q),
-                               block_k=_pick_block(sk, BLOCKWISE_BLOCK_K))
+                               block_k=_pick_block(sk, BLOCKWISE_BLOCK_K),
+                               window=window)
     raise ValueError(f"attention_route returned {route!r}")
 
 

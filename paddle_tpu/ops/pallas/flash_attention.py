@@ -547,6 +547,30 @@ def supports(seq_q, seq_k, head_dim=None,
 # length-masked (cached) forward — serving prefill / chunked prefill / verify
 # ---------------------------------------------------------------------------
 
+def _masked_tile_update(s, valid, v_ref, acc_ref, m_ref, l_ref):
+    """One key block of the cached kernels' online softmax: the scores ``s``
+    under ``valid`` folded into the running (max, denominator, output)."""
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_ref[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = _zero_masked_rows(jnp.exp(s - m_new), m_new)
+    l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0, 0],
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _write_normalised(o_ref, acc_ref, l_ref):
+    """The sweep's last step: a row that saw no key gives zeros."""
+    l = l_ref[:, 0:1]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+
+
 def _cached_fwd_kernel(klen_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
                        acc_ref, m_ref, l_ref, *, scale, block_k):
     """Online-softmax sweep with per-row validity from streamed positions:
@@ -571,24 +595,11 @@ def _cached_fwd_kernel(klen_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref,
     cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     qpos = qpos_ref[0, 0][:, 0:1]
     valid = (cols <= qpos) & (cols < klen_ref[pl.program_id(0)])
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[:, 0:1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = _zero_masked_rows(jnp.exp(s - m_new), m_new)
-    l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0, 0],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    _masked_tile_update(s, valid, v_ref, acc_ref, m_ref, l_ref)
 
     @pl.when(ki == nk - 1)
     def _finish():
-        l = l_ref[:, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+        _write_normalised(o_ref, acc_ref, l_ref)
 
 
 def _flash_cached_impl(q, k, v, qpos, klen, scale, block_q, block_k,
@@ -659,6 +670,133 @@ def _flash_cached_vjp_bwd(scale, block_q, block_k, interpret, res, g):
 _flash_cached.defvjp(_flash_cached_vjp_fwd, _flash_cached_vjp_bwd)
 
 
+# ---------------------------------------------------------------------------
+# the same under a band (LengthMask.window): only the key blocks it meets
+# ---------------------------------------------------------------------------
+
+#: query and key rows of a tile of the banded sweep. Of the keys a query
+#: block visits, ``window / (window + block)`` lie in the band of a row: at
+#: a window of 512, half with 512-row tiles, a quarter with the 1024-row
+#: tiles the unbanded kernel takes. On the v5e, 64 heads of 128 under a
+#: window of 512: 6.06 ms at 8,192 positions against 6.99 with 1024-row
+#: tiles and 11.01 with 256 (1.43 / 1.50 / 2.37 at 2,048; PERF.md, PR 36).
+BAND_BLOCK = 512
+
+
+def _banded_fwd_kernel(klen_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref,
+                       qpos_ref, o_ref, acc_ref, m_ref, l_ref, *, scale,
+                       block_k, window, nq):
+    """:func:`_cached_fwd_kernel` with a lower bound: key slot j attends iff
+    ``q_pos[row] - window < j <= q_pos[row]`` and ``j < kv_len[batch]``. The
+    innermost grid axis counts the key blocks of ONE query block's band,
+    from ``lo_ref[batch, q_block]`` (scalar-prefetched, as is the last one,
+    ``hi_ref``): a step past ``hi`` neither fetches (its index map repeats
+    the last block) nor computes."""
+    bb, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kj == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    ki = lo_ref[bb * nq + qi] + kj
+
+    @pl.when(ki <= hi_ref[bb * nq + qi])
+    def _visit():
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale
+        cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        qpos = qpos_ref[0, 0][:, 0:1]
+        valid = (cols <= qpos) & (cols > qpos - window) \
+            & (cols < klen_ref[bb])
+        _masked_tile_update(s, valid, v_ref, acc_ref, m_ref, l_ref)
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _finish():
+        _write_normalised(o_ref, acc_ref, l_ref)
+
+
+def band_blocks(block_q, block_k, window, nk):
+    """Key blocks a query block's band can meet: its rows stand at
+    ``block_q`` consecutive positions, so their bands cover ``block_q +
+    window - 1`` keys, wherever those begin."""
+    return min(nk, -(-(block_q + window - 2) // block_k) + 1)
+
+
+def _flash_banded_impl(q, k, v, qpos, klen, lo, hi, scale, block_q, block_k,
+                       window, interpret):
+    """``lo, hi [batch, q blocks]``: the first and the last key block of
+    each query block's band (``hi < lo``: none)."""
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    nq, nk = sq // block_q, sk // block_k
+    nkb = band_blocks(block_q, block_k, window, nk)
+
+    def qmap(bb, hh, qi, kj, klen_ref, lo_ref, hi_ref):
+        return (bb, hh, qi, 0)
+
+    def kmap(bb, hh, qi, kj, klen_ref, lo_ref, hi_ref):
+        first = lo_ref[bb * nq + qi]
+        last = jnp.maximum(hi_ref[bb * nq + qi], first)
+        return (bb, hh, jnp.minimum(first + kj, last), 0)
+
+    kernel = functools.partial(_banded_fwd_kernel, scale=scale,
+                               block_k=block_k, window=window, nq=nq)
+    return pl.pallas_call(
+        kernel,
+        name="flash_banded_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, h, nq, nkb),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, d), qmap),
+                pl.BlockSpec((1, 1, block_k, d), kmap),
+                pl.BlockSpec((1, 1, block_k, d), kmap),
+                pl.BlockSpec((1, 1, block_q, STAT_LANES),
+                             lambda bb, hh, qi, kj, *_: (bb, 0, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, d), qmap),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        cost_estimate=pl.CostEstimate(
+            flops=int(4 * b * h * sq * nkb * block_k * d),
+            bytes_accessed=int(2 * (2 * q.size + 2 * b * h * nq * nkb
+                                    * block_k * d)),
+            transcendentals=int(b * h * sq * nkb * block_k),
+        ),
+    )(klen, lo.reshape(b * nq), hi.reshape(b * nq), q, k, v, qpos)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+def _flash_banded(q, k, v, qpos, klen, lo, hi, scale, block_q, block_k,
+                  window, interpret):
+    return _flash_banded_impl(q, k, v, qpos, klen, lo, hi, scale, block_q,
+                              block_k, window, interpret)
+
+
+def _flash_banded_vjp_fwd(q, k, v, qpos, klen, lo, hi, scale, block_q,
+                          block_k, window, interpret):
+    return _flash_banded_impl(q, k, v, qpos, klen, lo, hi, scale, block_q,
+                              block_k, window, interpret), ()
+
+
+def _flash_banded_vjp_bwd(scale, block_q, block_k, window, interpret, res,
+                          g):
+    return _flash_cached_vjp_bwd(scale, block_q, block_k, interpret, res, g)
+
+
+_flash_banded.defvjp(_flash_banded_vjp_fwd, _flash_banded_vjp_bwd)
+
+
 def supports_cached(seq_q, seq_k, head_dim=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
     """Shape gate for the length-masked kernel: both sequence dims must tile
@@ -671,8 +809,8 @@ def supports_cached(seq_q, seq_k, head_dim=None,
 
 
 def flash_attention_cached(q, k, v, q_pos, kv_len=None, *, scale=None,
-                           block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                           interpret=None):
+                           block_q=None, block_k=None, interpret=None,
+                           window=None):
     """Length-masked flash attention over a static-shape KV cache.
 
     Args:
@@ -682,6 +820,13 @@ def flash_attention_cached(q, k, v, q_pos, kv_len=None, *, scale=None,
         row; key slot ``j`` attends iff ``j <= q_pos[b, i]``.
       kv_len: optional int32 ``(batch,)`` exclusive bound of valid cache
         rows (``None`` -> all ``seq_k`` rows writable-valid).
+      window: optional static int: additionally ``j > q_pos[b, i] - window``
+        (``LengthMask.window``). The banded kernel then sweeps, for each
+        query block, the key blocks its rows' bands meet and no others; it
+        sizes that sweep for rows at consecutive positions (a prompt, a
+        chunk: :func:`band_blocks`).
+      block_q, block_k: tile rows; default 1024, :data:`BAND_BLOCK` under a
+        window.
 
     Forward-only: serving's prefill / chunked-prefill / speculative-verify
     steps. Returns ``(batch, seq_q, heads, head_dim)``.
@@ -693,8 +838,9 @@ def flash_attention_cached(q, k, v, q_pos, kv_len=None, *, scale=None,
         interpret = interpret_requested()
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q = _pick_block(sq, block_q)
-    block_k = _pick_block(sk, block_k)
+    pref = DEFAULT_BLOCK_Q if window is None else BAND_BLOCK
+    block_q = _pick_block(sq, block_q or pref)
+    block_k = _pick_block(sk, block_k or pref)
     if not (block_q and block_k):
         raise ValueError(
             f"flash_attention_cached needs 128-aligned sequence blocks: "
@@ -709,6 +855,25 @@ def flash_attention_cached(q, k, v, q_pos, kv_len=None, *, scale=None,
         (b, 1, sq, STAT_LANES))
     klen = (jnp.full((b,), sk, jnp.int32) if kv_len is None
             else jnp.asarray(kv_len, jnp.int32).reshape(b))
+
+    if window is not None:
+        # each query block's first and last key block, from its rows'
+        # positions (a row at -1 belongs to no request and sees nothing)
+        pos = jnp.asarray(q_pos, jnp.int32).reshape(b, sq // block_q, block_q)
+        last = jnp.minimum(jnp.max(pos, -1), klen[:, None] - 1)
+        first = jnp.min(jnp.where(pos >= 0, pos, sk + window), -1) \
+            - (window - 1)
+        lo = jnp.clip(first, 0, sk - 1) // block_k
+        hi = jnp.where(last >= 0, last // block_k, -1)
+
+        def banded(qt, kt, vt, qpos, klen, lo, hi):
+            return _flash_banded(qt, kt, vt, qpos, klen, lo, hi,
+                                 float(scale), int(block_q), int(block_k),
+                                 int(window), bool(interpret))
+
+        out = batch_sharded(banded, (qt, kt, vt, qpos, klen, lo, hi),
+                            (True,) * 7)
+        return jnp.swapaxes(out, 1, 2)
 
     def call(qt, kt, vt, qpos, klen):
         return _flash_cached(qt, kt, vt, qpos, klen, float(scale),

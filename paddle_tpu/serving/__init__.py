@@ -27,6 +27,7 @@ from .engine import (  # noqa: F401
     EncoderScorer,
     GenerationEngine,
     RecurrentStateError,
+    RingCacheError,
 )
 from .scheduler import (  # noqa: F401
     FINISH_REASONS,
@@ -45,6 +46,7 @@ __all__ = [
     "StatePrefillView",
     "CountsView",
     "RecurrentStateError",
+    "RingCacheError",
     "DraftProposer",
     "NgramProposer",
     "default_buckets",

@@ -64,14 +64,18 @@ from .kv_cache import (
     DecodeView,
     KVCache,
     PrefillView,
+    RingPrefillView,
     StateDecodeView,
     StatePrefillView,
     _leaf,
+    cache_route,
+    count_cache_route,
     default_buckets,
     pick_bucket,
 )
 
-__all__ = ["GenerationEngine", "EncoderScorer", "RecurrentStateError"]
+__all__ = ["GenerationEngine", "EncoderScorer", "RecurrentStateError",
+           "RingCacheError"]
 
 
 class RecurrentStateError(ValueError):
@@ -80,6 +84,14 @@ class RecurrentStateError(ValueError):
     (rejected drafts have already advanced the state) or chunked prefill
     (a later chunk continues from the state the last one left, while decode
     ticks in between advance the same slot)."""
+
+
+class RingCacheError(ValueError):
+    """The model keeps some layers' K/V as a ring of their window and the
+    engine was asked for a step that needs the ring's order: speculative
+    verify (a rejected draft has already overwritten the row ``window``
+    positions back) or chunked prefill (a later chunk's first queries need
+    rows that its own writes replace)."""
 
 
 def _sample_next(logits, keys, temps, top_ks, top_ps):
@@ -175,7 +187,9 @@ class GenerationEngine:
             fall back to the bucketed one-shot path.
 
     A model with recurrent state takes neither ``spec_k`` nor
-    ``prefill_chunk``: :class:`RecurrentStateError`.
+    ``prefill_chunk``: :class:`RecurrentStateError`; nor does one that
+    keeps a ring (a ``kv`` entry with a ``window`` under ``max_len``):
+    :class:`RingCacheError`.
     """
 
     def __init__(self, model, *, max_batch=8, max_len=None,
@@ -222,6 +236,21 @@ class GenerationEngine:
                 f"after a rejected draft and prefill_chunk="
                 f"{self.prefill_chunk} to resume it across ticks; neither "
                 f"is built")
+        #: how each ``kv`` entry is kept (``full`` / ``ring``), None for the
+        #: other entries; and the rings' windows
+        self._routes = [cache_route(layer, self.max_len)
+                        if kind == "kv" else None
+                        for layer, kind in zip(self.cache_spec, kinds)]
+        self.ring_windows = sorted({
+            int(layer["window"]) for layer, route
+            in zip(self.cache_spec, self._routes) if route == "ring"})
+        if self.ring_windows and (self.spec_k or self.prefill_chunk):
+            raise RingCacheError(
+                f"the model keeps {self._routes.count('ring')} layers' K/V "
+                f"as rings of {self.ring_windows} rows: spec_k={self.spec_k} "
+                f"would have to restore the rows a rejected draft overwrote "
+                f"and prefill_chunk={self.prefill_chunk} to read rows its "
+                f"own chunk replaces; neither is built")
         #: names of the counts the model's layers note, in order
         self.count_names = next(
             (tuple(layer["names"]) for layer in self.cache_spec
@@ -234,6 +263,9 @@ class GenerationEngine:
         #: has no valid key for their attention and keeps its length, and
         #: its tokens do not count as routed nor its state as live
         self._live = np.zeros((self.max_batch,), bool)
+        #: the host's copy of each slot's length (rows its next decode step
+        #: reads, the new one included): the tick record's live rows
+        self._rows = np.zeros((self.max_batch,), np.int64)
         if freeze_weights == "auto":
             freeze_weights = jax.default_backend() == "cpu"
         self.freeze_weights = bool(freeze_weights)
@@ -268,16 +300,18 @@ class GenerationEngine:
         return KVCache.from_spec(self.cache_spec, self.max_batch,
                                  self.max_len, self.cache_dtype)
 
-    def _views(self, cache, kv, state, valid):
+    def _views(self, cache, kv, state, valid, ring=None):
         """One view a layer, by what the layer declared: ``kv(k, v)`` and
         ``state(arrays)`` build this step's views of K/V and of recurrent
-        state, a counting layer is told which tokens are ``valid``, a layer
-        that keeps nothing gets None."""
+        state, ``ring(k, v, window)`` of K/V kept as a ring, a counting
+        layer is told which tokens are ``valid``, a layer that keeps nothing
+        gets None."""
         views = []
-        for layer, k, v, st in zip(self.cache_spec, cache.ks, cache.vs,
-                                   cache.states):
+        for layer, route, k, v, st in zip(self.cache_spec, self._routes,
+                                          cache.ks, cache.vs, cache.states):
             kind = layer["kind"] if layer else None
-            views.append(kv(k, v) if kind == "kv"
+            views.append(ring(k, v, int(layer["window"])) if route == "ring"
+                         else kv(k, v) if kind == "kv"
                          else state(st) if kind == "state"
                          else CountsView(valid) if kind == "counts"
                          else None)
@@ -302,22 +336,38 @@ class GenerationEngine:
             return Tensor(tokens)
         return Tensor(jnp.concatenate([tokens.reshape(-1), counts]))
 
-    def _unpack(self, packed, n_tokens, live_slots=None):
+    def _unpack(self, packed, n_tokens, live_slots=None, prefilled=None):
         """Host side of :meth:`_pack`: the tokens; the counts are filed in
         the open serving tick's record (``Telemetry.add_count``), a decode
         step's under the layers' names, a prefill's under ``<name>.prefill``
-        (``live_slots`` is given by decode alone)."""
+        (``live_slots`` is given by decode alone, ``prefilled = (bucket,
+        prompt tokens)`` by the one-shot prefill)."""
         out = np.asarray(_leaf(packed)).reshape(-1)
         if _telemetry.enabled():
             tm = _telemetry.get_telemetry()
             suffix = ".prefill" if live_slots is None else ""
             for name, n in zip(self.count_names, out[n_tokens:]):
                 tm.add_count(name + suffix, int(n))
+            if prefilled is not None:
+                # the keys a ring layer's queries saw (each its window's),
+                # by bucket: the bucket decides which attention route ran
+                bucket, n = prefilled
+                for w in self.ring_windows:
+                    m = min(n, w)
+                    tm.add_count(f"serve.ring_live_rows.prefill.b{bucket}",
+                                 m * (m + 1) // 2 + (n - m) * w)
             if live_slots is not None:
                 # of max_batch slots, those the step's attention visited
                 tm.add_count("serve.decode_live_slots", int(live_slots))
                 if self.has_state:
                     tm.add_count("serve.state_live_slots", int(live_slots))
+                # the K/V rows the step's attention had to read: of a
+                # full-length layer, of a ring (one layer of each)
+                rows = self._rows[self._live]
+                tm.add_count("serve.kv_live_rows", int(rows.sum()))
+                for w in self.ring_windows:
+                    tm.add_count("serve.ring_live_rows",
+                                 int(np.minimum(rows, w).sum()))
         return out[:n_tokens]
 
     def release_slot(self, slot):
@@ -343,9 +393,14 @@ class GenerationEngine:
             # (q_pos, kv_len) so the blockwise/Pallas attention paths never
             # materialize the [1, 1, bucket, bucket] score mask.
             lmask = LengthMask(i[None, :], ln[None])
+            # one mask a layer KIND: a ring's layer sees its band of the
+            # bucket (and leaves the prompt's last rows in the ring)
+            bands = {w: LengthMask(i[None, :], ln[None], window=w)
+                     for w in self.ring_windows}
             views = self._views(
                 cache, lambda k, v: PrefillView(k, v, sl),
-                lambda st: StatePrefillView(st, sl, ln), (i < ln)[None, :])
+                lambda st: StatePrefillView(st, sl, ln), (i < ln)[None, :],
+                lambda k, v, w: RingPrefillView(k, v, sl, ln, bands[w]))
             logits, views = model(
                 tokens, position_ids=Tensor(i[None, :]),
                 attn_mask=lmask, cache=views)
@@ -419,9 +474,23 @@ class GenerationEngine:
             # slot without a request has no valid key: its attention reads
             # nothing of the cache and gives zeros
             lmask = LengthMask(jnp.where(lv, pos, -1)[:, None])
-            views = self._views(
-                cache, lambda k, v: DecodeView(k, v, pos), StateDecodeView,
-                lv[:, None])
+            # a ring holds position p in row p mod window: after this
+            # step's write its first min(pos + 1, window) rows are the
+            # window's keys, in an order softmax does not care about
+            rings = {w: LengthMask(jnp.where(
+                lv, jnp.minimum(pos, w - 1), -1)[:, None])
+                for w in self.ring_windows}
+
+            def full(k, v):
+                count_cache_route("full")
+                return DecodeView(k, v, pos)
+
+            def ring(k, v, w):
+                count_cache_route("ring")
+                return DecodeView(k, v, pos % w, rings[w])
+
+            views = self._views(cache, full, StateDecodeView, lv[:, None],
+                                ring)
             logits, views = model(
                 tokens, position_ids=Tensor(pos[:, None]),
                 attn_mask=lmask, cache=views)
@@ -566,8 +635,10 @@ class GenerationEngine:
                 toks, np.int32(prompt.size), np.int32(slot), self.cache)
         self.cache = cache  # donated: the old buffers are consumed
         self._live[int(slot)] = True
+        self._rows[int(slot)] = min(prompt.size + 1, self.max_len)
         with _telemetry.phase_span("serve.prefill_readback"):
-            return int(self._unpack(tok, 1)[0])
+            return int(self._unpack(
+                tok, 1, prefilled=(bucket, int(prompt.size)))[0])
 
     def chunked_prefill_fits(self, prompt_len):
         """True when a prompt of this length can prefill through the
@@ -630,6 +701,7 @@ class GenerationEngine:
         self.cache = cache
         if off + piece.size >= prompt.size:
             self._live[int(slot)] = True
+            self._rows[int(slot)] = min(prompt.size + 1, self.max_len)
             with _telemetry.phase_span("serve.prefill_readback"):
                 return int(self._unpack(tok, 1)[0])
         return None
@@ -651,7 +723,10 @@ class GenerationEngine:
         # the one blocking wait of a tick: the host sits here while the
         # device runs the step it was just handed
         with _telemetry.phase_span("serve.decode_readback"):
-            return self._unpack(tok, self.max_batch, self._live.sum())
+            out = self._unpack(tok, self.max_batch, self._live.sum())
+        self._rows[self._live] = np.minimum(self._rows[self._live] + 1,
+                                            self.max_len)
+        return out
 
     def verify_once(self, window_tokens):
         """One speculative verify step over ``[max_batch, spec_k + 1]``
@@ -694,6 +769,8 @@ class GenerationEngine:
         self.cache = KVCache(self.cache.ks, self.cache.vs,
                              jnp.minimum(ln + adv, self.max_len),
                              self.cache.states)
+        self._rows = np.minimum(self._rows + np.asarray(advance, np.int64)
+                                .reshape(self.max_batch), self.max_len)
 
     def generate(self, prompt_ids, max_new_tokens=32, eos_id=None):
         """Greedy single-request generation (slot 0; other slots idle).
@@ -735,9 +812,11 @@ class GenerationEngine:
         * ``base_bytes`` — everything but the cache (weights, decode
           temps): resident whether or not any request is active;
         * ``per_token_bytes`` — KV bytes one cached token pins across
-          all layers;
+          all layers that keep ``max_len`` rows (a ring's rows are not a
+          token's: they stop at the window);
         * ``prefill_bucket_bytes`` — per-bucket KV bytes a request
-          padded to that bucket pins at admit.
+          padded to that bucket pins at admit, a ring's rows up to its
+          window.
 
         When the abstract timeline is unavailable (lint failure),
         ``decode_peak_bytes`` falls back to plain cache arithmetic
@@ -747,7 +826,20 @@ class GenerationEngine:
         if self._footprints is not None and not refresh:
             return dict(self._footprints)
         cache_bytes = int(self.cache.nbytes())
-        per_token = max(1, cache_bytes // (self.max_batch * self.max_len))
+        # what a cached token pins: a row of every full-length K/V entry,
+        # with the rest of a slot (recurrent state) spread over its
+        # positions as before; a ring's rows are priced apart, a row a
+        # position up to its window
+        per_ring_row = {w: 0 for w in self.ring_windows}
+        for k, layer, route in zip(self.cache.ks, self.cache_spec,
+                                   self._routes):
+            if route == "ring":  # K and V of one row of one slot
+                per_ring_row[int(layer["window"])] += 2 * int(
+                    _leaf(k)[0, 0].size) * _leaf(k).dtype.itemsize
+        ring_bytes = self.max_batch * sum(
+            w * row for w, row in per_ring_row.items())
+        per_token = max(1, (cache_bytes - ring_bytes)
+                        // (self.max_batch * self.max_len))
         timeline = None
         try:
             from .. import analysis
@@ -763,7 +855,8 @@ class GenerationEngine:
             "base_bytes": max(0.0, decode_peak - cache_bytes),
             "per_token_bytes": float(per_token),
             "prefill_bucket_bytes": {
-                int(b): float(per_token * min(self.max_len, int(b)))
+                int(b): float(per_token * min(self.max_len, int(b)) + sum(
+                    row * min(w, int(b)) for w, row in per_ring_row.items()))
                 for b in self.prefill_buckets},
             "timeline": timeline,
         }

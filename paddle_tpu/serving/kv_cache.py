@@ -49,9 +49,10 @@ import jax.numpy as jnp
 
 from ..framework.tensor import Tensor
 
-__all__ = ["KVCache", "DecodeView", "PrefillView", "ChunkView",
-           "StateDecodeView", "StatePrefillView", "CountsView",
-           "pick_bucket", "default_buckets", "row_write_route"]
+__all__ = ["KVCache", "DecodeView", "PrefillView", "RingPrefillView",
+           "ChunkView", "StateDecodeView", "StatePrefillView", "CountsView",
+           "pick_bucket", "default_buckets", "row_write_route",
+           "cache_route"]
 
 #: additive-mask floor: large enough to zero a softmax lane in fp32/bf16
 #: without producing inf-inf NaNs when a whole row is masked
@@ -100,6 +101,12 @@ class KVCache:
 
     * ``{"kind": "kv", "heads", "head_dim", "dtype"}`` — K/V rows up to
       ``max_len``: ``ks[l] / vs[l]: [batch, max_len, heads, head_dim]``;
+      with ``"window": w`` (a layer that attends to the last ``w``
+      positions alone) a RING of ``min(w, max_len)`` rows, position ``p``
+      in row ``p mod w``: ``[batch, w, heads, head_dim]``. Which rows of a
+      ring are valid follows from the slot's length alone (the first
+      ``min(length, w)``), so a ring needs keys that carry their position
+      in themselves (rotated before they are cached) or none at all;
     * ``{"kind": "state", "arrays": {name: (shape, dtype)}}`` — fixed-shape
       recurrent state: ``states[l][name]: [batch, *shape]``;
     * anything else (``None``, ``{"kind": "counts", ...}``) — the layer
@@ -150,7 +157,8 @@ class KVCache:
             kind = layer["kind"] if layer else None
             k = v = state = None
             if kind == "kv":
-                shape = (batch, max_len, int(layer["heads"]),
+                rows = min(int(layer.get("window") or max_len), max_len)
+                shape = (batch, rows, int(layer["heads"]),
                          int(layer["head_dim"]))
                 dtype = kv_dtype or layer["dtype"]
                 k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
@@ -185,7 +193,8 @@ class KVCache:
 
     @property
     def max_len(self):
-        return int(self._first_k().shape[1])
+        """Rows of the longest K/V entry (a ring keeps fewer)."""
+        return max(int(_leaf(k).shape[1]) for k in self.ks if k is not None)
 
     @property
     def num_heads(self):
@@ -202,7 +211,9 @@ class KVCache:
                        (self.ks, self.vs, self.states)))
 
     def __repr__(self):
-        kinds = ["kv" if k is not None else "state" if st is not None
+        rows = self.max_len if any(k is not None for k in self.ks) else 0
+        kinds = [("kv" if _leaf(k).shape[1] == rows else "ring")
+                 if k is not None else "state" if st is not None
                  else "-" for k, st in zip(self.ks, self.states)]
         return (f"KVCache(batch={self.batch}, layers={kinds}, "
                 f"bytes={self.nbytes()})")
@@ -256,6 +267,25 @@ def _count_row_write_route(route):
         telemetry.get_telemetry().inc(f"kv.row_write_route.{route}")
 
 
+def cache_route(entry, max_len):
+    """``ring`` or ``full``: how a ``kv`` entry of a model's ``cache_spec``
+    is kept at ``max_len`` positions a slot. A window that reaches the whole
+    cache is no ring. Pure; the table is in ``tests/test_kv_ring.py``, and
+    the counter ``attn.cache_route.<route>`` says what a traced decode step
+    was handed, once a layer."""
+    window = entry.get("window")
+    return "ring" if window and int(window) < int(max_len) else "full"
+
+
+def count_cache_route(route):
+    """Counter ``attn.cache_route.<route>``: bumped by the engine when a
+    decode step is TRACED, once a ``kv`` entry."""
+    from ..profiler import telemetry
+
+    if telemetry.enabled():
+        telemetry.get_telemetry().inc(f"attn.cache_route.{route}")
+
+
 class DecodeView:
     """One layer's cache view for the batched decode step (and speculative
     verify's window).
@@ -271,14 +301,21 @@ class DecodeView:
     does; ``kv_row_write.py`` says what XLA does otherwise). The updated
     buffers stay on the view; the engine collects them into the next
     ``KVCache``.
+
+    A ring is this view too: the engine hands it ``pos mod window`` as the
+    row and, as ``mask``, what the ring's rows mean (the first ``min(pos +
+    1, window)`` are valid, in whatever order: softmax does not care). A
+    layer attends under ``mask`` where a view carries one, else under the
+    step's.
     """
 
-    __slots__ = ("k", "v", "pos")
+    __slots__ = ("k", "v", "pos", "mask")
 
-    def __init__(self, k, v, pos):
+    def __init__(self, k, v, pos, mask=None):
         self.k = _leaf(k)
         self.v = _leaf(v)
         self.pos = _leaf(pos)
+        self.mask = mask
 
     def update(self, k_new, v_new):
         from ..ops import pallas
@@ -310,21 +347,51 @@ class PrefillView:
     chunk (with the padding masked by the caller's mask) is exact.
     """
 
-    __slots__ = ("k", "v", "slot")
+    __slots__ = ("k", "v", "slot", "mask")
 
-    def __init__(self, k, v, slot):
+    def __init__(self, k, v, slot, mask=None):
         self.k = _leaf(k)
         self.v = _leaf(v)
         self.slot = _leaf(slot)
+        self.mask = mask
+
+    def _kept(self, new):
+        """The bucket's rows as the buffer keeps them: as they come."""
+        return new
 
     def update(self, k_new, v_new):
-        kn = _leaf(k_new).astype(self.k.dtype)
-        vn = _leaf(v_new).astype(self.v.dtype)
+        kn = self._kept(_leaf(k_new).astype(self.k.dtype))
+        vn = self._kept(_leaf(v_new).astype(self.v.dtype))
         z = jnp.int32(0)
         start = (self.slot.astype(jnp.int32), z, z, z)
         self.k = jax.lax.dynamic_update_slice(self.k, kn, start)
         self.v = jax.lax.dynamic_update_slice(self.v, vn, start)
         return k_new, v_new, self
+
+
+class RingPrefillView(PrefillView):
+    """:class:`PrefillView` of a ring of ``window`` rows: attention runs over
+    the bucket's own K/V under ``mask`` (the band), and the ring is left
+    with the last ``min(length, window)`` rows of the prompt, each at its
+    position mod ``window``, whatever the bucket. A bucket no longer than
+    the ring is written as it comes (position ``p`` IS row ``p``)."""
+
+    __slots__ = ("length",)
+
+    def __init__(self, k, v, slot, length, mask):
+        super().__init__(k, v, slot, mask)
+        self.length = _leaf(length).astype(jnp.int32)
+
+    def _kept(self, new):
+        window = self.k.shape[1]
+        if new.shape[1] <= window:
+            return new
+        # row r holds the last position below ``length`` that is r mod
+        # window (a row no position has reached yet holds padding: it is
+        # written before the slot's length makes it valid)
+        r = jnp.arange(window, dtype=jnp.int32)
+        laps = jnp.maximum(self.length - 1 - r, 0) // window
+        return jnp.take(new, r + laps * window, axis=1)
 
 
 class ChunkView:

@@ -615,6 +615,16 @@ def _site_flash_cached():
         block_k=128)), (q, k, v)
 
 
+def _site_flash_banded():
+    from paddle_tpu.ops.pallas import flash_attention_cached
+
+    q, k, v = _rand_qkv(b=1, s=256)
+    q_pos = np.arange(256, dtype=np.int32)[None]
+    return (lambda q, k, v: flash_attention_cached(
+        q, k, v, q_pos, np.asarray([250], np.int32), block_q=128,
+        block_k=128, window=64)), (q, k, v)
+
+
 def _site_flash_decode():
     from paddle_tpu.ops.pallas.flash_decode import flash_attention_decode
 
@@ -651,14 +661,15 @@ def _site_kv_row_write():
 @pytest.mark.parametrize("site,expected", [
     (_site_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     (_site_flash_cached, {"flash_cached_fwd"}),
+    (_site_flash_banded, {"flash_banded_fwd"}),
     (_site_flash_decode, {"flash_decode_fwd"}),
     (_site_flash_packed, {"flash_packed_fwd", "flash_packed_bwd"}),
     (_site_layer_norm, {"layer_norm_fwd", "layer_norm_bwd"}),
     (_site_kv_row_write, {"kv_row_write"}),
-], ids=["flash", "flash_cached", "flash_decode", "flash_packed",
-        "layer_norm", "kv_row_write"])
+], ids=["flash", "flash_cached", "flash_banded", "flash_decode",
+        "flash_packed", "layer_norm", "kv_row_write"])
 def test_every_pallas_call_site_carries_its_name(site, expected):
-    """Each of these ten ``pl.pallas_call`` sites names its kernel: the
+    """Each of these eleven ``pl.pallas_call`` sites names its kernel: the
     name is the custom call's in the device trace (``%jvp_flash_packed_fwd_``
     on the v5e), which is what a reader's pattern holds on to."""
     fn, args = site()
@@ -677,4 +688,4 @@ def test_no_pallas_call_site_is_left_unnamed():
             calls += 1
             named += bool(re.match(r"\s*[\w.()=, ]+,\s*name=\"\w+\"",
                                    text[m.end():m.end() + 200]))
-    assert calls == 15 and named == calls
+    assert calls == 16 and named == calls

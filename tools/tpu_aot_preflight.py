@@ -251,6 +251,30 @@ def kernel_programs(devs):
                            _sds((E, wid, hid), bf, one),
                            _sds((tiles,), jnp.int32, one),
                            _sds((1,), jnp.int32, one)))
+    # the same two products at the widths the Laguna cut serves: hidden
+    # 2048, gated experts of width 512 (32 held, 8 a token), a decode batch
+    # of 48 slots and the 8,192 bucket
+    E, hid, wid = 32, 2048, 512
+    for tokens, tm in ((48, 16), (8192, 128)):
+        tiles = -(-tokens * 8 // tm) + E
+        yield (f"gated experts up+down t{tokens} tm{tm} h{hid} w{wid}",
+               lambda tiles=tiles, tm=tm, E=E, hid=hid, wid=wid: jax.jit(
+                   lambda x, up, down, te, na: moe_grouped._grouped_call(
+                       moe_grouped._gated_call(x, up, te, na, tm, False),
+                       down, te, na, tm, None, False, False)).lower(
+                           _sds((tiles * tm, hid), bf, one),
+                           _sds((E, 2 * wid, hid), bf, one),
+                           _sds((E, wid, hid), bf, one),
+                           _sds((tiles,), jnp.int32, one),
+                           _sds((1,), jnp.int32, one)))
+    # the banded cached kernel as the Laguna cut's window layers reach it:
+    # one prompt in the 8,192 bucket, 64 query heads of 128, a window of 512
+    q = _sds((1, 8192, 64, 128), bf, one)
+    yield "flash banded b1 sq8192 h64 window 512", lambda: jax.jit(
+        lambda q, k, v, qp, kl: flash_attention_cached(
+            q, k, v, qp, kl, window=512)).lower(
+                q, q, q, _sds((1, 8192), jnp.int32, one),
+                _sds((1,), jnp.int32, one))
     slots, heads, hdim = 128, 64, 128
     nb = heads // kda_step.head_block(heads)
     yield "kda step b128 h64 128x128 donated", lambda: jax.jit(
@@ -403,6 +427,22 @@ def step_programs(devs):
         place)
 
 
+    del eng
+    # the benchmark's Laguna cut as its cell serves it: 48 slots of 9,216
+    # positions (4 full-length layers, 9 rings of 512), the decode step and
+    # the largest bucket its traffic uses (3.6 GB of parameters and 8.2 GB
+    # of cache are built on this host)
+    eng = chip_smoke.build_ring_engine(False, max_batch=48, max_len=9216,
+                                       freeze_weights=False)
+    eng.cache = None  # the example arguments bring their own 8.2 GB
+    yield "serve_decode (ring cut b48 x 9216)", lambda: lower_step(
+        eng.decode_step, eng.example_decode_args([1]), place, place)
+    yield "serve_prefill bucket 8192 (ring cut)", lambda: lower_step(
+        eng.prefill_step, (np.zeros((1, 8192), np.int32), np.int32(1),
+                           np.int32(0), eng._example_cache([0])), place,
+        place)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--steps", action="store_true",
@@ -488,6 +528,24 @@ def main(argv=None):
                     compiled.as_text())
                 print(f"       copies of an expert stack or of the states: "
                       f"{len(copies)}", flush=True)
+            if compiled is not None and "ring cut" in name:
+                # XLA ops of the program's own list (inside a fusion an
+                # operand is no buffer) whose result is a whole K or V
+                # buffer (full-length or ring) or an expert stack, but for
+                # the prefill's in-place dynamic-update-slice: a copy of
+                # one; and how much of the arguments the outputs alias (the
+                # donated cache, the ring written in place)
+                text = compiled.as_text()
+                copies = [name for name in re.findall(
+                    r"(%\S+) = bf16\[(?:48,(?:9216|512),8,128|"
+                    r"32,(?:1024|512),2048)\]\S* "
+                    r"(?:copy|transpose|fusion|copy-start)\(",
+                    text[text.index("\nENTRY"):])
+                    if "dynamic-update-slice" not in name]
+                print(f"       copies of a cache buffer or of an expert "
+                      f"stack: {len(copies)}; aliased "
+                      f"{ma.alias_size_in_bytes / 2**30:.2f} GiB",
+                      flush=True)
     if rep.failed:
         print(f"{len(rep.failed)} program(s) failed: {rep.failed}")
         return 1
