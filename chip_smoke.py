@@ -68,6 +68,8 @@ SERVE_LEGS = (
     ("speed-v2", {"spec_k": 4, "prefill_chunk": 128},
      [9, 100, 300, 700, 120, 14], [16, 12, 24, 16, 8, 20]),
 )
+#: what ``--legs`` may name, in the order they run
+LEGS = ("train", "kernels", "serve", "delta_rule", "ring", "row_dma", "dp4")
 #: kernel vs XLA formulation in fp32/highest: max|a-b| / max|b|. Forward
 #: outputs are one bf16 rounding apart; gradients accumulate bf16 products
 #: over 1024 keys (attention) or 24k rows (LayerNorm dgamma/dbeta).
@@ -204,8 +206,8 @@ def build_delta_rule_engine(tiny, max_batch, max_len, **kw):
     """The benchmark's Solar Open 2 cut through the normal constructor: the
     published widths (the config's defaults), the first 4 layers ``G K K
     K``, 40 of the 320 routed experts, 24,576 vocabulary rows; ``tiny``
-    keeps the kinds and the 128-wide heads (so the same routes are taken)
-    at a size the interpreter finishes."""
+    keeps the kinds, the 128-wide heads and two K/V heads (so the same
+    routes are taken) at a size the interpreter finishes."""
     import paddle_tpu as paddle
     from paddle_tpu.models import SolarOpen2Config, SolarOpen2ForCausalLM
     from paddle_tpu.serving import GenerationEngine
@@ -213,8 +215,8 @@ def build_delta_rule_engine(tiny, max_batch, max_len, **kw):
     cut = dict(num_hidden_layers=4, gqa_layers=(0,))
     if tiny:
         cfg = SolarOpen2Config(
-            vocab_size=512, hidden_size=128, num_attention_heads=2,
-            num_key_value_heads=1, kda_num_heads=8, kda_gate_rank=16,
+            vocab_size=512, hidden_size=128, num_attention_heads=4,
+            num_key_value_heads=2, kda_num_heads=8, kda_gate_rank=16,
             n_routed_experts=8, num_experts_per_tok=2,
             moe_intermediate_size=128, held_experts=(0, 1, 2, 3), **cut)
     else:
@@ -229,9 +231,9 @@ def build_ring_engine(tiny, max_batch, max_len, **kw):
     """The benchmark's Laguna cut through the normal constructor: the
     published widths (the config's defaults), the first 13 layers ``F S S S
     F S S S F S S S F`` (4 full, 9 window of 512), 32 of the 256 routed
-    experts, 12,544 vocabulary rows; ``tiny`` keeps the kinds, the window
-    and the 128-wide heads (so the same routes are taken) at a size the
-    interpreter finishes."""
+    experts, 12,544 vocabulary rows; ``tiny`` keeps the kinds, the window,
+    the 128-wide heads and two K/V heads (so the same routes are taken) at
+    a size the interpreter finishes."""
     import paddle_tpu as paddle
     from paddle_tpu.models import LagunaConfig, LagunaForCausalLM
     from paddle_tpu.serving import GenerationEngine
@@ -240,8 +242,8 @@ def build_ring_engine(tiny, max_batch, max_len, **kw):
         cfg = LagunaConfig(
             vocab_size=512, hidden_size=128, intermediate_size=256,
             num_hidden_layers=13,
-            num_attention_heads_per_layer=(2, 4, 4, 4) * 10,
-            num_key_value_heads=1, num_experts=8, num_experts_per_tok=2,
+            num_attention_heads_per_layer=(4, 8, 8, 8) * 10,
+            num_key_value_heads=2, num_experts=8, num_experts_per_tok=2,
             moe_intermediate_size=128, shared_expert_intermediate_size=128,
             held_experts=(0, 1, 2, 3))
     else:
@@ -630,13 +632,13 @@ def leg_delta_rule(tiny):
             if k.startswith(("kda.step_route", "kv.row_write_route",
                              "attn.decode_route")) and v != before.get(k, 0)}
     say(f"delta-rule engine: routes of the traced steps {took}")
-    traces = took.get("kv.row_write_route.dus", 0)
+    traces = took.get("kv.row_write_route.row_dma", 0)
     check(traces >= 1 and set(took) == {
-        "kda.step_route.kernel", "kv.row_write_route.dus",
+        "kda.step_route.kernel", "kv.row_write_route.row_dma",
         "attn.decode_route.einsum_grouped"}
         and took["kda.step_route.kernel"] == 3 * traces,
-        f"delta-rule engine: expected the state kernel in 3 layers, dus and "
-        f"the grouped einsum, got {took}")
+        f"delta-rule engine: expected the state kernel in 3 layers, the row "
+        f"DMA and the grouped einsum, got {took}")
     del eng, sched
     gc.collect()
     H = 8 if tiny else 64
@@ -693,15 +695,16 @@ def leg_ring(tiny):
     say(f"ring engine: routes of the traced steps {took}")
     traces = took.get("attn.cache_route.full", 0) // 4
     check(traces >= 1 and took.get("attn.cache_route.ring") == 9 * traces
-          and took.get("kv.row_write_route.dus") == 13 * traces
+          and took.get("kv.row_write_route.row_dma") == 13 * traces
           and took.get("attn.decode_route.einsum_grouped") == 13 * traces
           and took.get("attn.prefill_band.banded", 0) >= 9
           and not any(k.startswith(("kv.row_write_route.column",
+                                    "kv.row_write_route.dus",
                                     "attn.decode_route.flash"))
                       for k in took),
           f"ring engine: expected 4 full and 9 ring layers a decode trace, "
-          f"dus and the grouped einsum in all 13, the banded prefill in the "
-          f"window layers of the long bucket, got {took}")
+          f"the row DMA and the grouped einsum in all 13, the banded prefill "
+          f"in the window layers of the long bucket, got {took}")
     del eng, sched
     gc.collect()
     h, d, n = (4, 128, 1024) if tiny else (64, 128, 2048)
@@ -717,6 +720,91 @@ def leg_ring(tiny):
     err = _rel_err(got[:, :n - 100], want[:, :n - 100])
     say(f"flash_banded_fwd s{n} h{h} window 512: rel err {err:.2e}")
     check(err < FWD_TOL, f"banded kernel off the scan by {err:.2e}")
+
+
+def leg_row_dma(tiny):
+    """The decode step's row write where a row is contiguous
+    (``ops/pallas/kv_row_dma.py``) as the three expert cells' decode steps
+    reach it: the kernel and the vmapped ``dynamic_update_slice`` must
+    agree in every element of K and V at the Laguna cut's full-length rows
+    and rings and at the Solar Open 2 cut's cache, one row a slot and
+    verify's five, each slot at a position of its own (the first, the last
+    that fits, past the end: clamped). Then the Laguna cut's 26 writes of a
+    decode step, both ways, on the clock."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.kv_row_dma import kv_row_dma
+    from paddle_tpu.serving.kv_cache import _row_update
+
+    shapes = ([(6, 256, 8, 128), (6, 32, 8, 128), (8, 128, 8, 128)] if tiny
+              else [(48, 9216, 8, 128), (48, 512, 8, 128),
+                    (128, 5120, 8, 128)])
+    keys = iter(jax.random.split(jax.random.PRNGKey(3), 64))
+
+    def rnd(shape):
+        return jax.random.normal(next(keys), shape, jnp.float32).astype(
+            jnp.bfloat16)
+
+    write = jax.jit(lambda kv, new, starts: kv_row_dma(
+        tuple(kv), tuple(new), starts), donate_argnums=0)
+    want_of = jax.jit(lambda kv, new, starts: [
+        _row_update(x, n, starts) for x, n in zip(kv, new)])
+    for shape in shapes:
+        b, max_len = shape[:2]
+        for rows in (1, 5):
+            starts = jnp.asarray(np.random.RandomState(rows).randint(
+                0, max_len, b), jnp.int32).at[:3].set(jnp.asarray(
+                    [0, max_len - rows, max_len + 7]))
+            kv = [rnd(shape) for _ in range(2)]
+            new = [rnd((b, rows) + shape[2:]) for _ in range(2)]
+            want = want_of(kv, new, starts)
+            # donated, as the decode step hands its cache over: the kernel
+            # writes into these very buffers
+            got = write(kv, new, starts)
+            same = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
+            say(f"kernels: kv_row_dma rows{rows} {list(shape)} bf16: equal "
+                f"to the dynamic_update_slice: {same}")
+            check(same, f"kv_row_dma rows{rows} {shape}: differs from "
+                        f"_row_update")
+            del kv, new, want, got
+            gc.collect()
+    # the Laguna cut's decode-step writes alone, 4 full-length layers and 9
+    # rings, K and V, each slot at a position of its own: 20 steps inside
+    # one program, so that the device's time is what the clock reads
+    full, ring = shapes[0], shapes[1]
+    layers = [full if i % 4 == 0 else ring for i in range(13)]
+    b, steps = full[0], 20
+    pos = jnp.asarray(np.random.RandomState(0).randint(0, ring[1], b),
+                      jnp.int32)
+    new = rnd((b, 1) + full[2:])
+    writes = {
+        "dus": lambda k, v, p: (_row_update(k, new, p),
+                                _row_update(v, new, p)),
+        "row_dma": lambda k, v, p: kv_row_dma((k, v), (new, new), p)}
+    ms = {}
+    for name, write in writes.items():
+        def run(ks, vs, write=write):
+            def one(i, kv):
+                out = [write(k, v, (pos + i) % ring[1]) for k, v in zip(*kv)]
+                return [k for k, _ in out], [v for _, v in out]
+            return jax.lax.fori_loop(0, steps, one, (ks, vs))
+
+        run = jax.jit(run, donate_argnums=(0, 1))
+        kv = run([jnp.zeros(s, jnp.bfloat16) for s in layers],
+                 [jnp.zeros(s, jnp.bfloat16) for s in layers])
+        jax.block_until_ready(kv)
+        t0 = time.perf_counter()
+        for _ in range(1 if tiny else 5):
+            kv = run(*kv)
+        jax.block_until_ready(kv)
+        ms[name] = ((time.perf_counter() - t0) * 1e3
+                    / ((1 if tiny else 5) * steps))
+        del kv, run
+        gc.collect()
+    say(f"kernels: the Laguna cut's 26 row writes a decode step "
+        f"(b{b}, 4 x {full[1]} + 9 x {ring[1]} rows, {steps} steps a "
+        f"program): dus {ms['dus']:.3f} ms, row_dma {ms['row_dma']:.3f} ms")
 
 
 def leg_dp4(size, one_chip_losses):
@@ -792,7 +880,13 @@ def main(argv=None):
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny CPU rehearsal of the script (Pallas "
                          "interpreted); proves nothing about the chip")
+    ap.add_argument("--legs", default=",".join(LEGS),
+                    help=f"the legs to run, of {','.join(LEGS)} (dp4 "
+                         f"needs train)")
     args = ap.parse_args(argv)
+    legs = args.legs.split(",")
+    if set(legs) - set(LEGS):
+        sys.exit(f"chip_smoke: no leg {sorted(set(legs) - set(LEGS))}")
 
     import jax
 
@@ -833,22 +927,26 @@ def main(argv=None):
           else contextlib.nullcontext()):
         check(args.rehearse or not pallas.interpret_requested(),
               "Pallas interpret mode on the chip path")
-        losses = leg_train(size)
+        losses = leg_train(size) if "train" in legs else None
         gc.collect()
-        leg_kernels(size)
-        gc.collect()
-        for leg in SERVE_LEGS:
+        if "kernels" in legs:
+            leg_kernels(size)
+            gc.collect()
+        for leg in SERVE_LEGS if "serve" in legs else ():
             leg_serve(size, *leg)
             gc.collect()
-        leg_delta_rule(args.rehearse)
-        gc.collect()
-        leg_ring(args.rehearse)
-        gc.collect()
-        leg_dp4(size, losses)
+        for name, leg in (("delta_rule", leg_delta_rule), ("ring", leg_ring),
+                          ("row_dma", leg_row_dma)):
+            if name in legs:
+                leg(args.rehearse)
+                gc.collect()
+        if "dp4" in legs and losses is not None:
+            leg_dp4(size, losses)
     say(f"memory_stats of device 0: {dev.memory_stats()}")
     say(stats.line(cache_dir))
-    say(f"chip_smoke: all legs passed in {time.perf_counter() - t_start:.0f}s")
-    result = {"ok": True, "device": device}
+    say(f"chip_smoke: legs {','.join(legs)} passed in "
+        f"{time.perf_counter() - t_start:.0f}s")
+    result = {"ok": True, "device": device, "legs": legs}
     if args.rehearse:
         result["rehearsal"] = True
     print(json.dumps(result), flush=True)

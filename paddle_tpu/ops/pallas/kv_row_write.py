@@ -82,6 +82,13 @@ def supports_row_write(rows, max_len, heads, head_dim, itemsize=2, buffers=2):
             and 4 * buffers * width * LANES * itemsize <= BLOCK_BYTES)
 
 
+def clamped_starts(starts, max_len, rows):
+    """The starts as ``lax.dynamic_update_slice`` reads them: a negative one
+    counts from the end, then each is clamped so that ``rows`` rows fit."""
+    pos = jnp.asarray(starts, jnp.int32)
+    return jnp.clip(jnp.where(pos < 0, pos + max_len, pos), 0, max_len - rows)
+
+
 def _visited_column(pos_ref, bb, visit, rows):
     """128-lane column the ``visit``-th grid step of slot ``bb`` takes: the
     first row's, then the last row's."""
@@ -191,10 +198,7 @@ def kv_row_write(bufs, news, starts, *, interpret=None):
             f"buffer={bufs[0].shape} {bufs[0].dtype}")
     width = h * d
     pad = -width % LANES
-    # the start as lax.dynamic_update_slice reads it: a negative one counts
-    # from the end, then it is clamped so that the rows fit
-    pos = jnp.asarray(starts, jnp.int32)
-    pos = jnp.clip(jnp.where(pos < 0, pos + max_len, pos), 0, max_len - s)
+    pos = clamped_starts(starts, max_len, s)
     flat = [jnp.pad(n.reshape(b, s, width), ((0, 0), (0, 0), (0, pad)))
             for n in news]
     views = [jnp.transpose(x, (0, 2, 3, 1)).reshape(b, width, max_len)

@@ -16,17 +16,20 @@ through ``CompiledStep``'s ``donate_inputs`` the write happens in place in
 HBM.
 
 How the decode step's row is written is chosen by :func:`row_write_route`
-from the layout the backend keeps the buffer in. XLA:CPU, and XLA:TPU when
-``head_dim`` fills the 128 lanes, keep a row contiguous: there a vmapped
-``lax.dynamic_update_slice`` (:func:`_row_update`) is one small in-place
-update (arxiv 2301.13062). With ``head_dim`` under 128 XLA:TPU keeps the
-buffer as ``[batch, heads, head_dim, max_len]``, ``max_len`` on the lanes
-(what the decode attention kernel wants, ``ops/pallas/flash_decode.py``): a
-row is then ``heads * head_dim`` elements in as many lane rows, the same
-``dynamic_update_slice`` compiles to a ``while`` of one-row updates a slot
-(68% of a GPT-2 large decode step on the v5e), and the Pallas kernel
-``ops/pallas/kv_row_write.py`` rewrites the one 128-lane column of each
-slot that holds its position instead, aliased onto the donated buffer.
+from the layout the backend keeps the buffer in. On XLA:TPU the vmapped
+``lax.dynamic_update_slice`` (:func:`_row_update`) compiles to a ``while``
+of one-row updates, one trip a slot, whatever the layout, so the TPU takes
+one of two aliased Pallas kernels instead. With ``head_dim`` under 128
+XLA:TPU keeps the buffer as ``[batch, heads, head_dim, max_len]``,
+``max_len`` on the lanes (what the decode attention kernel wants,
+``ops/pallas/flash_decode.py``): a row is ``heads * head_dim`` elements in
+as many lane rows (the loop took 68% of a GPT-2 large decode step on the
+v5e), and ``ops/pallas/kv_row_write.py`` rewrites the one 128-lane column
+of each slot that holds its position. With ``head_dim`` in whole 128-lane
+tiles the buffer is row-major, a position's row a run of whole tiles (the
+loops took 3.8 ms of the Laguna cut's 19.5 ms decode program), and
+``ops/pallas/kv_row_dma.py`` copies each slot's new rows into place with
+one DMA. XLA:CPU keeps the ``dynamic_update_slice``.
 
 Masking carries the variable part: attention always runs over the full
 ``max_len`` keys and the per-slot lengths mask out the not-yet-written
@@ -225,11 +228,10 @@ class KVCache:
 def _row_update(buf, new, starts):
     """Batched row write: ``buf[i, starts[i]:starts[i]+s] = new[i]`` via a
     vmapped ``dynamic_update_slice`` (per-slot scalar start index, static
-    shapes). Where a row is contiguous in the backend's layout (XLA:CPU;
-    XLA:TPU at ``head_dim >= 128``) this is one small in-place update of a
-    donated buffer; where XLA:TPU puts ``max_len`` on the lanes it is a
-    ``while`` of one-row updates a slot, and :func:`row_write_route` sends
-    the decode step to the column kernel instead."""
+    shapes). XLA:CPU writes it in place in a donated buffer; XLA:TPU
+    compiles it to a ``while`` of one-row updates, one trip a slot, at any
+    ``head_dim``, and :func:`row_write_route` sends the decode step to a
+    kernel there where one takes the shape."""
 
     def one(b, n, s):
         z = jnp.int32(0)
@@ -240,20 +242,24 @@ def _row_update(buf, new, starts):
 
 def row_write_route(*, rows, max_len, heads, head_dim, itemsize, pallas):
     """The one place the decode-shaped row write is chosen: ``column_kernel``
-    (``ops/pallas/kv_row_write.py``) or ``dus`` (:func:`_row_update`). Shape
+    (``ops/pallas/kv_row_write.py``), ``row_dma``
+    (``ops/pallas/kv_row_dma.py``) or ``dus`` (:func:`_row_update`). Shape
     facts and what the platform tells (``pallas.is_available()``: a TPU
     backend, or a test's ``interpret_mode()``) come in as arguments and no
     global state is read, so a test can ask what the chip compiles.
 
-    The kernel takes the layouts in which XLA:TPU puts ``max_len`` on the
-    lanes (``head_dim`` under 128) in whole 128-lane columns; XLA:CPU, a
-    ``head_dim`` that fills the lanes (a row is contiguous there already), a
-    ``max_len`` that is no multiple of 128 and blocks too large for VMEM
-    keep the ``dynamic_update_slice``."""
+    The column kernel takes the layouts in which XLA:TPU puts ``max_len`` on
+    the lanes (``head_dim`` under 128) in whole 128-lane columns; the DMA
+    kernel those in which a row is contiguous (``head_dim`` in whole 128-lane
+    tiles, heads in whole sublane tiles); XLA:CPU, and every shape neither
+    kernel takes, keep the ``dynamic_update_slice``."""
+    from ..ops.pallas.kv_row_dma import supports_row_dma
     from ..ops.pallas.kv_row_write import supports_row_write
 
     if pallas and supports_row_write(rows, max_len, heads, head_dim, itemsize):
         return "column_kernel"
+    if pallas and supports_row_dma(rows, max_len, heads, head_dim, itemsize):
+        return "row_dma"
     return "dus"
 
 
@@ -293,10 +299,11 @@ class DecodeView:
     ``update(k_new, v_new)`` writes each slot's new K/V rows (decode one,
     verify ``spec_k + 1``) from that slot's position index on and returns
     the FULL buffers for attention (the length mask hides the invalid
-    tail). :func:`row_write_route` picks the write: the column kernel, K
-    and V in one call, where the TPU keeps ``max_len`` on the lanes, the
-    vmapped ``dynamic_update_slice`` elsewhere; the two agree element for
-    element. The kernel writes into the buffers it is handed, so on a TPU
+    tail). :func:`row_write_route` picks the write: on a TPU one of two
+    kernels, K and V in one call (the column kernel where the TPU keeps
+    ``max_len`` on the lanes, one DMA a slot where a row is contiguous),
+    else the vmapped ``dynamic_update_slice``; all agree element for
+    element. A kernel writes into the buffers it is handed, so on a TPU
     the step that builds this view donates its cache (every serving step
     does; ``kv_row_write.py`` says what XLA does otherwise). The updated
     buffers stay on the view; the engine collects them into the next
@@ -332,6 +339,10 @@ class DecodeView:
 
             self.k, self.v = kv_row_write((self.k, self.v), (kn, vn),
                                           self.pos)
+        elif route == "row_dma":
+            from ..ops.pallas.kv_row_dma import kv_row_dma
+
+            self.k, self.v = kv_row_dma((self.k, self.v), (kn, vn), self.pos)
         else:
             self.k = _row_update(self.k, kn, self.pos)
             self.v = _row_update(self.v, vn, self.pos)

@@ -1,7 +1,7 @@
-"""The decode step's K/V row write (ISSUE 33): the column kernel
-(``ops/pallas/kv_row_write.py``, interpret mode here) against
-``kv_cache._row_update``, the table of ``row_write_route``, and serving
-through either route.
+"""The decode step's K/V row write (ISSUE 33, 37): the column kernel
+(``ops/pallas/kv_row_write.py``) and the row DMA (``ops/pallas/
+kv_row_dma.py``), interpret mode here, against ``kv_cache._row_update``,
+the table of ``row_write_route``, and serving through each route.
 
 Contracts under test:
   * exact equality of the WHOLE buffer with the vmapped
@@ -16,6 +16,8 @@ Contracts under test:
     verify, the counter ``kv.row_write_route.<route>`` says which one a step
     traced, and ``serve_decode`` still compiles exactly once.
 """
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.ops import pallas
+from paddle_tpu.ops.pallas import kv_row_dma as D
 from paddle_tpu.ops.pallas import kv_row_write as W
 from paddle_tpu.profiler import telemetry
 from paddle_tpu.serving import GenerationEngine
@@ -202,6 +205,118 @@ def test_under_a_mesh_the_kernel_partitions_itself_over_the_batch():
 
 
 # ---------------------------------------------------------------------------
+# the row DMA (head_dim in whole 128-lane tiles) against _row_update
+# ---------------------------------------------------------------------------
+def _dma_both(bufs, news, starts):
+    got = D.kv_row_dma(tuple(bufs), tuple(news), starts, interpret=True)
+    want = [C._row_update(x, n, starts) for x, n in zip(bufs, news)]
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("heads", [2, 8])
+@pytest.mark.parametrize("rows", [1, 5, 8])
+def test_row_dma_equals_row_update(heads, rows):
+    """Decode and verify at heads of 128: a slot at the first position, one
+    at the last that fits, two past it (clamped as ``dynamic_update_slice``
+    clamps), one counted from the end, the rest anywhere."""
+    max_len = 64
+    bufs, news = _buffers(8, max_len, heads, 128, rows, jnp.bfloat16,
+                          seed=rows)
+    starts = jnp.asarray([0, max_len - rows, max_len - rows + 1, 10 ** 6,
+                          -3, 17, 31, 40], jnp.int32)
+    _dma_both(bufs, news, starts)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_row_dma_at_ring_positions(dtype):
+    """A ring of 32 rows written at ``pos mod 32``, the positions past the
+    window wrapped, as the engine hands a window layer its row."""
+    pos = np.asarray([5, 31, 32, 63, 100, 1023], np.int32)
+    bufs, news = _buffers(6, 32, 8, 128, 1, dtype, seed=3)
+    _dma_both(bufs, news, jnp.asarray(pos % 32))
+
+
+def test_row_dma_writes_dead_slots_where_row_update_does():
+    """A dead slot keeps the stale length its last request left, or the
+    engine's clamped one (``max_len - 1``), and is written like any other."""
+    bufs, news = _buffers(4, 48, 2, 128, 1, jnp.bfloat16, seed=9)
+    _dma_both(bufs, news, jnp.asarray([47, 12, 47, 0], jnp.int32))
+
+
+def test_row_dma_k_and_v_in_one_call_agree_with_one_buffer_a_call():
+    bufs, news = _buffers(4, 32, 8, 128, 5, jnp.bfloat16)
+    starts = jnp.asarray([0, 27, 12, 30], jnp.int32)
+    both = D.kv_row_dma(tuple(bufs), tuple(news), starts, interpret=True)
+    for x, n, w in zip(bufs, news, both):
+        (alone,) = D.kv_row_dma((x,), (n,), starts, interpret=True)
+        np.testing.assert_array_equal(_bits(alone), _bits(w))
+
+
+@pytest.mark.parametrize("nans", [False, True], ids=["bits", "nans"])
+def test_row_dma_keeps_every_byte_outside_the_written_rows(nans):
+    """Random bits around the written rows and in them: infinities,
+    negative zeros and subnormals come back bit for bit. A NaN stays a NaN
+    where it was (XLA:CPU's interpreter may quiet its payload; the chip's
+    DMA moves bytes, and ``chip_smoke.py`` holds it bit for bit)."""
+    b, max_len, h, d, s = 3, 32, 2, 128, 3
+    rng = np.random.RandomState(5)
+    raw = _random_bits(rng, (2, b, max_len, h, d), nans)
+    new_raw = _random_bits(rng, (2, b, s, h, d), nans)
+    new_raw[0, 0, 0, 0, :3] = [0x8000, 0x7F80, 0xFF80]  # -0.0, inf, -inf
+    starts = np.asarray([0, 29, 14], np.int32)
+    got = D.kv_row_dma(tuple(jnp.asarray(r).view(jnp.bfloat16) for r in raw),
+                       tuple(jnp.asarray(r).view(jnp.bfloat16)
+                             for r in new_raw), jnp.asarray(starts),
+                       interpret=True)
+    for g, before, new in zip(got, raw, new_raw):
+        want = before.copy()
+        for i, p in enumerate(starts):
+            want[i, p:p + s] = new[i]
+        if nans:
+            g = np.asarray(g, np.float32)
+            want = np.asarray(jnp.asarray(want).view(jnp.bfloat16),
+                              np.float32)
+            np.testing.assert_array_equal(np.isnan(g), np.isnan(want))
+            np.testing.assert_array_equal(g[~np.isnan(g)],
+                                          want[~np.isnan(want)])
+        else:
+            np.testing.assert_array_equal(_bits(g), want)
+
+
+def test_the_row_dma_refuses_what_the_route_does_not_send_it():
+    bufs, news = _buffers(2, 32, 3, 128, 1, jnp.bfloat16)
+    with pytest.raises(ValueError, match="sublane"):
+        D.kv_row_dma(tuple(bufs), tuple(news), jnp.zeros((2,), jnp.int32),
+                     interpret=True)
+
+
+def test_under_a_mesh_the_row_dma_partitions_itself_over_the_batch():
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.partition import partition_scope
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    bufs, news = _buffers(8, 32, 2, 128, 1, jnp.bfloat16)
+    starts = jnp.asarray([0, 31, 5, 6, 1, 2, 3, 20], jnp.int32)
+    row = NamedSharding(mesh, P("dp"))
+    placed = jax.device_put((bufs, news, starts), row)
+
+    def write(bufs, news, starts):
+        with partition_scope((mesh, ("dp",))):
+            return D.kv_row_dma(tuple(bufs), tuple(news), starts,
+                                interpret=True)
+
+    got = jax.jit(write)(*placed)
+    for g, x, n in zip(got, bufs, news):
+        assert g.sharding.is_equivalent_to(row, g.ndim)
+        np.testing.assert_array_equal(
+            _bits(g), _bits(C._row_update(x, n, starts)))
+
+
+# ---------------------------------------------------------------------------
 # the route table, read without a chip
 # ---------------------------------------------------------------------------
 TPU = dict(pallas=True)
@@ -210,6 +325,9 @@ INTERPRET = dict(pallas=True)  # is_available() is true inside interpret_mode
 
 LARGE = dict(max_len=1024, heads=20, head_dim=64, itemsize=2)  # gpt2-large
 HYBRID = dict(max_len=2048, heads=2, head_dim=128, itemsize=2)  # nemotron-3
+LAGUNA = dict(max_len=9216, heads=8, head_dim=128, itemsize=2)  # full rows
+RING = dict(LAGUNA, max_len=512)  # laguna's window layers
+SOLAR = dict(max_len=5120, heads=8, head_dim=128, itemsize=2)  # solar-open2
 
 
 def _row(name, platform, expect, *, rows=1, **facts):
@@ -227,7 +345,23 @@ ROUTE_ROWS = [
          head_dim=64, itemsize=2),
     _row("tpu-generate-batch-1-max-len-128", TPU, "column_kernel",
          max_len=128, heads=20, head_dim=64, itemsize=2),
-    _row("tpu-hybrid-head-dim-128", TPU, "dus", **HYBRID),
+    _row("tpu-hybrid-head-dim-128", TPU, "row_dma", **HYBRID),
+    _row("tpu-laguna-full-decode", TPU, "row_dma", **LAGUNA),
+    _row("tpu-laguna-ring-decode", TPU, "row_dma", **RING),
+    _row("tpu-solar-decode", TPU, "row_dma", **SOLAR),
+    _row("tpu-solar-verify-8", TPU, "row_dma", rows=8, **SOLAR),
+    _row("tpu-solar-rows-9", TPU, "dus", rows=9, **SOLAR),
+    _row("tpu-solar-f32", TPU, "row_dma", **dict(SOLAR, itemsize=4)),
+    _row("tpu-head-dim-256", TPU, "row_dma", **dict(SOLAR, head_dim=256)),
+    _row("tpu-head-dim-192", TPU, "dus", **dict(SOLAR, head_dim=192)),
+    _row("tpu-one-bf16-head-of-128", TPU, "dus", **dict(SOLAR, heads=1)),
+    _row("tpu-one-f32-head-of-128", TPU, "row_dma",
+         **dict(SOLAR, heads=1, itemsize=4)),
+    _row("tpu-six-heads-of-128", TPU, "dus", **dict(SOLAR, heads=6)),
+    _row("tpu-24-heads-of-128", TPU, "row_dma", **dict(SOLAR, heads=24)),
+    _row("tpu-head-dim-128-one-byte", TPU, "dus", **dict(SOLAR, itemsize=1)),
+    _row("tpu-ring-of-4-verify-5", TPU, "dus", rows=5,
+         **dict(RING, max_len=4)),
     _row("tpu-max-len-1000", TPU, "dus", **dict(LARGE, max_len=1000)),
     _row("tpu-max-len-64", TPU, "dus", **dict(LARGE, max_len=64)),
     _row("tpu-head-dim-8-under-a-bf16-tile", TPU, "dus",
@@ -239,8 +373,10 @@ ROUTE_ROWS = [
     _row("cpu-large-decode", CPU, "dus", **LARGE),
     _row("cpu-large-verify-5", CPU, "dus", rows=5, **LARGE),
     _row("cpu-hybrid", CPU, "dus", **HYBRID),
+    _row("cpu-laguna-full-decode", CPU, "dus", **LAGUNA),
+    _row("cpu-solar-verify-5", CPU, "dus", rows=5, **SOLAR),
     _row("interpret-large-decode", INTERPRET, "column_kernel", **LARGE),
-    _row("interpret-hybrid", INTERPRET, "dus", **HYBRID),
+    _row("interpret-hybrid", INTERPRET, "row_dma", **HYBRID),
     _row("interpret-toy-two-heads-of-64", INTERPRET, "column_kernel",
          max_len=128, heads=2, head_dim=64, itemsize=4),
 ]
@@ -282,12 +418,14 @@ def _counters():
     telemetry.reset()
 
 
-def _kernel_model(seed=0):
-    """Two heads of 64 over a 128-slot cache: shapes the kernel takes."""
+def _kernel_model(seed=0, head_dim=64):
+    """Two heads of 64 over a 128-slot cache: shapes the column kernel
+    takes (two heads of 128: the row DMA's)."""
     with unique_name.guard():
         paddle.seed(seed)
         model = GPTForCausalLM(GPTConfig(
-            vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
+            vocab_size=512, hidden_size=2 * head_dim, num_layers=2,
+            num_heads=2,
             max_position_embeddings=128, hidden_dropout=0.0,
             attention_dropout=0.0, initializer_range=0.6))
     model.eval()
@@ -299,16 +437,24 @@ def _route_counts(tm):
             if k.startswith("kv.row_write_route.")}
 
 
+#: a kernel's route, the head size that takes it, and how a test takes it
+#: away again (the column kernel's VMEM budget at 0; the row DMA's gate shut)
+KERNELS = {"column_kernel": (64, W, "BLOCK_BYTES", 0),
+           "row_dma": (128, D, "supports_row_dma", lambda *a, **k: False)}
+
+
+@pytest.mark.parametrize("route", sorted(KERNELS))
 @pytest.mark.parametrize("engine_kw,prompt_len", [
     ({}, 7), ({"spec_k": 4}, 9)], ids=["decode", "verify"])
 def test_greedy_serving_byte_identical_between_the_two_routes(
         _no_persistent_compile_cache, _counters, monkeypatch, engine_kw,
-        prompt_len):
+        prompt_len, route):
     """The same engine, both times in interpret mode (so attention takes the
-    same kernel): once with the column kernel writing the rows, once with
-    the kernel's VMEM budget at 0, which leaves ``dus``. The same tokens,
-    and each run's counter names the route its steps traced, once a layer."""
-    model = _kernel_model()
+    same kernel): once with the kernel writing the rows, once with the
+    kernel taken away, which leaves ``dus``. The same tokens, and each
+    run's counter names the route its steps traced, once a layer."""
+    head_dim, module, name, off = KERNELS[route]
+    model = _kernel_model(head_dim=head_dim)
     rng = np.random.RandomState(11)
     # a periodic prompt: the n-gram proposer drafts from the first tick
     prompt = np.tile(rng.randint(0, 512, 3), 4)[:prompt_len].tolist()
@@ -322,16 +468,37 @@ def test_greedy_serving_byte_identical_between_the_two_routes(
 
     with pallas.interpret_mode():
         kernel, kernel_routes = gen()
-        monkeypatch.setattr(W, "BLOCK_BYTES", 0)
+        monkeypatch.setattr(module, name, off)
         dus, dus_routes = gen()
     assert len(set(kernel)) > 2, "degenerate model; parity is vacuous"
     assert kernel == dus
-    assert set(kernel_routes) == {"column_kernel"}, kernel_routes
+    assert set(kernel_routes) == {route}, kernel_routes
     assert set(dus_routes) == {"dus"}, dus_routes
     # bumped once a layer whenever the step is traced (CompiledStep traces
     # it more than once): the same number either way, the two layers' share
-    assert kernel_routes["column_kernel"] == dus_routes["dus"] > 0
-    assert kernel_routes["column_kernel"] % 2 == 0
+    assert kernel_routes[route] == dus_routes["dus"] > 0
+    assert kernel_routes[route] % 2 == 0
+
+
+@pytest.mark.parametrize("family", ["laguna", "solar_open2", "nemotron_h"])
+def test_the_expert_decoders_write_every_kv_layer_by_row_dma(_counters,
+                                                             family):
+    """At heads of 128, as the benchmark's expert cuts hold them, a traced
+    decode step writes each K/V layer's rows with the row DMA where Pallas
+    is on offer, once a layer: rings and full-length rows alike (the cuts
+    themselves: 13 / 1 / 2, ``chip_smoke.py`` counts the first two on the
+    chip)."""
+    import importlib
+
+    tiny = importlib.import_module(f"{family}_tiny")
+    model, _ = tiny.build(tiny.tiny_config(head_dim=128))
+    kv_layers = sum(1 for e in model.cache_spec()
+                    if e and e["kind"] == "kv")
+    with pallas.interpret_mode():
+        eng = GenerationEngine(model, max_batch=2, max_len=64)
+        eng.decode_step.lower(*eng.example_decode_args([1, 0]))
+    assert kv_layers >= 1
+    assert _route_counts(_counters) == {"row_dma": kv_layers}
 
 
 def test_xla_cpu_keeps_the_dynamic_update_slice(_counters):
@@ -356,17 +523,20 @@ def test_decode_still_compiles_once_through_the_column_kernel(_counters):
     assert set(_route_counts(_counters)) == {"column_kernel"}
 
 
-def test_a_head_dim_that_fills_the_lanes_counts_dus_under_interpret(
-        _counters):
-    """The hybrid configuration's attention blocks hold heads of 128: a row
-    is contiguous on the TPU, and the route says ``dus`` whatever Pallas
-    offers."""
+@pytest.mark.parametrize("platform", ["interpret", "cpu"])
+def test_a_head_dim_that_fills_the_lanes_counts_its_route(_counters,
+                                                          platform):
+    """The expert configurations' attention holds heads of 128: a row is
+    contiguous on the TPU, and where Pallas is on offer the route says
+    ``row_dma``; XLA:CPU keeps ``dus``. Either way the same rows land."""
     k = jnp.zeros((2, 128, 2, 128), jnp.bfloat16)
     new = jnp.ones((2, 1, 2, 128), jnp.bfloat16)
-    with pallas.interpret_mode():
+    with (pallas.interpret_mode() if platform == "interpret"
+          else contextlib.nullcontext()):
         view = C.DecodeView(k, k, jnp.asarray([3, 127], jnp.int32))
         out_k, _, _ = view.update(new, new)
-    assert _route_counts(_counters) == {"dus": 1}
+    route = "row_dma" if platform == "interpret" else "dus"
+    assert _route_counts(_counters) == {route: 1}
     np.testing.assert_array_equal(
         np.asarray(out_k._value[:, :, 0, 0], np.float32)[[0, 1], [3, 127]],
         [1.0, 1.0])

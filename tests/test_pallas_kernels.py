@@ -658,6 +658,16 @@ def _site_kv_row_write():
         k, k, new, new)
 
 
+def _site_kv_row_dma():
+    from paddle_tpu.ops.pallas.kv_row_dma import kv_row_dma
+
+    k = jnp.ones((2, 16, 2, 128), jnp.float32)
+    new = jnp.ones((2, 1, 2, 128), jnp.float32)
+    starts = np.asarray([3, 15], np.int32)
+    return (lambda k, v, kn, vn: kv_row_dma((k, v), (kn, vn), starts)), (
+        k, k, new, new)
+
+
 @pytest.mark.parametrize("site,expected", [
     (_site_flash, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     (_site_flash_cached, {"flash_cached_fwd"}),
@@ -666,8 +676,9 @@ def _site_kv_row_write():
     (_site_flash_packed, {"flash_packed_fwd", "flash_packed_bwd"}),
     (_site_layer_norm, {"layer_norm_fwd", "layer_norm_bwd"}),
     (_site_kv_row_write, {"kv_row_write"}),
+    (_site_kv_row_dma, {"kv_row_dma"}),
 ], ids=["flash", "flash_cached", "flash_banded", "flash_decode",
-        "flash_packed", "layer_norm", "kv_row_write"])
+        "flash_packed", "layer_norm", "kv_row_write", "kv_row_dma"])
 def test_every_pallas_call_site_carries_its_name(site, expected):
     """Each of these eleven ``pl.pallas_call`` sites names its kernel: the
     name is the custom call's in the device trace (``%jvp_flash_packed_fwd_``
@@ -688,4 +699,4 @@ def test_no_pallas_call_site_is_left_unnamed():
             calls += 1
             named += bool(re.match(r"\s*[\w.()=, ]+,\s*name=\"\w+\"",
                                    text[m.end():m.end() + 200]))
-    assert calls == 16 and named == calls
+    assert calls == 17 and named == calls

@@ -106,6 +106,44 @@ def test_row_write_kernel_compiles_and_writes_the_donated_buffer_in_place(
                for l in operands), out
 
 
+def test_row_dma_writes_the_donated_buffers_in_place_at_full_depth(
+        preflight):
+    # the decode step's row write where a row is contiguous (heads of 128):
+    # the kernel alone at the expert cells' caches, one row a slot and
+    # verify's five; then the Laguna cut's 13 layers (4 x 9,216 rows and 9
+    # rings of 512) at b48, donated, each layer writing then attending.
+    # There: no while loop and no dynamic-update-slice left; every cache
+    # operand of every call is a donated parameter and every output one of
+    # the program's, through bitcasts alone (no copy, no staging of a ring
+    # through VMEM around the call: PR 33 found that only at full depth);
+    # every output aliased to its donated parameter
+    import re
+
+    out = preflight.stdout
+    lines = out.splitlines()
+    for cb, sk in ((48, 9216), (48, 512), (128, 5120)):
+        for rows in (1, 5):
+            at = [l.startswith(f"ok   kv row dma b{cb} s{rows} sk{sk}:")
+                  for l in lines].index(True)
+            assert ("row DMA calls 1: cache operands that are donated "
+                    "parameters 2 of 2, outputs that are the program's 2 of "
+                    "2; while loops 0, dynamic-update-slices 0"
+                    in lines[at + 1]), lines[at + 1]
+            temp, aliased = map(int, re.search(
+                r"(\d+) temporary bytes, (\d+) of", lines[at + 1]).groups())
+            assert temp == 0 and aliased == 2 * cb * sk * 8 * 128 * 2
+    facts = lines[[l.startswith("ok   kv row dma donated decode b48 x13:")
+                   for l in lines].index(True) + 1]
+    assert ("row DMA calls 13: cache operands that are donated parameters "
+            "26 of 26, outputs that are the program's 26 of 26; while loops "
+            "0, dynamic-update-slices 0" in facts), facts
+    aliased = int(re.search(r"bytes, (\d+) of", facts).group(1))
+    assert aliased == 2 * 48 * (4 * 9216 + 9 * 512) * 8 * 128 * 2, facts
+    assert ("ok   dp4: kv row dma b8" in out
+            and "per-device Mosaic operands: s32[2] bf16[2,1,8,128] "
+                "bf16[2,1,8,128] bf16[1024,8,128] bf16[1024,8,128]" in out)
+
+
 def test_sharded_step_compiles_and_kernels_see_the_local_batch(preflight):
     # "Mosaic kernels cannot be automatically partitioned": LN + flash
     # inside one jit over the 4-device mesh must partition themselves, and

@@ -21,6 +21,7 @@ anything failed, 2 if the topology is unavailable.
 from __future__ import annotations
 
 import argparse
+import collections
 import os
 import re
 import sys
@@ -77,6 +78,72 @@ def decode_program_facts(text):
     return (f"copies of a cache buffer: {cache_buffer_copies(text, 32)}; "
             f"lists of live (slot, block) pairs built: "
             f"{pair_lists_built(text)}")
+
+
+def entry_ops(text):
+    """The ENTRY computation of a compiled program as ``{name: (opcode,
+    operand names, line)}``."""
+    ops = {}
+    for line in text[text.index("\nENTRY"):].splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = .*? ([a-z][a-z\-]*)\((.*?)\)"
+                     r"(?:, |$)", line)
+        if m:
+            ops[m.group(1)] = (m.group(2), re.findall(r"%[\w.\-]+",
+                                                      m.group(3)), line)
+    return ops
+
+
+def row_dma_facts(text):
+    """What a compiled decode-shaped program that writes its rows with
+    ``kv_row_dma`` is held to, as one line: no ``while`` and no
+    ``dynamic-update-slice`` left; every cache operand of every call is a
+    donated parameter seen through bitcasts alone, and every output of every
+    call reaches the program's outputs through bitcasts alone (no copy, no
+    staging through VMEM, in or out); and how often XLA prefetches a written
+    buffer for the attention that reads it next (a read, not a copy of the
+    cache)."""
+    ops = entry_ops(text)
+    users = collections.defaultdict(list)
+    for name, (_, operands, _) in ops.items():
+        for o in operands:
+            users[o].append(name)
+
+    def source(name):
+        while ops[name][0] == "bitcast":
+            name = ops[name][1][0]
+        return name
+
+    def reaches_root(name):
+        seen = [name]
+        while seen:
+            n = seen.pop()
+            if "ROOT " in ops[n][2]:
+                return True
+            seen += [u for u in users[n]
+                     if ops[u][0] in ("bitcast", "tuple")]
+        return False
+
+    calls = [n for n, (op, _, _) in ops.items()
+             if op == "custom-call" and "kv_row_dma" in n]
+    params = outs = buffers = 0
+    for call in calls:
+        cache_operands = ops[call][1][len(ops[call][1]) // 2 + 1:]
+        buffers += len(cache_operands)
+        params += sum(ops[source(o)][0] == "parameter"
+                      for o in cache_operands)
+        outs += sum(reaches_root(g) for g in users[call]
+                    if ops[g][0] == "get-tuple-element")
+    prefetches = sum(
+        1 for n, (op, operands, _) in ops.items()
+        if op in ("copy-start", "slice-start")
+        and ops[source(operands[0])][0] == "get-tuple-element"
+        and any(c in ops[source(operands[0])][1] for c in calls))
+    return (f"row DMA calls {len(calls)}: cache operands that are donated "
+            f"parameters {params} of {buffers}, outputs that are the "
+            f"program's {outs} of {buffers}; while loops "
+            f"{len(re.findall(r' while[(]', text))}, dynamic-update-slices "
+            f"{len(re.findall(r'dynamic-update-slice[(]', text))}; "
+            f"prefetches of a written buffer for its attention {prefetches}")
 
 
 class Report:
@@ -196,6 +263,48 @@ def kernel_programs(devs):
         write_then_attend, donate_argnums=(0, 1)).lower(
             big, big, _sds((32, wh * d), bf, one),
             _sds((wh * d, wh * d), bf, one), _sds((32,), jnp.int32, one))
+
+    # the decode step's row write where a row is contiguous (heads of 128)
+    # as the expert cells reach it: the kernel alone at the Laguna cut's
+    # full-length rows and rings and at the Solar Open 2 cut's cache, one
+    # row a slot and verify's five; then the Laguna cut's 13 layers at b48,
+    # donated, each layer writing its rows then attending over them as the
+    # grouped einsum does: what XLA stages through VMEM shows at full depth
+    from paddle_tpu.ops.pallas.kv_row_dma import kv_row_dma
+    from paddle_tpu.serving.kv_cache import DecodeView
+
+    for cb, sk in ((48, 9216), (48, 512), (128, 5120)):
+        buf = _sds((cb, sk, 8, 128), bf, one)
+        for rows in (1, 5):
+            new = _sds((cb, rows, 8, 128), bf, one)
+            yield (f"kv row dma b{cb} s{rows} sk{sk}",
+                   lambda buf=buf, new=new, cb=cb: jax.jit(
+                       lambda k, v, kn, vn, pos: kv_row_dma(
+                           (k, v), (kn, vn), pos),
+                       donate_argnums=(0, 1)).lower(
+                           buf, buf, new, new, _sds((cb,), jnp.int32, one)))
+
+    def laguna_writes(ks, vs, x, w, pos):
+        out_k, out_v = [], []
+        for k, v in zip(ks, vs):
+            rows = k.shape[1]
+            new = (x @ w).reshape(48, 1, 8, 128)
+            k, v, _ = DecodeView(k, v, pos % rows).update(new, new)
+            k, v = k._value, v._value
+            p = jax.nn.softmax(jnp.einsum(
+                "bqhd,bkhd->bhqk", new, k).astype(jnp.float32), axis=-1)
+            x = jnp.einsum("bhqk,bkhd->bqhd", p.astype(bf), v).reshape(
+                48, 1024)
+            out_k.append(k)
+            out_v.append(v)
+        return x, out_k, out_v
+
+    laguna = [_sds((48, 9216 if i % 4 == 0 else 512, 8, 128), bf, one)
+              for i in range(13)]
+    yield "kv row dma donated decode b48 x13", lambda: jax.jit(
+        laguna_writes, donate_argnums=(0, 1)).lower(
+            laguna, laguna, _sds((48, 1024), bf, one),
+            _sds((1024, 1024), bf, one), _sds((48,), jnp.int32, one))
 
     # the expert and state-space kernels at the widths the benchmark's
     # hybrid configuration serves: hidden 2688, expert width 1856 (no
@@ -324,6 +433,16 @@ def kernel_programs(devs):
     yield "dp4: kv row write b8", lambda: jax.jit(
         write, donate_argnums=(0, 1)).lower(
             kvs, kvs, news, news, _sds((gb,), jnp.int32, row))
+
+    def write_rows(k, v, kn, vn, pos):
+        with partition_scope((mesh, ("dp",))):
+            return kv_row_dma((k, v), (kn, vn), pos)
+
+    rows128 = _sds((gb, 512, 8, 128), bf, row)
+    new128 = _sds((gb, 1, 8, 128), bf, row)
+    yield "dp4: kv row dma b8", lambda: jax.jit(
+        write_rows, donate_argnums=(0, 1)).lower(
+            rows128, rows128, new128, new128, _sds((gb,), jnp.int32, row))
 
 
 # ---------------------------------------------------------------------------
@@ -488,6 +607,12 @@ def main(argv=None):
                 print(f"       outputs aliased to parameters: "
                       f"{' '.join(f'{o}<-{i}' for o, i in pairs) or 'none'}; "
                       f"{decode_program_facts(text)}", flush=True)
+        if compiled is not None and name.startswith("kv row dma"):
+            ma = compiled.memory_analysis()
+            print(f"       {ma.temp_size_in_bytes} temporary bytes, "
+                  f"{ma.alias_size_in_bytes} of {ma.argument_size_in_bytes} "
+                  f"argument bytes aliased; "
+                  f"{row_dma_facts(compiled.as_text())}", flush=True)
         if compiled is not None and name.startswith(("gated experts",
                                                      "kda step")):
             # no copy of an expert stack (0.84 GB) or of the states (0.54
@@ -546,6 +671,8 @@ def main(argv=None):
                       f"stack: {len(copies)}; aliased "
                       f"{ma.alias_size_in_bytes / 2**30:.2f} GiB",
                       flush=True)
+                if "serve_decode" in name:
+                    print(f"       {row_dma_facts(text)}", flush=True)
     if rep.failed:
         print(f"{len(rep.failed)} program(s) failed: {rep.failed}")
         return 1
