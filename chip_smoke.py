@@ -69,7 +69,8 @@ SERVE_LEGS = (
      [9, 100, 300, 700, 120, 14], [16, 12, 24, 16, 8, 20]),
 )
 #: what ``--legs`` may name, in the order they run
-LEGS = ("train", "kernels", "serve", "delta_rule", "ring", "row_dma", "dp4")
+LEGS = ("train", "kernels", "serve", "delta_rule", "ring",
+        "column_kernel", "row_dma", "dp4")
 #: kernel vs XLA formulation in fp32/highest: max|a-b| / max|b|. Forward
 #: outputs are one bf16 rounding apart; gradients accumulate bf16 products
 #: over 1024 keys (attention) or 24k rows (LayerNorm dgamma/dbeta).
@@ -469,27 +470,6 @@ def leg_kernels(size):
             f"{same}; dead rows zero: {zeros}")
         check(same, f"{name}: a live row differs from the slot-grid kernel")
         check(zeros, f"{name}: a dead slot's rows are not zero")
-    # the row write as the serving cells' decode step reaches it (32 slots of
-    # 1024 positions, GPT-2 large's 20 heads of 64; the rehearsal keeps its
-    # own heads), each slot at a position of its own: the kernel and the
-    # vmapped dynamic_update_slice must agree in every element of K and V
-    from paddle_tpu.ops.pallas.kv_row_write import kv_row_write
-    from paddle_tpu.serving.kv_cache import _row_update
-
-    starts = lens.at[3:5].set(jnp.asarray([127, 128]))
-    for rows in (1, 5):
-        kv = [rnd((db, sk, wh, d), bf) for _ in range(2)]
-        new = [rnd((db, rows, wh, d), bf) for _ in range(2)]
-        want = jax.jit(lambda kv, new: [
-            _row_update(x, n, starts) for x, n in zip(kv, new)])(kv, new)
-        # donated, as the decode step hands its cache over: the kernel's
-        # outputs are pinned to HBM and alias these very buffers
-        got = jax.jit(lambda kv, new: kv_row_write(
-            tuple(kv), tuple(new), starts), donate_argnums=0)(kv, new)
-        same = all(bool(jnp.array_equal(g, w)) for g, w in zip(got, want))
-        say(f"kernels: kv_row_write rows{rows} sk{sk} b{db} "
-            f"{wh}x{d} bf16: equal to the dynamic_update_slice: {same}")
-        check(same, f"kv_row_write rows{rows}: differs from _row_update")
 
 
 def _periodic_prompt(rng, vocab, n):
@@ -722,6 +702,109 @@ def leg_ring(tiny):
     check(err < FWD_TOL, f"banded kernel off the scan by {err:.2e}")
 
 
+def leg_column_kernel(tiny):
+    """The decode step's row write where XLA:TPU keeps ``max_len`` on the
+    lanes (``ops/pallas/kv_row_write.py``) as the two ``gpt2_large`` cells'
+    decode steps reach it (32 slots of 1,024 positions, 20 heads of 64),
+    under the engine's mask of live slots: 7 of 32 live, each at a position
+    of its own (the first, a column's last and first, the last that fits,
+    past the end: clamped), one row a slot and verify's five. Live slots
+    must come back bit for bit what the vmapped ``dynamic_update_slice``
+    gives, dead ones as they were; with every slot live the whole buffers
+    must equal it, and with none live all but one slot must be as they
+    were. Then the 36 layers' writes of a decode step at 7 and at 32 of 32
+    live, on the clock."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.kv_row_write import kv_row_write
+    from paddle_tpu.serving.kv_cache import _row_update
+
+    b, max_len, heads, d = (8, 256, 2, 64) if tiny else (32, 1024, 20, 64)
+    shape = (b, max_len, heads, d)
+    live7 = np.zeros((b,), bool)
+    live7[[0, 3, 5, 9, 14, 20, 31] if b == 32 else [0, 3, 5]] = True
+
+    def rnd(key, shape):
+        return jax.random.normal(jax.random.PRNGKey(key), shape,
+                                 jnp.float32).astype(jnp.bfloat16)
+
+    # donated, as the decode step hands its cache over: the kernel's outputs
+    # are pinned to HBM and alias these very buffers
+    write = jax.jit(lambda kv, new, starts, live: kv_row_write(
+        tuple(kv), tuple(new), starts, live), donate_argnums=0)
+    want_of = jax.jit(lambda kv, new, starts: [
+        _row_update(x, n, starts) for x, n in zip(kv, new)])
+    for rows in (1, 5):
+        starts = jnp.asarray(np.random.RandomState(rows).randint(
+            0, max_len, b), jnp.int32).at[:5].set(jnp.asarray(
+                [0, 127, 128, max_len - rows, max_len + 7]))
+        few = f"{live7.sum()} of {b} live"
+        for label, live in ((few, live7),
+                            ("all live", np.ones((b,), bool)),
+                            ("none live", np.zeros((b,), bool))):
+            new = [rnd(10 + i, (b, rows, heads, d)) for i in range(2)]
+            before = [rnd(i, shape) for i in range(2)]
+            want = want_of(before, new, starts)
+            got = write([jnp.copy(x) for x in before], new, starts,
+                        jnp.asarray(live))
+            same = jax.jit(lambda xs, ys: jnp.stack([
+                jnp.all(x == y, axis=(1, 2, 3))
+                for x, y in zip(xs, ys)]).all(0))
+            written = np.asarray(same(got, want))  # slot by slot
+            left = np.asarray(same(got, before))
+            live_ok = bool(written[live].all())
+            dead_ok = (bool(left[~live].all()) if live.any()
+                       else int((~left).sum()) <= 1
+                       and bool(written[~left].all()))
+            say(f"kernels: kv_row_write rows{rows} {list(shape)} bf16, "
+                f"{label}: live slots equal to the dynamic_update_slice: "
+                f"{live_ok}; dead slots as they were: {dead_ok} "
+                f"({int((~left[~live]).sum())} of {int((~live).sum())} "
+                f"rewritten)")
+            check(live_ok, f"kv_row_write rows{rows} {label}: a live slot "
+                           f"differs from _row_update")
+            check(dead_ok, f"kv_row_write rows{rows} {label}: a dead slot "
+                           f"was written")
+            del new, before, want, got
+            gc.collect()
+    # the decode step's 36 layers of K and V alone, each slot at a position
+    # of its own: 20 steps inside one program, so that the device's time is
+    # what the clock reads
+    layers, steps = (2, 2) if tiny else (36, 20)
+    pos = jnp.asarray(np.random.RandomState(0).randint(0, max_len - steps, b),
+                      jnp.int32)
+    new = rnd(20, (b, 1, heads, d))
+    us = {}
+    few, every = f"{live7.sum()} of {b}", f"{b} of {b}"
+    for label, live in ((few, live7), (every, np.ones((b,), bool))):
+        live = jnp.asarray(live)
+
+        def run(ks, vs, live=live):
+            def one(i, kv):
+                out = [kv_row_write((k, v), (new, new), pos + i, live)
+                       for k, v in zip(*kv)]
+                return [k for k, _ in out], [v for _, v in out]
+            return jax.lax.fori_loop(0, steps, one, (ks, vs))
+
+        run = jax.jit(run, donate_argnums=(0, 1))
+        kv = run([jnp.zeros(shape, jnp.bfloat16) for _ in range(layers)],
+                 [jnp.zeros(shape, jnp.bfloat16) for _ in range(layers)])
+        jax.block_until_ready(kv)
+        t0 = time.perf_counter()
+        for _ in range(1 if tiny else 5):
+            kv = run(*kv)
+        jax.block_until_ready(kv)
+        us[label] = ((time.perf_counter() - t0) * 1e6
+                     / ((1 if tiny else 5) * steps * layers))
+        del kv, run
+        gc.collect()
+    say(f"kernels: the decode step's {layers} column writes (b{b} x "
+        f"{max_len}, {heads} x {d}, {steps} steps a program): "
+        f"{us[few]:.1f} us a layer at {few} live, {us[every]:.1f} us at "
+        f"{every}")
+
+
 def leg_row_dma(tiny):
     """The decode step's row write where a row is contiguous
     (``ops/pallas/kv_row_dma.py``) as the three expert cells' decode steps
@@ -936,6 +1019,7 @@ def main(argv=None):
             leg_serve(size, *leg)
             gc.collect()
         for name, leg in (("delta_rule", leg_delta_rule), ("ring", leg_ring),
+                          ("column_kernel", leg_column_kernel),
                           ("row_dma", leg_row_dma)):
             if name in legs:
                 leg(args.rehearse)

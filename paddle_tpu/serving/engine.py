@@ -483,7 +483,7 @@ class GenerationEngine:
 
             def full(k, v):
                 count_cache_route("full")
-                return DecodeView(k, v, pos)
+                return DecodeView(k, v, pos, live=lv)
 
             def ring(k, v, w):
                 count_cache_route("ring")
@@ -534,7 +534,8 @@ class GenerationEngine:
             # a slot without a request has no valid key, as in serve_decode
             lmask = LengthMask(jnp.where(_leaf(live)[:, None], pos, -1))
             views = self._views(
-                cache, lambda k, v: DecodeView(k, v, pos0), None, None)
+                cache, lambda k, v: DecodeView(k, v, pos0, live=_leaf(live)),
+                None, None)
             logits, views = model(
                 tokens, position_ids=Tensor(pos),
                 attn_mask=lmask, cache=views)

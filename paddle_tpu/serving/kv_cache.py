@@ -25,7 +25,7 @@ XLA:TPU keeps the buffer as ``[batch, heads, head_dim, max_len]``,
 ``ops/pallas/flash_decode.py``): a row is ``heads * head_dim`` elements in
 as many lane rows (the loop took 68% of a GPT-2 large decode step on the
 v5e), and ``ops/pallas/kv_row_write.py`` rewrites the one 128-lane column
-of each slot that holds its position. With ``head_dim`` in whole 128-lane
+of each live slot that holds its position. With ``head_dim`` in whole 128-lane
 tiles the buffer is row-major, a position's row a run of whole tiles (the
 loops took 3.8 ms of the Laguna cut's 19.5 ms decode program), and
 ``ops/pallas/kv_row_dma.py`` copies each slot's new rows into place with
@@ -122,6 +122,12 @@ class KVCache:
     engine hands the decode and verify steps its mask of live slots, a dead
     slot's query has no valid key (``LengthMask.q_pos`` −1) whatever its
     length says, and the next prefill into the slot sets the length anew.
+    The decode-shaped row write (:class:`DecodeView`, every route of
+    :func:`row_write_route`) writes a live slot's rows exactly as
+    :func:`_row_update` writes them, bit for bit, clamped starts included;
+    a dead slot's rows it need not touch (the column kernel leaves them
+    alone whenever one slot is live; ``row_dma`` and ``dus`` write them as
+    they write a live slot's).
     A recurrent state has no such mask — a stale one would be USED — so a
     prefill always starts a slot's state from zeros
     (:class:`StatePrefillView`) and overwrites what the last request left.
@@ -228,10 +234,12 @@ class KVCache:
 def _row_update(buf, new, starts):
     """Batched row write: ``buf[i, starts[i]:starts[i]+s] = new[i]`` via a
     vmapped ``dynamic_update_slice`` (per-slot scalar start index, static
-    shapes). XLA:CPU writes it in place in a donated buffer; XLA:TPU
-    compiles it to a ``while`` of one-row updates, one trip a slot, at any
-    ``head_dim``, and :func:`row_write_route` sends the decode step to a
-    kernel there where one takes the shape."""
+    shapes), every slot. XLA:CPU writes it in place in a donated buffer;
+    XLA:TPU compiles it to a ``while`` of one-row updates, one trip a slot,
+    at any ``head_dim``, and :func:`row_write_route` sends the decode step
+    to a kernel there where one takes the shape. What it writes into a live
+    slot is what every route writes there; a dead slot's rows a route need
+    not touch (``KVCache``)."""
 
     def one(b, n, s):
         z = jnp.int32(0)
@@ -303,7 +311,12 @@ class DecodeView:
     kernels, K and V in one call (the column kernel where the TPU keeps
     ``max_len`` on the lanes, one DMA a slot where a row is contiguous),
     else the vmapped ``dynamic_update_slice``; all agree element for
-    element. A kernel writes into the buffers it is handed, so on a TPU
+    element in every live slot. ``live`` is the engine's mask of the slots
+    that hold a request (None: every slot): the column kernel visits the
+    live slots alone and leaves a dead slot's rows as they were whenever
+    one slot is live; ``row_dma`` and ``dus`` ignore it and write every
+    slot (their share of a step is too small to gain by it). A ring's view
+    carries none. A kernel writes into the buffers it is handed, so on a TPU
     the step that builds this view donates its cache (every serving step
     does; ``kv_row_write.py`` says what XLA does otherwise). The updated
     buffers stay on the view; the engine collects them into the next
@@ -316,13 +329,14 @@ class DecodeView:
     step's.
     """
 
-    __slots__ = ("k", "v", "pos", "mask")
+    __slots__ = ("k", "v", "pos", "mask", "live")
 
-    def __init__(self, k, v, pos, mask=None):
+    def __init__(self, k, v, pos, mask=None, live=None):
         self.k = _leaf(k)
         self.v = _leaf(v)
         self.pos = _leaf(pos)
         self.mask = mask
+        self.live = live
 
     def update(self, k_new, v_new):
         from ..ops import pallas
@@ -338,7 +352,7 @@ class DecodeView:
             from ..ops.pallas.kv_row_write import kv_row_write
 
             self.k, self.v = kv_row_write((self.k, self.v), (kn, vn),
-                                          self.pos)
+                                          self.pos, self.live)
         elif route == "row_dma":
             from ..ops.pallas.kv_row_dma import kv_row_dma
 

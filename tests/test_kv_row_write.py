@@ -5,10 +5,16 @@ the table of ``row_write_route``, and serving through each route.
 
 Contracts under test:
   * exact equality of the WHOLE buffer with the vmapped
-    ``dynamic_update_slice``: every dtype, batch and position the engine
-    builds, one row (decode) and several (speculative verify, straddling a
-    128-lane column or not), a dead slot at its clamped position, starts the
+    ``dynamic_update_slice`` when every slot is live: every dtype, batch and
+    position the engine builds, one row (decode) and several (speculative
+    verify, straddling a 128-lane column or not), starts the
     ``dynamic_update_slice`` would clamp;
+  * with the engine's mask of live slots, the column kernel writes a live
+    slot exactly as ``_row_update`` does and leaves a dead slot's bytes as
+    they were whenever one slot is live (with none live, at most one dead
+    slot is written, as ``_row_update`` writes it); under a mesh each shard
+    lists its own; the engine hands its mask to the kernel from decode and
+    verify;
   * everything outside the written rows is the input's, bit for bit;
   * ``row_write_route`` is a pure function of shape facts and of what the
     platform tells, so tier-1 (XLA:CPU) can say what the TPU compiles;
@@ -99,19 +105,102 @@ def test_verify_rows_straddling_a_column_equal_row_update(dtype, rows):
         np.testing.assert_array_equal(_bits(g), _bits(w))
 
 
-@pytest.mark.parametrize("rows,start", [(1, 255), (5, 251), (1, 10 ** 6),
-                                        (5, 254), (1, -3)])
-def test_dead_slots_and_clamped_starts_are_written_as_row_update_writes_them(
-        rows, start):
-    """A dead slot sits at the engine's clamped position (``max_len - 1``
-    decode, ``max_len - W`` verify) and is written like any other; a start
-    outside ``[0, max_len - rows]`` lands where ``dynamic_update_slice``
-    clamps it."""
-    bufs, news = _buffers(3, 256, 2, 64, rows, jnp.bfloat16, seed=7)
-    starts = jnp.asarray([5, start, 130], jnp.int32)
-    got, want = _write_both(bufs, news, starts)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(_bits(g), _bits(w))
+def _assert_live_written_dead_left(bufs, news, starts, live):
+    """The column kernel under the mask ``live``: each live slot is bit for
+    bit ``_row_update``'s; a dead slot is its input's whenever one slot is
+    live, and with none live at most one slot is written, as ``_row_update``
+    writes it."""
+    got = W.kv_row_write(tuple(bufs), tuple(news), starts, jnp.asarray(live),
+                         interpret=True)
+    live = np.asarray(live, bool)
+    for g, x, n in zip(got, bufs, news):
+        g, x = _bits(g), _bits(x)
+        w = _bits(C._row_update(x.view(n.dtype), n, starts))
+        np.testing.assert_array_equal(g[live], w[live])
+        if live.any():
+            np.testing.assert_array_equal(g[~live], x[~live])
+        else:
+            touched = [i for i in range(len(g))
+                       if not np.array_equal(g[i], x[i])]
+            assert len(touched) <= 1, touched
+            for i in touched:
+                np.testing.assert_array_equal(g[i], w[i])
+
+
+def _mask(b, live_slots):
+    live = np.zeros((b,), bool)
+    live[list(live_slots)] = True
+    return live
+
+
+#: (rows, max_len, starts, live slots): the cases of the column kernel under
+#: the engine's mask
+LIVE_CASES = {
+    # a live slot at a start ``dynamic_update_slice`` clamps (the engine's
+    # ``max_len - 1`` decode, ``max_len - W`` verify, past the end, counted
+    # from the end) beside a dead slot and a live one
+    "clamped-1-255": (1, 256, [5, 255, 130], [1, 2]),
+    "clamped-5-251": (5, 256, [5, 251, 130], [1, 2]),
+    "clamped-1-past-the-end": (1, 256, [5, 10 ** 6, 130], [1, 2]),
+    "clamped-5-254": (5, 256, [5, 254, 130], [1, 2]),
+    "clamped-1-from-the-end": (1, 256, [5, -3, 130], [1, 2]),
+    # live slots 0, 5 and 31 of 32, dead ones between them
+    "gaps-decode": (1, 256, None, [0, 5, 31]),
+    "gaps-verify-5": (5, 256, None, [0, 5, 31]),
+    "all-live-decode": (1, 256, None, range(32)),
+    "all-live-verify-8": (8, 256, None, range(32)),
+    "none-live-decode": (1, 256, None, []),
+    "none-live-verify-5": (5, 256, None, []),
+    # verify rows straddling a 128-lane column (126, 124, 255) or not,
+    # dead slots among them
+    "straddling-5": (5, 384, [0, 126, 124, 255, 379, 17], [0, 2, 4]),
+    "straddling-8": (8, 384, [0, 126, 124, 255, 376, 17], [1, 3, 4]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_the_live_slots_alone_are_written(case):
+    rows, max_len, starts, live_slots = LIVE_CASES[case]
+    if starts is None:
+        starts = np.random.RandomState(rows).randint(0, max_len - rows, 32)
+    b = len(starts)
+    bufs, news = _buffers(b, max_len, 2, 64, rows, jnp.bfloat16, seed=7)
+    _assert_live_written_dead_left(bufs, news, jnp.asarray(starts, jnp.int32),
+                                   _mask(b, live_slots))
+
+
+@pytest.mark.parametrize("rows", [1, 5])
+def test_under_a_mesh_each_shard_lists_its_own_live_slots(rows):
+    """Every live slot on one shard of four: the other shards list none,
+    and each leaves all but at most one of its own slots alone."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.partition import partition_scope
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    bufs, news = _buffers(8, 256, 2, 64, rows, jnp.bfloat16, seed=rows)
+    starts = jnp.asarray([0, 127, 128, 250, 1, 2, 3, 200], jnp.int32)
+    live = jnp.asarray(_mask(8, [2, 3]))  # the second shard's two slots
+    row = NamedSharding(mesh, P("dp"))
+    placed = jax.device_put((bufs, news, starts, live), row)
+
+    def write(bufs, news, starts, live):
+        with partition_scope((mesh, ("dp",))):
+            return W.kv_row_write(tuple(bufs), tuple(news), starts, live,
+                                  interpret=True)
+
+    got = jax.jit(write)(*placed)
+    for g, x, n in zip(got, bufs, news):
+        assert g.sharding.is_equivalent_to(row, g.ndim)
+        g, x = _bits(g), _bits(x)
+        w = _bits(C._row_update(x.view(n.dtype), n, starts))
+        np.testing.assert_array_equal(g[2:4], w[2:4])
+        for shard in (0, 2, 3):  # no live slot: at most one slot written
+            touched = [i for i in (2 * shard, 2 * shard + 1)
+                       if not np.array_equal(g[i], x[i])]
+            assert len(touched) <= 1, touched
+            for i in touched:
+                np.testing.assert_array_equal(g[i], w[i])
 
 
 @pytest.mark.parametrize("heads,head_dim", [(3, 32), (5, 64), (1, 16)])
@@ -478,6 +567,32 @@ def test_greedy_serving_byte_identical_between_the_two_routes(
     # it more than once): the same number either way, the two layers' share
     assert kernel_routes[route] == dus_routes["dus"] > 0
     assert kernel_routes[route] % 2 == 0
+
+
+@pytest.mark.parametrize("route", ["column_kernel", "dus"])
+@pytest.mark.parametrize("step", ["decode", "verify"])
+def test_the_engine_hands_its_live_mask_to_the_column_kernel(monkeypatch,
+                                                             step, route):
+    """One step of two slots from zeroed buffers, the second dead: the
+    column kernel leaves its rows zero, ``dus`` writes them; the live slot's
+    rows are the same either way."""
+    if route == "dus":
+        monkeypatch.setattr(W, "BLOCK_BYTES", 0)
+    with pallas.interpret_mode():
+        eng = GenerationEngine(_kernel_model(), max_batch=2, max_len=128,
+                               prefill_buckets=(16,),
+                               spec_k=4 if step == "verify" else 0)
+        make = (eng.example_verify_args if step == "verify"
+                else eng.example_decode_args)
+        args = list(make([3, 7]))
+        args[-1] = np.asarray([True, False])
+        run = eng.verify_step if step == "verify" else eng.decode_step
+        cache = run(*args)[-1]
+    for buf in (*cache.ks, *cache.vs):
+        buf = np.asarray(buf._value if hasattr(buf, "_value") else buf,
+                         np.float32)
+        assert buf[0].any()
+        assert buf[1].any() == (route == "dus")
 
 
 @pytest.mark.parametrize("family", ["laguna", "solar_open2", "nemotron_h"])

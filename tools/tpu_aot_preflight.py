@@ -72,12 +72,21 @@ def pair_lists_built(text):
         r"reduce-window\([^\n]*op_name=\"[^\"]*jit\(_flash_decode\)", text))
 
 
+def slot_lists_built(text):
+    """How often a compiled program builds the row write's list of live
+    slots (``kv_row_write``'s ``live_pairs`` over the engine's mask): once,
+    however many layers."""
+    return len(re.findall(
+        r"reduce-window\([^\n]*op_name=\"[^\"]*jit\(_kv_row_write\)", text))
+
+
 def decode_program_facts(text):
-    """The two things a compiled decode-shaped program at the serving cells'
+    """The things a compiled decode-shaped program at the serving cells'
     batch is held to, as one line of the report."""
     return (f"copies of a cache buffer: {cache_buffer_copies(text, 32)}; "
             f"lists of live (slot, block) pairs built: "
-            f"{pair_lists_built(text)}")
+            f"{pair_lists_built(text)}; lists of live slots built: "
+            f"{slot_lists_built(text)}")
 
 
 def entry_ops(text):
@@ -247,13 +256,14 @@ def kernel_programs(devs):
     # 128 MiB of VMEM ahead of the kernel and copying its aliased result
     # out again (25 of a 36-layer decode step's 72 buffers, 5 ms a step on
     # the chip, before the kernel pinned its outputs to HBM)
-    def write_then_attend(ks, vs, x, w, pos):
+    def write_then_attend(ks, vs, x, w, pos, live):
         out_k, out_v = [], []
         for k, v in zip(ks, vs):
             new = (x @ w).reshape(32, 1, wh, d)
-            k, v = kv_row_write((k, v), (new, new), pos)
-            x = flash_attention_decode(new, k, v, pos[:, None]).reshape(
-                32, wh * d)
+            k, v = kv_row_write((k, v), (new, new), pos, live)
+            x = flash_attention_decode(
+                new, k, v, jnp.where(live, pos, -1)[:, None]).reshape(
+                    32, wh * d)
             out_k.append(k)
             out_v.append(v)
         return x, out_k, out_v
@@ -262,7 +272,8 @@ def kernel_programs(devs):
     yield "kv row write donated decode b32 x8", lambda: jax.jit(
         write_then_attend, donate_argnums=(0, 1)).lower(
             big, big, _sds((32, wh * d), bf, one),
-            _sds((wh * d, wh * d), bf, one), _sds((32,), jnp.int32, one))
+            _sds((wh * d, wh * d), bf, one), _sds((32,), jnp.int32, one),
+            _sds((32,), jnp.bool_, one))
 
     # the decode step's row write where a row is contiguous (heads of 128)
     # as the expert cells reach it: the kernel alone at the Laguna cut's
